@@ -1,6 +1,9 @@
 """Paper §3 scaling claim (95% parallel efficiency at 1024 GPUs via hidden
-communication) plus the PR-6 fault-tolerance cost model, measured on fake
-CPU devices through the real engine:
+communication) plus the checkpointing fault-tolerance cost model,
+measured on fake CPU devices through the real engine. Every child forces
+``JAX_PLATFORMS=cpu`` with fake host devices, so this measures nothing
+on a TPU, even on a machine that has one; the multi-chip path on the
+chip is ``chip_smoke.py --chips 4``:
 
 * **Weak scaling** (``scale_seq_r{R}`` / ``scale_ovl_r{R}`` rows): fixed
   local block per rank, domain grows with the rank count; one
@@ -195,7 +198,11 @@ def checkpoint_rows(n: int, short: int, long_: int,
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser()
+    ap = argparse.ArgumentParser(
+        description="Weak scaling and checkpoint overhead on fake CPU "
+                    "devices (every child runs with JAX_PLATFORMS=cpu: "
+                    "nothing here runs on a TPU; the on-chip multi-chip "
+                    "check is chip_smoke.py --chips 4)")
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke: 1-2 ranks, short runs, 1 repeat")
     ap.add_argument("--json", action="store_true",
@@ -233,4 +240,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -593,6 +593,9 @@ def main(out_rows=None, nsteps: int = 1, iters: int = 20, sizes=None,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nsteps", type=int, default=1,
                     help="temporal blocking depth k (k>1 adds seq-vs-fused rows)")

@@ -55,9 +55,7 @@ class CheckpointError(ValueError):
 
 
 def _flatten_with_paths(tree):
-    from ..compat import tree_flatten_with_path
-
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in kp)
              for kp, _ in flat]
     vals = [v for _, v in flat]

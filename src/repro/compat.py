@@ -1,54 +1,11 @@
-"""Version-compat shims for the jax API surface this repo depends on.
-
-The repo targets current jax but must run on the 0.4.x line too (the
-pinned toolchain of some hosts). Everything version-sensitive funnels
-through here:
-
-  * ``shard_map`` — moved from ``jax.experimental.shard_map`` to top-level
-    ``jax.shard_map``; the replication-check kwarg was renamed
-    ``check_rep`` -> ``check_vma`` along the way.
-  * ``axis_size`` — ``jax.lax.axis_size`` only exists on newer jax; 0.4.x
-    exposes the static size through ``jax.core.axis_frame``.
-  * ``tree_flatten_with_path`` — ``jax.tree.flatten_with_path`` on newer
-    jax, ``jax.tree_util.tree_flatten_with_path`` on 0.4.x.
-"""
+"""The one jax API the repo wraps: ``shard_map`` with the mesh and specs
+passed positionally (``jax.shard_map`` takes them as keywords only)."""
 from __future__ import annotations
-
-import inspect
 
 import jax
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-_SM_PARAMS = inspect.signature(_shard_map).parameters
-_CHECK_KW = "check_vma" if "check_vma" in _SM_PARAMS else "check_rep"
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool | None = None,
-              **kwargs):
-    """jax.shard_map with the replication-check kwarg normalized to the
-    new ``check_vma`` spelling on every supported jax version."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-def tree_flatten_with_path(tree):
-    """(key_path, leaf) flattening on every supported jax version."""
-    try:
-        return jax.tree.flatten_with_path(tree)
-    except AttributeError:  # jax <= 0.4.x keeps it in tree_util
-        return jax.tree_util.tree_flatten_with_path(tree)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, usable in Python control flow
-    inside shard_map on every supported jax version."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame.size if hasattr(frame, "size") else frame
+def shard_map(f, mesh, in_specs, out_specs, **kwargs):
+    """``jax.shard_map(f, mesh=mesh, in_specs=..., out_specs=...)``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -803,6 +803,7 @@ class StencilKernel:
     def launch_info(self) -> dict:
         """Derived launch parameters of compiled instances (for inspection)."""
         return {
-            k: {"grid": v.grid, "block": v.block, "window_bytes": v.window_bytes}
+            k: {"grid": v.grid, "block": v.block,
+                "window_bytes": v.window_bytes, "vmem_bytes": v.vmem_bytes}
             for k, v in self._cache.items()
         }

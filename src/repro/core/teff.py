@@ -45,6 +45,29 @@ A100_SXM4 = HardwareSpec("NVIDIA A100 SXM4", peak_bw=1355e9, peak_flops=312e12,
 P100_PCIE = HardwareSpec("NVIDIA P100 PCIe", peak_bw=561e9, peak_flops=18.7e12,
                          link_bw=16e9, hbm_bytes=16e9)
 
+# Published peaks keyed by ``jax.Device.device_kind``. TPU v5e (JAX
+# reports "TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197
+# TFLOP/s bf16, 16 GB of HBM at 819 GB/s. GPUs: the vendors' A100/P100
+# datasheets (the paper's Fig. 2 hardware).
+DEVICE_PEAKS = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+    "NVIDIA A100-SXM4-40GB": A100_SXM4,
+    "Tesla P100-PCIE-16GB": P100_PCIE,
+}
+
+
+def device_peaks(device_kind: str) -> HardwareSpec:
+    """The published peaks of one accelerator kind; a kind not in
+    :data:`DEVICE_PEAKS` is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks recorded for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}) — add its entry, with its "
+            "source, to repro.core.teff.DEVICE_PEAKS") from None
+
 
 def a_eff(n_points: int, n_read: int, n_write: int, itemsize: int) -> int:
     """Effective bytes moved per step: each counted field crosses HBM once."""

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import telemetry as _telemetry
-from ..compat import axis_size as _axis_size
 
 
 # Wire formats for ghost payloads. Interiors can stay f32 while the
@@ -132,7 +131,7 @@ def halo_exchange(
     depths = _axis_depths(depths, r, len(mesh_axes))
     for mesh_ax, arr_ax, per, (d_lo, d_hi) in zip(mesh_axes, array_axes,
                                                   periodic, depths):
-        n = _axis_size(mesh_ax)
+        n = jax.lax.axis_size(mesh_ax)
         if n == 1:
             if per:
                 # self-wrap: ghost layers come from own opposite interior
@@ -239,7 +238,7 @@ def grouped_halo_exchange(
         groups.setdefault(jnp.asarray(out[n]).dtype, []).append(n)
     for ax_i, (mesh_ax, arr_ax, per) in enumerate(
             zip(mesh_axes, array_axes, periodic)):
-        n_ranks = _axis_size(mesh_ax)
+        n_ranks = jax.lax.axis_size(mesh_ax)
         if n_ranks == 1:
             if per:
                 for f in names:
@@ -380,7 +379,7 @@ def _emit_exchange_telemetry(col, fields, names, mesh_axes, radius, depths,
     device program is untouched. Gauges carry per-exchange bytes;
     multiply by the step count for totals."""
     try:
-        active = [_axis_size(ax) > 1 for ax in mesh_axes]
+        active = [jax.lax.axis_size(ax) > 1 for ax in mesh_axes]
     except Exception:       # outside shard_map — assume every axis ships
         active = None
     shp = {f: tuple(fields[f].shape) for f in names}

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size as _named_axis_size
-
 
 @dataclasses.dataclass(frozen=True)
 class ShardRules:
@@ -178,7 +176,7 @@ def seq_sharded_decode_attention(
         # global offset of this shard's cache slice (row-major over seq_axes)
         off = jnp.int32(0)
         for ax in seq_axes:
-            off = off * _named_axis_size(ax) + jax.lax.axis_index(ax)
+            off = off * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         off = off * s_loc
         qg = q.reshape(b, Hkv, R, D).astype(jnp.float32) * scale_
         s = jnp.einsum("bgrd,bgkd->bgrk", qg, kc.astype(jnp.float32))

@@ -22,6 +22,7 @@ import json
 import os
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from .. import telemetry as _telemetry
@@ -181,6 +182,7 @@ def autotune(
     reductions: Sequence[str] | None = None,
     check_every: int | None = None,
     compute_dtype=None,
+    interpret: bool | None = None,
 ) -> TuneResult:
     """Find the fastest (tile, nsteps[, march_axis]) for a stencil
     problem class.
@@ -216,7 +218,15 @@ def autotune(
     are dropped *before anything is built or compiled*. Only the
     survivors are measured; ``TuneResult.candidates_pruned`` records how
     many configs never paid a compile.
+
+    A candidate whose tile the launch cannot realize by construction
+    (:class:`~repro.kernels.stencil.UnrealizableTile`) is skipped. Any
+    other failure is re-raised when the candidates compile for the device
+    (``interpret`` false; default: whenever the backend is a TPU) — a
+    kernel the compiler refuses is a fault, not a slow candidate.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     prune_tag = (None if cost_model is None or hw is None
                  else (getattr(hw, "name", "hw"), float(prune_ratio)))
     # Every tune keys the FULL (storage, compute) dtype pair — the v4
@@ -301,8 +311,12 @@ def autotune(
             fn = make_step(tile, k, march) if pass_march else \
                 make_step(tile, k)
             m = teff.measure(fn, iters=iters, warmup=1)
+        except _stencil.UnrealizableTile:
+            continue  # tile the launch cannot realize by construction
         except Exception:
-            continue  # candidate not realizable (tile/shape mismatch etc.)
+            if not interpret:
+                raise
+            continue
         tried += 1
         per_step = m.median_s / k
         if best is None or per_step < best.per_step_s:
@@ -348,7 +362,6 @@ def autotune_diffusion3d(
     ``march_candidates`` (e.g. ``(None, 0)``) adds streamed execution to
     the search space.
     """
-    import jax
     import numpy as np
 
     from ..core import init_parallel_stencil, fd3d as fd

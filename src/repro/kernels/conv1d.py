@@ -15,9 +15,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .stencil import halo_window_spec
+
+def _halo_window_spec(block, halo, index_map) -> pl.BlockSpec:
+    """Halo-extended window with out-of-bounds padding (element offsets
+    in the padded coordinates). Mosaic refuses padded windows, so this
+    kernel runs in interpret mode only."""
+    return pl.BlockSpec(
+        tuple(pl.Element(b + lo + hi, padding=(lo, hi))
+              for b, (lo, hi) in zip(block, halo)), index_map)
 
 
 def _body(x_ref, w_ref, b_ref, o_ref, *, K, BL, silu):
@@ -39,13 +45,18 @@ def _body(x_ref, w_ref, b_ref, o_ref, *, K, BL, silu):
 
 @functools.lru_cache(maxsize=64)
 def _build(B, L, C, K, BL, dtype_name, silu, interpret):
+    if not interpret:
+        raise NotImplementedError(
+            "conv1d_causal reads a left-padded halo window, which Mosaic "
+            "refuses (padded Element windows); compiled for the TPU use "
+            "ops.conv1d_causal(impl='chunked') (the jnp realization)")
     dtype = jnp.dtype(dtype_name)
     body = functools.partial(_body, K=K, BL=BL, silu=silu)
     return pl.pallas_call(
         body,
         grid=(B, L // BL),
         in_specs=[
-            halo_window_spec((1, BL, C), ((0, 0), (K - 1, 0), (0, 0)),
+            _halo_window_spec((1, BL, C), ((0, 0), (K - 1, 0), (0, 0)),
                              lambda b, j: (b, j * BL, 0)),
             pl.BlockSpec((K, C), lambda b, j: (0, 0)),
             pl.BlockSpec((C,), lambda b, j: (0,)),

@@ -4,11 +4,17 @@ ParallelStencil's ``@parallel loopopt=true`` generates a CUDA kernel where a
 thread block stages a halo-extended tile of the input fields in shared
 memory/registers and sweeps it. The TPU-native equivalent built here:
 
-  * the Pallas *grid* tiles the full array; every input field gets a
-    **halo-extended VMEM window** (element-indexed, overlapping windows
-    with OOB padding — ``pl.Element`` dims on new jax, the equivalent
-    ``Unblocked`` indexing mode on jax <= 0.4.x) — this is the BlockSpec
-    realization of shared-memory blocking;
+  * the Pallas *grid* tiles the array; every input field gets a
+    **halo-extended VMEM window** (element-indexed ``pl.Element`` dims,
+    overlapping windows) — this is the BlockSpec realization of
+    shared-memory blocking. Mosaic (the TPU compiler) accepts only
+    unpadded windows whose two minor dims are (8, 128)-aligned or span
+    the array, so the derived launch tiles the *leading* axes and keeps
+    the two minor axes whole; a tiled window's offset is clamped into
+    the array at the domain ends and the kernel copies it into a VMEM
+    frame at the matching shift, so the update always sees the nominal
+    ``[pid*block - halo, pid*block + block + halo)`` frame (cells beyond
+    the domain hold stale values and are masked);
   * the kernel body evaluates the *same math-close update function* the
     ``jnp`` backend uses, on the window, producing the block-interior
     update;
@@ -77,23 +83,38 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ir.reductions import normalize_reductions as _normalize_reductions
 
-# Default VMEM working-set budget per kernel instance. v5e has 128 MiB of
-# VMEM per core; leave generous headroom for Pallas pipelining (double
-# buffering doubles the live window set) and spills.
-DEFAULT_VMEM_BUDGET = 8 << 20
+# Per-grid-step block of a reduction-partials output: the smallest f32
+# tile Mosaic stores (one value, broadcast; ``run`` reads [..., 0, 0]).
+_PARTIAL_TILE = (8, 128)
 
-# Hard per-core VMEM capacity (v5e: 128 MiB) for the preflight admission
-# check. The soft budget above steers automatic tile derivation; THIS is
-# the wall an explicit tile must not cross — beyond it the backend fails
-# with an opaque allocation error long after tracing. Override per
+# Scoped-VMEM limit handed to Mosaic (``vmem_limit_bytes``) and the wall
+# the preflight admission check holds every launch to. A TPU v5e core has
+# 128 MiB of VMEM; without an explicit limit Mosaic enforces 16 MiB. The
+# 8 MiB kept back is Mosaic's own internal scratch. Override per
 # deployment with REPRO_VMEM_LIMIT_BYTES.
-DEFAULT_VMEM_LIMIT = 128 << 20
+DEFAULT_VMEM_LIMIT = 120 << 20
+
+# Soft budget steering automatic tile derivation: the launch's whole
+# accounted footprint (see :func:`launch_footprint_bytes`) stays under it
+# where the array allows.
+DEFAULT_VMEM_BUDGET = 48 << 20
 
 
-class LaunchFootprintError(ValueError):
-    """The derived launch's VMEM window footprint exceeds the device
-    limit — raised at derivation time (preflight), not as an opaque
-    backend allocation failure at compile/run time."""
+class UnrealizableTile(ValueError):
+    """A tile this launch cannot realize by construction (it does not
+    divide the array, or its windows cannot fit VMEM) — the one error
+    class a tile search may skip."""
+
+
+class TileNotCompilable(UnrealizableTile, NotImplementedError):
+    """A tile Mosaic cannot compile by construction: it splits one of the
+    two minor axes, which a compiled launch keeps whole."""
+
+
+class LaunchFootprintError(UnrealizableTile):
+    """The derived launch's VMEM footprint exceeds the device limit —
+    raised at derivation time (preflight), not as an opaque backend
+    allocation failure at compile/run time."""
 
 
 def _vmem_limit(vmem_limit: int | None) -> int:
@@ -116,7 +137,9 @@ def preflight_vmem(block: Sequence[int], window_bytes: int,
               else "derived block (grid too small to shrink further)")
     raise LaunchFootprintError(
         f"launch preflight: {source} {tuple(block)} needs "
-        f"{window_bytes / 2**20:.1f} MiB of VMEM windows, over the device "
+        f"{window_bytes / 2**20:.1f} MiB of VMEM (double-buffered windows "
+        "and output blocks, scratch and the body's intermediates), over the "
+        "device "
         f"limit of {limit / 2**20:.1f} MiB — pass a smaller tile=, raise "
         "march_axis streaming, or (if the device really has more VMEM) "
         "set REPRO_VMEM_LIMIT_BYTES")
@@ -212,10 +235,11 @@ def streamed_footprint_bytes(
     field_offsets: Sequence[Sequence[int]],
     itemsize: int,
     march_axis: int,
+    queue: bool = True,
 ) -> int:
     """VMEM bytes of a *streamed* launch: per field, the fetch window
     carries no halo along the march axis (new planes only — the reuse
-    that kills the refetch) plus the rolling plane queue of
+    that kills the refetch) plus (``queue``) the rolling plane queue of
     ``Q * block_m`` planes carried in scratch across grid steps."""
     block = tuple(int(b) for b in block)
     nd = len(block)
@@ -228,8 +252,69 @@ def streamed_footprint_bytes(
                  for a in range(nd) if a != m]
         area = math.prod(other) if other else 1
         total += (block[m] - off[m]) * area          # fetch window
-        total += q * block[m] * area                 # scratch plane queue
+        if queue:
+            total += q * block[m] * area             # scratch plane queue
     return total * itemsize
+
+
+def effective_halo(shape: Sequence[int], block: Sequence[int], halo
+                   ) -> tuple[tuple[int, int], ...]:
+    """Per-axis window halo of a launch: an axis the block spans whole
+    (one grid step) has no neighbouring block to read from, so it carries
+    no halo — the update sees the whole domain along it."""
+    pairs = _halo_pairs(halo, len(tuple(block)))
+    return tuple((0, 0) if b == s else p
+                 for s, b, p in zip(shape, block, pairs))
+
+
+def launch_footprint_bytes(
+    shape: Sequence[int],
+    block: Sequence[int],
+    halo,
+    field_offsets: Sequence[Sequence[int]],
+    itemsize: int,
+    n_out: int,
+    march_axis: int | None = None,
+    nsteps: int = 1,
+    compute_itemsize: int | None = None,
+) -> int:
+    """VMEM a launch holds, as Mosaic allocates it: the pipelined input
+    windows and output blocks twice over (double buffering), the frame
+    buffer of each output (its stage when a tiled window's origin is
+    clamped, and its rotated field's buffer between temporal sweeps),
+    — streamed — the plane queues, and the body's intermediate values.
+    ``halo`` is the total (all sweeps) per-axis depth; axes the block
+    spans whole carry none (:func:`effective_halo`).
+
+    The intermediates are estimated as ``1 + n_out + nsteps`` frames at
+    the compute dtype, a bound fitted to Mosaic's scoped allocations for
+    the diffusion step at 128³-512³ (f32 and bf16, 1-4 sweeps, streamed)
+    that it exceeded by at most 1.5 MiB."""
+    shape = tuple(int(s) for s in shape)
+    block = tuple(int(b) for b in block)
+    nd = len(block)
+    pairs = effective_halo(shape, block, halo)
+    if march_axis is None:
+        windows = window_footprint_bytes(block, pairs, field_offsets,
+                                         itemsize)
+        queues = 0
+    else:
+        streamed = streamed_footprint_bytes(block, pairs, field_offsets,
+                                            itemsize, march_axis)
+        windows = streamed_footprint_bytes(
+            block, pairs, field_offsets, itemsize, march_axis,
+            queue=False)
+        queues = streamed - windows
+    frame = math.prod(min(b + lo + hi, s)
+                      for s, b, (lo, hi) in zip(shape, block, pairs))
+    frames = 0
+    if nsteps > 1 or any(a != march_axis and sum(pairs[a])
+                         for a in range(nd)):
+        frames = n_out * frame * itemsize
+    outs = n_out * math.prod(block) * itemsize
+    body = ((1 + n_out + int(nsteps)) * frame
+            * max(itemsize, compute_itemsize or itemsize))
+    return 2 * (windows + outs) + frames + queues + body
 
 
 def derive_launch(
@@ -245,23 +330,30 @@ def derive_launch(
     march_axis: int | None = None,
     march_min_block: int = 1,
     vmem_limit: int | None = None,
+    whole_axes: Sequence[int] = (),
+    n_out: int = 1,
+    compute_itemsize: int | None = None,
 ) -> tuple[tuple[int, ...], tuple[int, ...]]:
     """Derive (grid, block_shape) from array bounds — ParallelStencil's
     automatic launch-parameter derivation, with TPU tiling constraints.
 
-    Every derived launch passes a preflight admission check against the
-    hard device VMEM capacity (``vmem_limit``, default
-    :data:`DEFAULT_VMEM_LIMIT` or ``REPRO_VMEM_LIMIT_BYTES``): an
-    explicit ``tile`` whose halo-extended windows cannot fit raises a
-    pointed :class:`LaunchFootprintError` here, before compile, instead
-    of an opaque backend allocation failure later.
+    Every launch is costed with :func:`launch_footprint_bytes` (the VMEM
+    Mosaic allocates for it: double-buffered windows of all fields and
+    ``n_out`` output blocks, realignment frames, plane queues) and passes
+    a preflight admission check against the limit the compiler enforces
+    (``vmem_limit``, default :data:`DEFAULT_VMEM_LIMIT` or
+    ``REPRO_VMEM_LIMIT_BYTES``): an explicit ``tile`` that cannot fit
+    raises a pointed :class:`LaunchFootprintError` here, before compile,
+    instead of an opaque allocation failure later.
 
-    The minor (last) axis prefers 128-lane multiples, the next-to-minor
-    8-sublane multiples. Blocks must divide the array extents (the caller
-    pads otherwise). The block set is shrunk until the halo-extended
-    windows of all fields fit the VMEM budget. With temporal blocking
-    (``nsteps > 1``) the window halo is ``nsteps * radius`` per side, so
-    the same budget yields smaller blocks.
+    ``whole_axes`` are kept at full extent (the TPU layout keeps the two
+    minor axes whole: Mosaic refuses windows whose minor dims are neither
+    (8, 128)-aligned nor whole). Elsewhere the minor (last) axis prefers
+    128-lane multiples, the next-to-minor 8-sublane multiples. Blocks must
+    divide the array extents (the caller pads otherwise). The block set is
+    shrunk until the footprint fits ``vmem_budget``. With temporal
+    blocking (``nsteps > 1``) the window halo is ``nsteps * radius`` per
+    side, so the same budget yields smaller blocks.
 
     ``halos`` overrides the symmetric ``radius`` halo with per-axis
     (lo, hi) single-sweep depths (the footprint-inferred geometry): the
@@ -270,9 +362,9 @@ def derive_launch(
 
     ``field_offsets`` gives the per-field staggering offsets of the whole
     coupled field set (one tuple per field, entries subtracted from the
-    base window extent); when present the VMEM footprint is the *sum of
-    the per-field windows*, so a system with many fields gets smaller
-    blocks than a single-field problem under the same budget.
+    base window extent); the VMEM footprint is the *sum of the per-field
+    windows*, so a system with many fields gets smaller blocks than a
+    single-field problem under the same budget.
 
     ``march_axis`` switches the VMEM accounting to the streamed launch
     geometry: the march axis carries no window halo (blocks fetch new
@@ -289,24 +381,25 @@ def derive_launch(
     if field_offsets is None:
         field_offsets = [(0,) * nd] * int(n_fields)
     field_offsets = [tuple(int(o) for o in off) for off in field_offsets]
+    whole = {int(a) for a in whole_axes}
 
-    def window_bytes(blk):
-        if march_axis is not None:
-            return streamed_footprint_bytes(blk, halo, field_offsets,
-                                            itemsize, march_axis)
-        return window_footprint_bytes(blk, halo, field_offsets, itemsize)
+    def footprint(blk):
+        return launch_footprint_bytes(shape, blk, halo, field_offsets,
+                                      itemsize, n_out, march_axis, k,
+                                      compute_itemsize)
 
     if tile is not None:
         block = tuple(int(b) for b in tile)
         if len(block) != nd or any(s % b for s, b in zip(shape, block)):
-            raise ValueError(f"tile {block} must divide shape {shape}")
-        preflight_vmem(block, window_bytes(block), vmem_limit,
+            raise UnrealizableTile(f"tile {block} must divide shape {shape}")
+        preflight_vmem(block, footprint(block), vmem_limit,
                        explicit_tile=True)
     else:
         caps = [256 if a == nd - 1 else (64 if a == nd - 2 else 16) for a in range(nd)]
         aligns = [128 if a == nd - 1 else (8 if a == nd - 2 else 1) for a in range(nd)]
         block = [
-            _pick_block(s, c, al) for s, c, al in zip(shape, caps, aligns)
+            s if a in whole else _pick_block(s, c, al)
+            for a, (s, c, al) in enumerate(zip(shape, caps, aligns))
         ]
         if march_axis is not None:
             # The march block should be *small*: each sequential grid
@@ -320,9 +413,11 @@ def derive_launch(
             fit = [d for d in _divisors_leq(shape[m], shape[m]) if d >= need]
             block[m] = fit[0] if fit else shape[m]
 
-        # Shrink the largest non-minor axis first; keep lane alignment longest.
-        while window_bytes(block) > vmem_budget:
-            cands = sorted(range(nd), key=lambda a: (a == nd - 1, -block[a]))
+        # Shrink the largest non-minor axis first; keep lane alignment
+        # longest; whole axes never shrink.
+        while footprint(block) > vmem_budget:
+            cands = sorted((a for a in range(nd) if a not in whole),
+                           key=lambda a: (a == nd - 1, -block[a]))
             for a in cands:
                 smaller = [d for d in _divisors_leq(shape[a], block[a] - 1)]
                 if smaller:
@@ -333,57 +428,40 @@ def derive_launch(
         block = tuple(block)
         # "let it ride" can still exceed the soft budget — but never the
         # hard device capacity
-        preflight_vmem(block, window_bytes(block), vmem_limit,
+        preflight_vmem(block, footprint(block), vmem_limit,
                        explicit_tile=False)
     grid = tuple(s // b for s, b in zip(shape, block))
     return grid, block
 
 
-def halo_window_spec(
-    block: Sequence[int],
-    halo: Sequence[int | tuple[int, int]],
-    index_map: Callable,
-) -> pl.BlockSpec:
-    """BlockSpec for an overlapping, halo-extended VMEM window.
-
-    ``halo`` gives the per-dimension (lo, hi) extension (an int means
-    symmetric). ``index_map`` must return *element* offsets in the padded
-    coordinate system — for a stride-``block`` tiling that is simply
-    ``pid * block`` per dim. Out-of-bounds cells read as garbage/NaN and
-    must be masked by the kernel body.
-
-    Version compat: jax >= 0.5 expresses this with ``pl.Element`` block
-    dims; jax 0.4.x spells the identical semantics as the ``Unblocked``
-    indexing mode with padding.
-    """
-    halo = tuple((h, h) if isinstance(h, int) else (int(h[0]), int(h[1]))
-                 for h in halo)
-    if hasattr(pl, "Element"):
-        dims = tuple(
-            pl.Element(b + lo + hi, padding=(lo, hi))
-            for b, (lo, hi) in zip(block, halo)
-        )
-        return pl.BlockSpec(dims, index_map)
-    win = tuple(b + lo + hi for b, (lo, hi) in zip(block, halo))
-    return pl.BlockSpec(win, index_map, indexing_mode=pl.Unblocked(halo))
+def window_spec(extent: Sequence[int], index_map: Callable) -> pl.BlockSpec:
+    """BlockSpec for an overlapping VMEM window of ``extent`` elements per
+    axis; ``index_map`` returns the window's *element* offsets, which must
+    keep it inside the array (Mosaic supports no padded windows)."""
+    return pl.BlockSpec(tuple(pl.Element(int(e)) for e in extent), index_map)
 
 
-def compiler_params(nd: int, march: bool = False):
-    """``dimension_semantics`` for an nd stencil grid. All-parallel by
-    default (every block independent, letting Mosaic pipeline block
-    revisits); with ``march=True`` the innermost (last) grid dimension is
-    ``"arbitrary"`` — executed sequentially so the scratch plane queue
-    carries state from one grid step to the next — while the leading tile
-    dimensions stay ``"parallel"`` (Megacore may still split them).
-    Returns None when this jax has no TPU compiler-params surface."""
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp is None:
-        return None
-    if march:
-        return cp(dimension_semantics=("parallel",) * (nd - 1) + ("arbitrary",))
-    return cp(dimension_semantics=("parallel",) * nd)
+def window_start(pid, block: int, lo: int, hi: int, n: int):
+    """Element offset of a tiled axis's window: the nominal frame origin
+    ``pid*block - lo`` clamped so the window (``block + lo + hi`` cells,
+    or the whole axis when that is shorter) stays inside an axis of ``n``
+    cells (the base extent; staggered fields clamp identically)."""
+    return jnp.clip(pid * block - lo, 0, max(n - block - lo - hi, 0))
+
+
+def compiler_params(nd: int, march: bool = False,
+                    vmem_limit: int | None = None):
+    """Mosaic parameters for an nd stencil grid: ``dimension_semantics``
+    all-parallel by default (every block independent, letting Mosaic
+    pipeline block revisits); with ``march=True`` the innermost (last)
+    grid dimension is ``"arbitrary"`` — executed sequentially so the
+    scratch plane queue carries state from one grid step to the next —
+    while the leading tile dimensions stay ``"parallel"``. The scoped-VMEM
+    limit is the one the preflight admitted the launch against."""
+    sem = ("parallel",) * nd if not march else \
+        ("parallel",) * (nd - 1) + ("arbitrary",)
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=_vmem_limit(vmem_limit))
 
 
 def field_geometry(
@@ -467,59 +545,23 @@ def write_geometry(
     return tuple(modes), tuple(rings)
 
 
-def _write_modes(
-    update_shape: Sequence[int],
-    window_shape: Sequence[int],
-    radius: int,
-    off: Sequence[int],
-    name: str,
-) -> tuple[str, ...]:
-    """Legacy declared-radius wrapper of :func:`write_geometry`."""
-    modes, _ = write_geometry(update_shape, window_shape, off, name,
-                              ring=radius)
-    return modes
-
-
-def _valid_mask(block, field_shape, off, rings, modes, ext, pids=None):
-    """Mask of the cells this block may write for one output field, on
-    the frame ``[pid*block - ext_lo, pid*block + block + ext_hi - off)``
-    per axis (``ext``: per-axis (lo, hi) frame extensions; zeros with
-    ``off=0`` is the plain out-block frame; temporal sweeps blend on
-    progressively shrinking super-blocks).
+def _valid_mask(origin, frame_shape, field_shape, rings, modes):
+    """Mask of the cells one output field may take from its update, on a
+    frame whose cell ``j`` is global cell ``origin + j`` per axis.
 
     ``inn`` axes accept the field's global interior at that axis's ring
-    depth; ``all`` axes accept every in-domain cell (OOB cells beyond a
+    depth; ``all`` axes accept every in-domain cell (cells beyond a
     staggered field's extent stay masked and are cropped by the caller).
-
-    ``pids`` supplies per-axis logical block ids when they differ from
-    the raw grid position — the streamed path's march axis writes block
-    ``i - Lhi`` while fetching block ``i``. ``None`` reads
-    ``pl.program_id`` per axis (grid in array-axis order).
     """
-    nd = len(block)
-    ext = _halo_pairs(ext, nd)
-    mshape = tuple(b + lo + hi - o
-                   for b, (lo, hi), o in zip(block, ext, off))
     m = None
-    for a in range(nd):
-        pid = pl.program_id(a) if pids is None else pids[a]
-        g = pid * block[a] - ext[a][0] + jax.lax.broadcasted_iota(
-            jnp.int32, mshape, a)
+    for a in range(len(frame_shape)):
+        g = origin[a] + jax.lax.broadcasted_iota(jnp.int32, frame_shape, a)
         if modes[a] == "inn":
-            w = rings[a] if not isinstance(rings, int) else rings
-            ma = (g >= w) & (g < field_shape[a] - w)
+            ma = (g >= rings[a]) & (g < field_shape[a] - rings[a])
         else:
             ma = (g >= 0) & (g < field_shape[a])
         m = ma if m is None else (m & ma)
     return m
-
-
-def _interior_mask(block, shape, radius: int, extent: int = 0):
-    """Collocated interior mask (the pre-coupled-engine special case of
-    :func:`_valid_mask`; kept for the hand-specialized kernels)."""
-    nd = len(block)
-    return _valid_mask(block, tuple(shape), (0,) * nd, (radius,) * nd,
-                       ("inn",) * nd, extent)
 
 
 def _embed(a, frame: Sequence[int], starts: Sequence[int]):
@@ -556,30 +598,25 @@ def _shift(a, axis: int, d: int):
     return jnp.pad(a[tuple(idx)], pad)
 
 
-def _apply_bc_frame(arr, bc, field_shape, block, ext, dtype, pids=None):
-    """Realize one output's dirichlet/neumann0 condition on a block frame
-    ``[pid*block - ext_lo, pid*block + block + ext_hi - off)`` (``arr``'s
-    own shape), bitwise-equal to the ``core.boundary`` post-pass.
+def _apply_bc_frame(arr, bc, field_shape, origin, dtype):
+    """Realize one output's dirichlet/neumann0 condition on a frame whose
+    cell ``j`` is global cell ``origin + j`` per axis, bitwise-equal to
+    the ``core.boundary`` post-pass.
 
     Face cells are located by global-index iotas; neumann0 copies travel
     through frame-local static shifts, applied axis-by-axis in the same
     sequential order as the post-pass (which is what defines the corner
     values). Periodic conditions cannot be realized from local windows
     (their sources live across the domain) and are handled by the caller
-    as a face-slab scatter on the assembled output. ``pids`` carries
-    per-axis logical block ids when they differ from the grid position
-    (the streamed path); ``None`` reads ``pl.program_id``.
+    as a face-slab scatter on the assembled output.
     """
     if bc is None or bc.kind == "periodic":
         return arr
-    nd = len(block)
-    ext = _halo_pairs(ext, nd)
+    nd = arr.ndim
     d = bc.depth
 
     def giota(a):
-        pid = pl.program_id(a) if pids is None else pids[a]
-        return pid * block[a] - ext[a][0] + \
-            jax.lax.broadcasted_iota(jnp.int32, arr.shape, a)
+        return origin[a] + jax.lax.broadcasted_iota(jnp.int32, arr.shape, a)
 
     if bc.kind == "dirichlet":
         val = jnp.asarray(bc.value, dtype)
@@ -804,6 +841,30 @@ def build_stencil_call(
                     "or drop march_axis"
                 )
 
+    # The TPU layout: Mosaic refuses windows whose two minor dims are
+    # neither (8, 128)-aligned nor whole, and padded windows, so compiled
+    # launches keep the two minor axes whole and tile only leading axes
+    # with clamped windows. Interpret mode derives the same layout and
+    # additionally honours any explicit tile (the CPU test path for the
+    # general multi-axis geometry).
+    minor = tuple(range(max(nd - 2, 0), nd))
+    if not interpret:
+        if march is not None and march in minor:
+            raise NotImplementedError(
+                f"march_axis={march} streams a minor axis of a {nd}-d "
+                "stencil; compiled for the TPU a launch keeps its two minor "
+                "axes whole (Mosaic windows must span them), so only a "
+                "leading axis can stream — march axis 0 of a 3-d stencil "
+                "or drop march_axis")
+        if tile is not None and any(int(tile[a]) != shape[a] for a in minor):
+            raise TileNotCompilable(
+                f"tile {tuple(tile)} splits a minor axis of shape {shape}; "
+                "compiled for the TPU a launch keeps its two minor axes "
+                "whole (Mosaic refuses windows whose minor dims are neither "
+                f"(8, 128)-aligned nor whole) — pass a tile spanning axes "
+                f"{minor} or leave tile=None")
+    whole = tuple(a for a in minor if a != march)
+
     def _derive(m):
         # Fused neumann0 conditions copy through frame-local shifts, so
         # the (small, halo-sized) march block must still hold 2*depth
@@ -819,12 +880,15 @@ def build_stencil_call(
             field_offsets=[offsets[n] for n in field_names],
             halos=None if halos is None else sweep_halo,
             march_axis=m, march_min_block=min_bm,
+            whole_axes=whole if tile is None else (),
+            n_out=len(out_names), compute_itemsize=compute_dtype.itemsize,
         )
 
     grid, block = _derive(march)
-    whalo = tuple((nsteps * lo, nsteps * hi) for lo, hi in sweep_halo)
     if march is not None:
-        q_blocks, llo_b, lhi_b = march_queue_blocks(block[march], whalo[march])
+        q_blocks, llo_b, lhi_b = march_queue_blocks(
+            block[march], (nsteps * sweep_halo[march][0],
+                           nsteps * sweep_halo[march][1]))
         if shape[march] < q_blocks * block[march]:
             # The march extent cannot even fill the plane queue:
             # streaming would fetch mostly duplicate planes. Fall back to
@@ -840,12 +904,32 @@ def build_stencil_call(
                         f"block extent >= {2 * c.depth + offsets[o][a]}, got "
                         f"{block[a]} (pass a larger tile)"
                     )
+    # Axes the block spans whole carry no halo: the update sees the whole
+    # domain along them and every sweep keeps their full extent.
+    ehalo = effective_halo(shape, block, sweep_halo)
+    whalo = tuple((nsteps * lo, nsteps * hi) for lo, hi in ehalo)
+    # Tiled axes whose windows overlap their neighbours' ("realigned"):
+    # the window offset is clamped into the array at the domain ends, so
+    # the frame's origin moves with it and each output block is cut from
+    # its frame at a per-block shift. The update never shrinks the frame:
+    # every sweep is embedded back into it, and cells a sweep could not
+    # compute exactly (within the consumed halo of a non-domain frame
+    # edge) never reach the block.
+    realign = tuple(a != march and whalo[a][0] + whalo[a][1] > 0
+                    for a in range(nd))
+    fext = tuple(min(b + lo + hi, s)
+                 for s, b, (lo, hi) in zip(shape, block, whalo))
+    win = {n: tuple(e - o for e, o in zip(fext, offsets[n]))
+           for n in field_names}
+    fetch = {n: tuple(block[a] if a == march else w
+                      for a, w in enumerate(win[n]))
+             for n in field_names}
 
     if march is None:
         launch_grid = grid
 
-        def in_index_map(*pids):
-            return tuple(pid * b for pid, b in zip(pids, block))
+        def logical_pids(*pids):
+            return pids
 
         def out_index_map(*pids):
             return pids
@@ -856,18 +940,14 @@ def build_stencil_call(
         # queue stays column-coherent. The fetch leads the write by Lhi
         # blocks (lookahead); `Lhi` extra drain steps flush the tail, and
         # the fetch map clamps there (duplicate planes stand in for the
-        # out-of-bounds padding of the all-parallel path — both only ever
-        # reach masked boundary-ring cells).
+        # planes beyond the domain — both only ever reach masked
+        # boundary-ring cells).
         others = tuple(a for a in range(nd) if a != march)
         launch_grid = tuple(grid[a] for a in others) + (grid[march] + lhi_b,)
 
-        def in_index_map(*pids):
-            i = pids[-1]
-            return tuple(
-                jnp.minimum(i, grid[march] - 1) * block[march] if a == march
-                else pids[others.index(a)] * block[a]
-                for a in range(nd)
-            )
+        def logical_pids(*pids):
+            return tuple(pids[-1] if a == march else pids[others.index(a)]
+                         for a in range(nd))
 
         def out_index_map(*pids):
             i = pids[-1]
@@ -877,11 +957,18 @@ def build_stencil_call(
                 for a in range(nd)
             )
 
-    n_s, n_f = len(scalar_names), len(field_names)
+    def in_index_map(*pids):
+        # whole axes return a literal 0: Mosaic must prove a minor-axis
+        # offset tile-aligned, which it cannot for a traced expression
+        lp = logical_pids(*pids)
+        return tuple(
+            0 if block[a] == shape[a]
+            else jnp.minimum(lp[a], grid[a] - 1) * block[a] if a == march
+            else window_start(lp[a], block[a], *whalo[a], shape[a])
+            if realign[a] else lp[a] * block[a]
+            for a in range(nd))
 
-    def _crop(a):
-        return a[tuple(slice(lo, d - hi)
-                       for d, (lo, hi) in zip(a.shape, sweep_halo))]
+    n_s, n_f = len(scalar_names), len(field_names)
 
     def _check_updates(updates):
         missing = set(out_names) - set(updates)
@@ -889,166 +976,187 @@ def build_stencil_call(
             raise ValueError(f"update_fn did not produce outputs {missing}")
 
     n_out = len(out_names)
+    stage = any(realign)
+    use_queue = march is not None and q_blocks > 1
+    frame_bufs = stage or nsteps > 1
+    sweep_win = {n: tuple(block[a] + whalo[a][0] + whalo[a][1]
+                          if a == march else w for a, w in enumerate(win[n]))
+                 for n in field_names}
+    # the block's place in the (base-extent) frame where it is static
+    static_cut = tuple(
+        slice(whalo[a][0], whalo[a][0] + block[a]) if a == march
+        else slice(0, block[a]) for a in range(nd))
+
+    def blend(o, updates, windows, tgt, origin):
+        """The output ``o``'s update placed on the frame of ``tgt``,
+        blended with its previous values under the write mask, with the
+        output's fused boundary condition applied."""
+        fshape = windows[tgt].shape
+        modes, rings = write_geometry(
+            updates[o].shape, windows[o].shape, offsets[o], o, ring)
+        upd = _embed(updates[o].astype(dtype), fshape, rings)
+        mask = _valid_mask(origin, fshape, shapes[o], rings, modes)
+        blended = jnp.where(mask, upd, windows[tgt])
+        return _apply_bc_frame(blended, inkernel_bc.get(o), shapes[o],
+                               origin, dtype)
 
     def body(*refs):
         scal_refs = refs[:n_s]
         in_refs = refs[n_s : n_s + n_f]
-        out_refs = refs[n_s + n_f : n_s + n_f + n_out]
-        red_refs = refs[n_s + n_f + n_out : n_s + n_f + n_out + len(red_names)]
-        q_refs = refs[n_s + n_f + n_out + len(red_names) :]
+        rest = refs[n_s + n_f:]
+        out_refs = rest[:n_out]
+        red_refs = rest[n_out : n_out + len(red_names)]
+        rest = rest[n_out + len(red_names):]
+        frame_refs = rest[:n_out] if frame_bufs else ()
+        stage_refs = frame_refs if stage else (None,) * n_out
+        q_refs = rest[len(frame_refs):]
+        # each rotated field sweeps in its output's frame buffer (the
+        # final stage write comes after the last sweep has been read)
+        rot_refs = {rotations[o]: r.at[tuple(slice(0, w)
+                                             for w in sweep_win[rotations[o]])]
+                    for o, r in zip(out_names, frame_refs)} \
+            if nsteps > 1 else {}
         scalars = {n: ref[0] for n, ref in zip(scalar_names, scal_refs)}
-        if march is None:
-            pids = None
-            windows = {n: ref[...] for n, ref in zip(field_names, in_refs)}
-        else:
-            i = pl.program_id(nd - 1)
-            pids = tuple(
-                jnp.maximum(i - lhi_b, 0) if a == march
-                else pl.program_id(others.index(a))
+        lp = logical_pids(*(pl.program_id(g)
+                            for g in range(len(launch_grid))))
+        # logical block ids: the streamed march axis writes block i - Lhi
+        # while it fetches block i
+        pids = tuple(jnp.maximum(lp[a] - lhi_b, 0) if a == march else lp[a]
+                     for a in range(nd))
+        # global index of each frame's cell 0, per axis
+        origin = tuple(
+            0 if block[a] == shape[a]
+            else pids[a] * block[a] - whalo[a][0] if a == march
+            else window_start(lp[a], block[a], *whalo[a], shape[a])
+            if realign[a] else lp[a] * block[a]
+            for a in range(nd))
+        windows = {n: ref[...] for n, ref in zip(field_names, in_refs)}
+        if use_queue:
+            # Roll each field's plane queue by one block and append the
+            # newly fetched planes; the halo-extended march window of the
+            # *written* block (o = i - Lhi) is a static slice of the
+            # queue: queue plane q holds global plane (i - Q + 1)*bm + q.
+            bm = block[march]
+            tail = tuple(slice(bm, None) if a == march else slice(None)
+                         for a in range(nd))
+            qs = llo_b * bm - whalo[march][0]
+            wsl = tuple(
+                slice(qs, qs + bm + whalo[march][0] + whalo[march][1])
+                if a == march else slice(None)
                 for a in range(nd)
             )
-            if q_blocks == 1:
-                # Zero march halo: nothing to carry — the fetched block
-                # IS the window (streaming still sequences the axis).
-                windows = {n: ref[...] for n, ref in zip(field_names,
-                                                         in_refs)}
-            else:
-                # Roll each field's plane queue by one block and append
-                # the newly fetched planes; the halo-extended march window
-                # of the *written* block (o = i - Lhi) is a static slice
-                # of the queue: queue plane q holds global plane
-                # (i - Q + 1)*bm + q.
-                bm = block[march]
-                tail = tuple(slice(bm, None) if a == march else slice(None)
-                             for a in range(nd))
-                qs = llo_b * bm - whalo[march][0]
-                wsl = tuple(
-                    slice(qs, qs + bm + whalo[march][0] + whalo[march][1])
-                    if a == march else slice(None)
-                    for a in range(nd)
-                )
-                windows = {}
-                for n, in_ref, q_ref in zip(field_names, in_refs, q_refs):
-                    q = jnp.concatenate([q_ref[tail], in_ref[...]],
-                                        axis=march)
-                    q_ref[...] = q
-                    windows[n] = q[wsl]
-        for s in range(nsteps - 1):
-            updates = call_update(windows, scalars)
-            _check_updates(updates)
-            win_shapes = {n: w.shape for n, w in windows.items()}
-            m = nsteps - 1 - s  # remaining sweep margins after this sweep
-            ext = tuple((m * lo, m * hi) for lo, hi in sweep_halo)
-            windows = {n: _crop(w) for n, w in windows.items()}
-            for o in out_names:
-                tgt = rotations[o]
-                modes, rings = write_geometry(
-                    updates[o].shape, win_shapes[o], offsets[o], o, ring)
-                # Place the update on the cropped target frame: element u
-                # lands at u + ring - halo_lo per axis (`all`: crop the
-                # sweep's consumed halo; `inn`: the interior already lines
-                # up when ring == halo_lo, else _embed pads/crops).
-                frame = tuple(b - off + lo + hi for b, off, (lo, hi)
-                              in zip(block, offsets[o], ext))
-                upd = _embed(
-                    updates[o].astype(dtype), frame,
-                    tuple(w - lo for w, (lo, _) in zip(rings, sweep_halo)))
-                mask = _valid_mask(block, shapes[o], offsets[o], rings,
-                                   modes, ext, pids)
-                # Cells outside the mask (boundary ring of `inn` axes) keep
-                # carrying their previous values; a fused bc then rewrites
-                # that ring exactly like the post-pass would between steps.
-                blended = jnp.where(mask, upd, windows[tgt])
-                blended = _apply_bc_frame(blended, inkernel_bc.get(o),
-                                          shapes[o], block, ext, dtype, pids)
-                windows[tgt] = blended
+            for n, q_ref in zip(field_names, q_refs):
+                q = jnp.concatenate([q_ref[tail], windows[n]], axis=march)
+                q_ref[...] = q
+                windows[n] = q[wsl]
+        if rot_refs:
+            # Sweeps 1..k-1 run as one loop whose rotated fields live in
+            # VMEM scratch, so the sweep is emitted once (an unrolled
+            # chain grows Mosaic's code and compile time with k); the
+            # other windows are re-read from their refs each sweep.
+            queue_of = dict(zip(field_names, q_refs))
+            ref_of = dict(zip(field_names, in_refs))
+
+            def current():
+                return {n: rot_refs[n][...] if n in rot_refs
+                        else queue_of[n][wsl] if use_queue
+                        else ref_of[n][...] for n in field_names}
+
+            for t, r in rot_refs.items():
+                r[...] = windows[t]
+
+            def sweep(_, carry):
+                cur = current()
+                updates = call_update(cur, scalars)
+                _check_updates(updates)
+                # Cells outside the mask (boundary ring of `inn` axes)
+                # keep carrying their previous values; a fused bc then
+                # rewrites that ring exactly like the post-pass would
+                # between steps.
+                new = {rotations[o]: blend(o, updates, cur, rotations[o],
+                                           origin) for o in out_names}
+                for t, v in new.items():
+                    rot_refs[t][...] = v
+                return carry
+
+            jax.lax.fori_loop(0, nsteps - 1, sweep, 0)
+            windows = current()
         updates = call_update(windows, scalars)
         _check_updates(updates)
         blendeds = {}
-        for o, oref in zip(out_names, out_refs):
-            modes, rings = write_geometry(
-                updates[o].shape, windows[o].shape, offsets[o], o, ring)
-            # Lift update and previous values onto the out-block frame
-            # [pid*block, pid*block + block).
-            starts = tuple(w - lo for w, (lo, _) in zip(rings, sweep_halo))
-            upd = _embed(updates[o].astype(dtype), block, starts)
-            prev = _embed(windows[o],
-                          block, tuple(-lo for lo, _ in sweep_halo))
-            mask = _valid_mask(block, shapes[o], (0,) * nd, rings, modes,
-                               (0,) * nd, pids)
-            blended = jnp.where(mask, upd, prev)
-            blended = _apply_bc_frame(blended, inkernel_bc.get(o),
-                                      shapes[o], block, ((0, 0),) * nd,
-                                      dtype, pids)
-            oref[...] = blended
-            blendeds[o] = blended
-        # Fused reduction epilogue: fold each named check over the SAME
-        # out-block frame the write just produced — output operands are
-        # the blended values still live in registers/VMEM, input operands
-        # the window's block slice (the value the boundary copy reads) —
-        # masked to the operand's in-domain cells (each domain cell lies
-        # in exactly one out block, so the per-tile partials tile the
-        # whole-array reduction without overlap).
+        for o, oref, sref in zip(out_names, out_refs, stage_refs):
+            blendeds[o] = blend(o, updates, windows, o, origin)
+            # outputs are stored at the base extent: a staggered frame
+            # pads up to it (the pad is cropped on the way out)
+            full = _embed(blendeds[o], fext, (0,) * nd)
+            if sref is None:
+                oref[...] = full[static_cut]
+            else:
+                sref[...] = full
+                oref[...] = sref[tuple(
+                    pl.ds(pids[a] * block[a] - origin[a], block[a])
+                    if realign[a] else static_cut[a] for a in range(nd))]
+        # Fused reduction epilogue: fold each named check over the frame
+        # cells of this block — output operands are the blended values
+        # still live in VMEM, input operands the window (the value the
+        # boundary copy reads) — masked to the block's in-domain cells
+        # (each domain cell lies in exactly one block, so the per-tile
+        # partials tile the whole-array reduction without overlap).
         if red_names:
-            def frame_value(f):
-                if f in blendeds:
-                    return blendeds[f]
-                return _embed(windows[f], block,
-                              tuple(-lo for lo, _ in sweep_halo))
-
-            dom = _valid_mask(block, shape, (0,) * nd, (0,) * nd,
-                              ("all",) * nd, (0,) * nd, pids)
+            dom = None
+            for a in range(nd):
+                g = origin[a] + jax.lax.broadcasted_iota(jnp.int32, fext, a)
+                b0 = pids[a] * block[a]
+                ma = (g >= b0) & (g < b0 + block[a]) & (g < shape[a])
+                dom = ma if dom is None else (dom & ma)
             for rn, rref in zip(red_names, red_refs):
                 r = reductions[rn]
                 # operands lift to the accumulation dtype BEFORE the
                 # elementwise map: |T2 - T| and T*T happen at >= f32
                 # even when the blended storage values are bf16
-                mapped = r.map_element(*[frame_value(op).astype(acc_dtype)
-                                         for op in r.operands])
-                rref[...] = r.fold(mapped, dom).reshape((1,) * nd)
+                mapped = r.map_element(*[
+                    blendeds.get(op, windows[op]).astype(acc_dtype)
+                    for op in r.operands])
+                # one (8, 128) tile per grid step: the smallest block
+                # Mosaic stores; run() reads element [0, 0]
+                rref[...] = jnp.full(rref.shape, r.fold(mapped, dom),
+                                     acc_dtype)
 
-    # The march-axis fetch window carries no halo (streaming fetches new
-    # planes only; the halo planes are carried in the scratch queue).
-    field_halo = whalo if march is None else tuple(
-        (0, 0) if a == march else whalo[a] for a in range(nd))
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in scalar_names]
-    in_specs += [
-        halo_window_spec(
-            tuple(b - o for b, o in zip(block, offsets[n])),
-            field_halo,
-            in_index_map,
-        )
-        for n in field_names
-    ]
+    in_specs += [window_spec(fetch[n], in_index_map) for n in field_names]
     # Outputs are stored at the base extent (blocks tile it exactly) and
     # cropped back to their staggered extents on the way out. Reduction
-    # partials ride as one-scalar-per-tile outputs through the same
+    # partials ride as one (8, 128) tile per grid tile through the same
     # (lagged, on the streamed path) block index map.
     out_specs = [pl.BlockSpec(block, out_index_map) for _ in out_names]
     out_shape = [jax.ShapeDtypeStruct(shape, dtype) for _ in out_names]
-    out_specs += [pl.BlockSpec((1,) * nd, out_index_map) for _ in red_names]
+    out_specs += [pl.BlockSpec((1,) * nd + _PARTIAL_TILE,
+                               lambda *p: tuple(out_index_map(*p)) + (0, 0))
+                  for _ in red_names]
     # partials stay at the accumulation dtype all the way to finish():
     # rounding them through a bf16 output would undo the f32 folds
-    out_shape += [jax.ShapeDtypeStruct(grid, acc_dtype) for _ in red_names]
+    out_shape += [jax.ShapeDtypeStruct(grid + _PARTIAL_TILE, acc_dtype)
+                  for _ in red_names]
 
-    kwargs = {}
-    if march is not None and q_blocks > 1:
+    # One frame buffer per output: the stage its block is cut from at a
+    # per-block shift, and the rotated field's sweep-to-sweep buffer.
+    scratch = [pltpu.VMEM(fext, dtype) for _ in out_names] \
+        if frame_bufs else []
+    if use_queue:
         # One rolling plane queue per field, persisted across grid steps
         # (the march dimension is sequential, so the previous step's
         # planes are still live when the next block arrives).
-        kwargs["scratch_shapes"] = [
-            pltpu.VMEM(
-                tuple(q_blocks * block[march] if a == march
-                      else block[a] + whalo[a][0] + whalo[a][1] - offsets[n][a]
-                      for a in range(nd)),
-                dtype,
-            )
+        scratch += [
+            pltpu.VMEM(tuple(q_blocks * block[march] if a == march else w
+                             for a, w in enumerate(win[n])), dtype)
             for n in field_names
         ]
+    kwargs = {}
+    if scratch:
+        kwargs["scratch_shapes"] = scratch
     if not interpret:
-        cp = compiler_params(nd, march=march is not None)
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+        kwargs["compiler_params"] = compiler_params(
+            len(launch_grid), march=march is not None)
     call = pl.pallas_call(
         body,
         grid=launch_grid,
@@ -1077,7 +1185,7 @@ def build_stencil_call(
         if n_out + len(red_names) == 1:
             outs = [outs]
         outs = list(outs)
-        partials = outs[n_out:]
+        partials = [p[..., 0, 0] for p in outs[n_out:]]
         outs = [
             o[tuple(slice(0, s) for s in shapes[n])] if shapes[n] != shape else o
             for n, o in zip(out_names, outs[:n_out])
@@ -1107,11 +1215,14 @@ def build_stencil_call(
     run.march_axis = march
     run.march_fallback = march_fallback
     run.queue_planes = 0 if march is None else q_blocks * block[march]
+    offs = [offsets[n] for n in field_names]
     if march is None:
-        run.window_bytes = window_footprint_bytes(
-            block, whalo, [offsets[n] for n in field_names], dtype.itemsize)
+        run.window_bytes = window_footprint_bytes(block, whalo, offs,
+                                                  dtype.itemsize)
     else:
         run.window_bytes = streamed_footprint_bytes(
-            block, whalo, [offsets[n] for n in field_names], dtype.itemsize,
-            march)
+            block, whalo, offs, dtype.itemsize, march)
+    run.vmem_bytes = launch_footprint_bytes(
+        shape, block, whalo, offs, dtype.itemsize, n_out, march, nsteps,
+        compute_dtype.itemsize)
     return run

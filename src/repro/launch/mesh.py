@@ -11,15 +11,11 @@ state (the dry-run sets XLA_FLAGS *before* first jax init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    return {} if AxisType is None else {"axis_types": (AxisType.Auto,) * n_axes}
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -55,7 +55,7 @@ from ..distributed import fault
 __all__ = [
     "RendezvousError", "DistContext", "Supervisor", "SuperviseOutcome",
     "initialize", "free_port", "default_coordinator",
-    "kill_process", "heartbeat_ages",
+    "kill_process", "heartbeat_ages", "check_one_process_per_chip",
     "ENV_COORDINATOR", "ENV_NUM_PROCESSES", "ENV_PROCESS_ID",
     "ENV_RUN_ID", "ENV_HEARTBEAT_DIR",
     "STALE_EXIT_CODE", "DEADLINE_EXIT_CODE",
@@ -90,6 +90,43 @@ def free_port() -> int:
 
 def default_coordinator() -> str:
     return f"127.0.0.1:{free_port()}"
+
+
+def _chip_set(env: dict) -> Optional[frozenset]:
+    """The TPU chips a child with this environment would open: None when
+    it runs on another platform, every chip when it is not bound to its
+    own (``TPU_VISIBLE_CHIPS``)."""
+    plats = env.get("JAX_PLATFORMS", "")
+    if plats and "tpu" not in plats.split(","):
+        return None
+    bound = env.get("TPU_VISIBLE_CHIPS", "")
+    return frozenset(bound.split(",")) if bound else frozenset(["*"])
+
+
+def check_one_process_per_chip(envs: Sequence[dict], what: str) -> None:
+    """Fail fast where two of the child processes described by ``envs``
+    would open the same TPU chip of this host. A chip belongs to one
+    process: the second one to reach for it fails or hangs on the TPU
+    runtime's lock. Chips are counted from the PCI bus, so the caller
+    never initializes a JAX backend (which would itself hold them)."""
+    sets = [s for s in map(_chip_set, envs) if s is not None]
+    if len(sets) < 2:
+        return
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+
+    n_chips, _ = num_available_tpu_chips_and_device_id()
+    if not n_chips:
+        return
+    for i, a in enumerate(sets):
+        for b in sets[i + 1:]:
+            if "*" in a or "*" in b or a & b:
+                raise RuntimeError(
+                    f"{what}: {len(sets)} processes would share this "
+                    f"host's {n_chips} TPU chip(s), and a chip belongs to "
+                    "one process — run them on the CPU (JAX_PLATFORMS=cpu "
+                    "in their environment), give each its own chip "
+                    "(TPU_VISIBLE_CHIPS), or drive the chips from one "
+                    "process")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,11 +223,8 @@ def initialize(coordinator: Optional[str] = None,
     import jax
 
     if cpu_collectives:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except Exception:
-            pass  # older jax: option absent; CPU collectives unavailable
+        jax.config.update("jax_cpu_collectives_implementation",
+                          cpu_collectives)
 
     plan = fault.FaultPlan.active()
     state = {"attempt": 0}
@@ -345,12 +379,11 @@ class Supervisor:
     def _base_env(self, attempt_run_id: str, coordinator: str,
                   world: int) -> dict:
         env = dict(os.environ)
-        # each worker is exactly ONE process with ONE local CPU device;
+        # each worker is exactly ONE process with its own local device;
         # an inherited fake-device flag would multiply the global mesh
         flags = [f for f in env.get("XLA_FLAGS", "").split()
                  if not f.startswith("--xla_force_host_platform_device_count")]
         env["XLA_FLAGS"] = " ".join(flags)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.pop(fault.PLAN_ENV, None)   # plans are per-rank, via rank_env
         env[ENV_COORDINATOR] = coordinator
         env[ENV_NUM_PROCESSES] = str(world)
@@ -366,15 +399,19 @@ class Supervisor:
         fault.Heartbeat.retire_stale(self.heartbeat_dir)
         coordinator = default_coordinator()
         base = self._base_env(attempt_run_id, coordinator, world)
+        envs = []
+        for rank in range(world):
+            env = dict(base)
+            env[ENV_PROCESS_ID] = str(rank)
+            if self.rank_env is not None:
+                env.update(self.rank_env(rank, world, attempt) or {})
+            envs.append(env)
+        check_one_process_per_chip(envs, f"gang of {world} ranks")
         t0 = time.monotonic()
         procs: dict[int, subprocess.Popen] = {}
         logs = []
         try:
-            for rank in range(world):
-                env = dict(base)
-                env[ENV_PROCESS_ID] = str(rank)
-                if self.rank_env is not None:
-                    env.update(self.rank_env(rank, world, attempt) or {})
+            for rank, env in enumerate(envs):
                 log = open(os.path.join(
                     self.heartbeat_dir, f"{attempt_run_id}.rank{rank}.log"),
                     "wb")

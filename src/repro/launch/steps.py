@@ -92,9 +92,7 @@ def cache_shardings(cache_shapes, mesh: Mesh, rules: shd.ShardRules,
         logical = [None, "batch"] + [None] * (s.ndim - 2)
         return NamedSharding(mesh, shd.logical_to_spec(mesh, rules, logical, s.shape))
 
-    from ..compat import tree_flatten_with_path
-
-    flat, treedef = tree_flatten_with_path(cache_shapes)
+    flat, treedef = jax.tree.flatten_with_path(cache_shapes)
     out = []
     for kp, v in flat:
         path = tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in kp)

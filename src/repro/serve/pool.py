@@ -36,7 +36,8 @@ from typing import Optional
 
 from ..distributed import fault
 from ..launch.multihost import (ENV_HEARTBEAT_DIR, ENV_PROCESS_ID,
-                                ENV_RUN_ID, heartbeat_ages, kill_process)
+                                ENV_RUN_ID, check_one_process_per_chip,
+                                heartbeat_ages, kill_process)
 from .errors import ServerClosed, WorkerDied
 from .procworker import CLOSED_MARKER, read_result, write_request
 
@@ -120,6 +121,9 @@ class ProcessWorkerPool:
         marker = os.path.join(self.spool, CLOSED_MARKER)
         if os.path.exists(marker):
             os.unlink(marker)
+        check_one_process_per_chip(
+            [self._child_env(r) for r in range(self.n_workers)],
+            f"pool of {self.n_workers} workers")
         for rank in range(self.n_workers):
             self._spawn(rank)
         self._watcher = threading.Thread(target=self._watch,
@@ -127,17 +131,20 @@ class ProcessWorkerPool:
         self._watcher.start()
         return self
 
-    def _spawn(self, rank: int) -> None:
+    def _child_env(self, rank: int) -> dict:
         env = dict(os.environ)
         flags = [f for f in env.get("XLA_FLAGS", "").split()
                  if not f.startswith("--xla_force_host_platform_device_count")]
         env["XLA_FLAGS"] = " ".join(flags)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.pop(fault.PLAN_ENV, None)   # plans reach workers via self.env
         env[ENV_PROCESS_ID] = str(rank)
         env[ENV_RUN_ID] = self.run_id
         env[ENV_HEARTBEAT_DIR] = self.heartbeat_dir
         env.update(self.env)
+        return env
+
+    def _spawn(self, rank: int) -> None:
+        env = self._child_env(rank)
         self._procs[rank] = subprocess.Popen(
             [sys.executable, "-m", "repro.serve.procworker",
              "--spool", self.spool, "--kernel", self.kernel,

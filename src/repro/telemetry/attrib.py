@@ -15,8 +15,9 @@ near 1.0 means the launch runs at its modeled roofline; 0.58 means "this
 kernel leaves 42% of its modeled throughput on the table" — a first-class
 metric instead of an offline bench artifact.
 
-The hardware spec defaults per jax backend (TPU -> v5e constants, GPU ->
-A100, CPU -> a cached STREAM-copy measurement) and can be pinned with
+The hardware spec is the accelerator's published peaks, looked up by
+``device_kind`` in ``teff.DEVICE_PEAKS`` (an unknown kind is an error),
+or on CPU a cached STREAM-copy measurement; it can be pinned with
 ``REPRO_TELEMETRY_BW_GBS`` / ``REPRO_TELEMETRY_FLOPS_G`` so CI numbers
 don't depend on a noisy runner measurement.
 """
@@ -48,16 +49,11 @@ def default_hardware():
         flops = float(fl_env) * 1e9 if fl_env else 8.0 * bw
         hw = teff.HardwareSpec("pinned", peak_bw=bw, peak_flops=flops)
     else:
-        try:
-            import jax
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-        if backend == "tpu":
-            hw = teff.TPU_V5E
-        elif backend == "gpu":
-            hw = teff.A100_SXM4
+        dev = jax.devices()[0]
+        if dev.platform != "cpu":
+            hw = teff.device_peaks(dev.device_kind)
         else:
             bw = teff.measure_host_bandwidth()
             flops = float(fl_env) * 1e9 if fl_env else 8.0 * bw

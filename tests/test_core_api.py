@@ -125,3 +125,29 @@ def test_human_bytes():
     assert human_bytes(512) == "512.00 B"
     assert human_bytes(2 * 1024 ** 3) == "2.00 GiB"
     assert volume_bytes((4, 4), jnp.float32) == 64
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placed_from_outside(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing else
+    is configured; otherwise the cache sits at <checkout>/.jax_cache."""
+    import os
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV, env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

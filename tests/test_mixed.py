@@ -257,7 +257,9 @@ def test_int8_roundtrip_error_bound_hypothesis():
     @given(hnp.arrays(np.float32, hnp.array_shapes(min_dims=1, max_dims=2,
                                                    min_side=1, max_side=300),
                       elements={"allow_nan": False, "allow_infinity": False,
-                                "min_value": -1e30, "max_value": 1e30}))
+                                # float32-exact bounds (2**100 ~ 1.27e30)
+                                "min_value": -2.0 ** 100,
+                                "max_value": 2.0 ** 100}))
     def check(x):
         g = jnp.asarray(x)
         q, scale, meta = compression.quantize_int8(g)

@@ -40,6 +40,34 @@ def run_child(code: str, env_extra: dict | None = None,
                           text=True, timeout=timeout, env=env)
 
 
+@pytest.mark.parametrize("envs,shared", [
+    ([{}, {}], True),                                  # both default to TPU
+    ([{"JAX_PLATFORMS": "tpu"}, {"TPU_VISIBLE_CHIPS": "0"}], True),
+    ([{"TPU_VISIBLE_CHIPS": "0,1"}, {"TPU_VISIBLE_CHIPS": "1"}], True),
+    ([{"TPU_VISIBLE_CHIPS": "0"}, {"TPU_VISIBLE_CHIPS": "1"}], False),
+    ([{"JAX_PLATFORMS": "cpu"}, {"JAX_PLATFORMS": "cpu"}], False),
+    ([{}], False),
+])
+def test_one_process_per_chip(monkeypatch, envs, shared):
+    """On a host with TPU chips, children that would open the same chip
+    are refused before any is started; CPU children and children bound
+    to disjoint chips pass."""
+    from jax._src import hardware_utils
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (4, None))
+    if shared:
+        with pytest.raises(RuntimeError, match="a chip belongs to one"):
+            multihost.check_one_process_per_chip(envs, "gang")
+    else:
+        multihost.check_one_process_per_chip(envs, "gang")
+    # a host without chips never refuses
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (0, None))
+    multihost.check_one_process_per_chip(envs, "gang")
+
+
 # ---------------------------------------------------------------------------
 # run-id heartbeat namespacing + stale retirement (satellite 1)
 # ---------------------------------------------------------------------------

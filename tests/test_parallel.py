@@ -8,6 +8,7 @@ import pytest
 from repro.core import Grid, FieldSet, fd2d, fd3d, init_parallel_stencil
 from repro.kernels import ref
 from repro.kernels.stencil import (LaunchFootprintError, derive_launch,
+                                   launch_footprint_bytes,
                                    preflight_vmem)
 
 
@@ -149,14 +150,19 @@ def test_preflight_rejects_oversized_explicit_tile():
     assert "REPRO_VMEM_LIMIT_BYTES" in msg
     # the same footprint is admitted when the device really has the room
     derive_launch(shape, 1, 3, 4, tile=(512, 512, 512),
-                  vmem_limit=4 << 30)
+                  vmem_limit=8 << 30)
     # LaunchFootprintError IS a ValueError: existing callers' handlers hold
     assert issubclass(LaunchFootprintError, ValueError)
 
 
 def test_preflight_env_override(monkeypatch):
     tile = (64, 64, 64)
-    window = 3 * int(np.prod([b + 2 for b in tile])) * 4
+    # the whole launch as Mosaic allocates it: double-buffered windows and
+    # output block plus the body's intermediates (the tile spans the
+    # array, so no axis carries a halo)
+    window = launch_footprint_bytes((64, 64, 64), tile, 1, [(0, 0, 0)] * 3,
+                                    4, 1)
+    assert window >= 2 * 4 * 4 * 64 ** 3
     monkeypatch.setenv("REPRO_VMEM_LIMIT_BYTES", str(window - 1))
     with pytest.raises(LaunchFootprintError):
         derive_launch((64, 64, 64), 1, 3, 4, tile=tile)

@@ -305,6 +305,16 @@ def test_default_hardware_env_pin(monkeypatch):
     attrib.reset_hardware_cache()
 
 
+def test_device_peaks_keyed_by_kind():
+    from repro.core import teff
+    # JAX reports a v5e chip as "TPU v5 lite"
+    assert teff.device_peaks("TPU v5 lite") is teff.TPU_V5E
+    assert teff.TPU_V5E.peak_bw == pytest.approx(819e9)
+    # an unknown accelerator is an error, never a default peak
+    with pytest.raises(ValueError, match="TPU v9"):
+        teff.device_peaks("TPU v9")
+
+
 # ------------------------------------------------------------- halo bytes
 def test_exchange_byte_counts_hand_checked():
     shapes = {"A": (8, 6), "B": (8, 6)}

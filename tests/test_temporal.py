@@ -176,6 +176,33 @@ def test_autotune_picks_and_caches(tmp_path):
     assert len(calls) == n_calls
 
 
+def test_autotune_skips_only_unrealizable_tiles_on_device():
+    """Compiled for the device, a candidate that raises anything but
+    UnrealizableTile is a fault the tuner re-raises; interpret mode keeps
+    skipping it."""
+    from repro.kernels.stencil import UnrealizableTile
+
+    def make_step(fail):
+        def build(tile, k):
+            if k == 2:
+                raise fail
+            return lambda: jnp.zeros(())
+        return build
+
+    kw = dict(shape=(16, 16, 16), dtype="float32", radius=1, n_fields=3,
+              nsteps_candidates=(1, 2), iters=1)
+    autotune._CACHE.clear()
+    r = autotune.autotune(make_step(UnrealizableTile("no")), tag="u1",
+                          interpret=False, **kw)
+    assert r.nsteps == 1
+    with pytest.raises(RuntimeError, match="refused"):
+        autotune.autotune(make_step(RuntimeError("Mosaic refused it")),
+                          tag="u2", interpret=False, **kw)
+    r = autotune.autotune(make_step(RuntimeError("Mosaic refused it")),
+                          tag="u3", interpret=True, **kw)
+    assert r.nsteps == 1
+
+
 def test_autotune_diffusion3d_smoke():
     r = autotune.autotune_diffusion3d((16, 16, 16), nsteps_candidates=(1, 2),
                                       iters=1)
