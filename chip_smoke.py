@@ -4,8 +4,8 @@ and check every result against a plain reference run on the same chip.
 
     python3 chip_smoke.py              # one chip, phases (a)-(d)
     python3 chip_smoke.py --chips 4    # four chips: elastic 2x2x1 solve
-                                       # (512x512x254 interior per chip)
-                                       # against the same grid on one chip
+                                       # (512^3 interior per chip) against
+                                       # the same grid on one chip
 
 One-chip phases, all through the public API (``init_parallel_stencil``
 with ``backend="pallas", interpret=False``, ``@parallel``, ``run_steps``,
@@ -65,6 +65,7 @@ def fig1_state(shape, seed: int):
     """Fig. 1's initial state (T = 1.7 plus a Gaussian hot spot of width
     0.1, Ci = 1/c0) on the unit cube, the spot's centre and amplitude
     drawn from ``seed``, and its scalars."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -75,8 +76,12 @@ def fig1_state(shape, seed: int):
     rng = np.random.default_rng(seed)
     centre = rng.uniform(0.4, 0.6, 3)
     amp = rng.uniform(0.5, 1.5)
-    r2 = sum((x - c) ** 2 for x, c in zip(grid.meshgrid(), centre))
-    T = 1.7 + amp * jnp.exp(-r2 / (2 * 0.1 ** 2))
+
+    def hot_spot():   # jitted: no full-size coordinate arrays on the chip
+        r2 = sum((x - c) ** 2 for x, c in zip(grid.meshgrid(), centre))
+        return 1.7 + amp * jnp.exp(-r2 / (2 * 0.1 ** 2))
+
+    T = jax.jit(hot_spot)()
     Ci = jnp.full(grid.shape, 1.0 / c0, jnp.float32)
     _dx, _dy, _dz = grid.inv_spacing
     scalars = dict(lam=lam, dt=grid.stable_diffusion_dt(lam / c0),
@@ -144,7 +149,7 @@ def phase_a(ps, n: int, nt: int, seed: int, on_tpu: bool) -> None:
         def run(T2, T, Ci):
             def body(_, c):
                 T2, T = c
-                return stepper(T2, T, Ci), T2
+                return T, stepper(T2, T, Ci)
             return jax.lax.fori_loop(0, nt, body, (T2, T))[1]
         return run
 
@@ -282,67 +287,83 @@ def phase_d(ps, n: int, seed: int) -> None:
 
 
 def four_chips(ps, n: int, seed: int, on_tpu: bool) -> None:
-    """elastic_solve_until on a 2x2x1 mesh, an (n, n, n/2 - 2) interior
-    per chip, against solve_until of the same global grid on one chip.
+    """elastic_solve_until on a 2x2x1 mesh, an n^3 interior per chip,
+    against the plain-jnp Fig. 1 step (``kernels/ref.py``) over the same
+    global grid on one chip.
 
-    The depth is halved because the one-chip reference must hold the
-    whole grid: solve_until over 4 x 512^3 needs 19.45 GB of HBM
-    (compiled for a v5e), over the chip's 15.75 GB."""
+    The reference is a host loop of single steps, each donating its write
+    buffer: 9.48 GB of HBM at n=512 (compiled for a v5e). ``solve_until``
+    over that grid would need 19.45 GB, over the chip's 15.75 GB."""
     import jax
+    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.core import solve_until
     from repro.distributed import elastic
+    from repro.kernels import ref
     from repro.launch.mesh import make_mesh
 
     factors, every, max_iters = (2, 2, 1), 10, 20
+    axes = elastic.DEFAULT_AXES[:len(factors)]
     devs = jax.devices()
     if len(devs) < 4:
         fail(f"--chips 4 needs four devices, JAX has {len(devs)}")
-    shape = (2 * n + 2, 2 * n + 2, n // 2)
+    shape = (2 * n + 2, 2 * n + 2, n + 2)
     conv = fig1_kernel(ps, {"err": "max_abs_diff(T2, T)"})
     f, s = fig1_state(shape, seed)
-
-    t0 = time.perf_counter()
-    one = solve_until(conv, f, s, tol=0.0, max_iters=max_iters,
-                      check_every=every)
-    want = np.asarray(one.output(conv))
-    one_s = time.perf_counter() - t0
-    del one
     host = {k: np.asarray(v) for k, v in f.items()}
     del f
 
-    # where the decomposed state lives: one rank block per chip
-    mesh = make_mesh(factors, elastic.DEFAULT_AXES)
-    probe = elastic.remesh(
-        elastic.decompose_fields({"T": host["T"]}, factors, 1), mesh,
-        {"T": P(*elastic.DEFAULT_AXES, None, None, None)})["T"]
-    shard_devs = sorted(str(sh.device) for sh in probe.addressable_shards)
-    local = list(probe.addressable_shards[0].data.shape[len(factors):])
-    del probe
+    step = jax.jit(lambda T2, T, Ci: ref.diffusion3d_step(
+        T2, T, Ci, s["lam"], s["dt"], s["_dx"], s["_dy"], s["_dz"]),
+        donate_argnums=0)
+    t0 = time.perf_counter()
+    T2, T, Ci = (jnp.asarray(host[k]) for k in ("T2", "T", "Ci"))
+    for _ in range(max_iters):
+        T2, T = T, step(T2, T, Ci)
+    want, want_err = np.asarray(T), float(jnp.max(jnp.abs(T - T2)))
+    one_s = time.perf_counter() - t0
+    del T2, T, Ci
+
+    # the decomposed state: one rank block per chip
+    mesh = make_mesh(factors, axes)
+    stacked = elastic.remesh(
+        elastic.decompose_fields(host, factors, 1), mesh,
+        {k: P(*axes, None, None, None) for k in host})
+    shard_devs = sorted(str(sh.device)
+                        for sh in stacked["T"].addressable_shards)
+    local = list(stacked["T"].addressable_shards[0].data.shape[len(factors):])
     distinct = len(set(shard_devs)) == 4
     platforms = sorted({d.platform for d in mesh.devices.flat})
+    # the chunk driver elastic_solve_until runs, compiled for this mesh
+    solver = elastic.make_elastic_solver(conv, s, mesh, factors, axes,
+                                         ("T",), check_every=every)
+    _, c_s, has_kernel = compile_timed(solver, stacked, jnp.float32(0.0),
+                                       jnp.int32(max_iters))
+    del stacked
 
     t0 = time.perf_counter()
     res = elastic.elastic_solve_until(
         conv, host, s, factors=factors, tol=0.0, max_iters=max_iters,
-        exchange=("T",), check_every=every)
+        exchange=("T",), check_every=every, radius=1)
     got = np.asarray(res.output(conv))
     el_s = time.perf_counter() - t0
-    rec = {"what": "elastic_solve_until on a 2x2x1 mesh vs solve_until "
-                   "of the same global grid on one chip",
+    rec = {"what": "elastic_solve_until on a 2x2x1 mesh vs kernels/ref.py "
+                   "diffusion3d_step over the same global grid on one chip",
            "global_shape": list(shape), "local_shape": local,
-           "iters": [int(res.iters), max_iters], "shard_devices": shard_devs,
-           "mesh_platforms": platforms, "elastic_wall_s": el_s,
+           "iters": [int(res.iters), max_iters], "err": float(res.err),
+           "ref_err": want_err, "shard_devices": shard_devs,
+           "mesh_platforms": platforms, "compile_s": c_s,
+           "tpu_custom_call": has_kernel, "elastic_wall_s": el_s,
            "one_chip_wall_s": one_s}
     why = None
     if not distinct or (on_tpu and platforms != ["tpu"]):
         why = "shards do not sit on four distinct TPU devices"
     elif int(res.iters) != max_iters:
         why = "iteration count differs"
-    check("4chips", rec, max_err(got, want), ATOL, kernel_expected=False,
-          why=why)
+    check("4chips", rec,
+          max(max_err(got, want), abs(float(res.err) - want_err)), ATOL,
+          on_tpu, why=why)
 
 
 def main(argv=None) -> int:

@@ -263,12 +263,14 @@ class StencilKernel:
         return v
 
     def apply_reductions(self, outs: Mapping[str, Any],
-                         fields: Mapping[str, Any]) -> dict[str, Any]:
+                         fields: Mapping[str, Any],
+                         mask=None) -> dict[str, Any]:
         """The post-pass reference realization of this kernel's
         reductions: whole-array folds over the final outputs (``outs``)
         and the pre-step fields — exactly what a separate norm pass
-        computes. The fused epilogue is tested ``allclose`` against this
-        (bitwise only holds within one compiled program)."""
+        computes — restricted to the cells of ``mask`` when given. The
+        fused epilogue is tested ``allclose`` against this (bitwise only
+        holds within one compiled program)."""
         reds = {}
         acc = self.ps.acc_dtype
         for name, r in self.reductions.items():
@@ -277,7 +279,7 @@ class StencilKernel:
             # increments and the convergence signal is gone)
             ops = [(outs[op] if op in outs else fields[op]).astype(acc)
                    for op in r.operands]
-            reds[name] = r.fold(r.map_element(*ops))
+            reds[name] = r.fold(r.map_element(*ops), mask)
         return reds
 
     # -- argument classification ------------------------------------------

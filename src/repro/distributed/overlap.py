@@ -90,16 +90,46 @@ def finish_reductions(kernel: StencilKernel, reds: Mapping[str, jax.Array],
     valid partials — the combines are associative). Rank-local (inside
     ``shard_map``).
 
-    Ownership contract: ``max``-combine kinds (``max_abs``,
-    ``max_abs_diff``) are exact under the repo's ghost-ring
-    decomposition — ghost cells duplicate neighbor values (or carry an
-    unchanged physical ring whose diff is 0), and duplicates cannot
-    change a max. ``sum``-combine kinds are exact over *disjoint* rank
-    domains; with allocated ghost rings the psum double-counts the
-    overlap, so conserved-quantity sums should be folded on ghost-free
-    shards (or corrected by the caller)."""
+    A kernel's fused values fold its whole local array, ghost rings
+    included, and an output's ghost ring still holds the previous step
+    (the update writes the interior only): combined this way, a check
+    mixes in a stale step and sums count the overlap twice.
+    :func:`owned_reductions` folds the owned cells only."""
     return {n: kernel.reductions[n].all_reduce(v, mesh_axes)
             for n, v in reds.items()}
+
+
+def owned_mask(shape: Sequence[int], mesh_axes: Sequence[str],
+               ghost: int) -> jax.Array:
+    """The cells of a rank-local array this rank owns — those
+    :func:`..halo.local_to_global` stitches into the global array: a
+    ``ghost``-deep ring is dropped on every side that faces another rank
+    along a decomposed axis, the physical boundary ring is kept.
+    Rank-local (inside ``shard_map``)."""
+    mask = jnp.ones(tuple(shape), bool)
+    for a, ax in enumerate(mesh_axes):
+        n, i = shape[a], jax.lax.axis_index(ax)
+        last = jax.lax.axis_size(ax) - 1
+        g = jax.lax.broadcasted_iota(jnp.int32, tuple(shape), a)
+        mask &= ((g >= jnp.where(i == 0, 0, ghost))
+                 & (g < jnp.where(i == last, n, n - ghost)))
+    return mask
+
+
+def owned_reductions(kernel: StencilKernel, outs: Mapping[str, jax.Array],
+                     fields: Mapping[str, jax.Array],
+                     mesh_axes: Sequence[str], ghost: int
+                     ) -> dict[str, jax.Array]:
+    """The kernel's reductions over the cells each rank owns
+    (:func:`owned_mask`), each combined across ranks by ONE ``pmax`` /
+    ``psum``: the value the whole-array fold of the gathered global
+    arrays gives. ``outs`` are the step's outputs by name, ``fields`` its
+    inputs with fresh ghost rings. Rank-local (inside ``shard_map``)."""
+    op = next(iter(kernel.reductions.values())).operands[0]
+    shape = (outs[op] if op in outs else fields[op]).shape
+    reds = kernel.apply_reductions(outs, fields,
+                                   owned_mask(shape, mesh_axes, ghost))
+    return finish_reductions(kernel, reds, mesh_axes)
 
 
 class MonitoredStepper:
@@ -166,8 +196,8 @@ def sequential_step(
 ):
     """Reference: exchange halos, then update. No overlap. A kernel with
     fused reductions returns ``((outs, reds), fresh)`` with the rank
-    partials already combined across ranks (:func:`finish_reductions`) —
-    the whole convergence check costs one collective scalar.
+    reductions folded over the cells each rank owns and combined across
+    ranks (:func:`owned_reductions`) — one collective scalar each.
     ``halo_compress`` selects the ghost wire format (``"bf16"``/
     ``"int8"`` — see :func:`..halo.halo_exchange`)."""
     r, depths, _ = _kernel_geometry(kernel, fields, scalars, exchange,
@@ -175,11 +205,11 @@ def sequential_step(
     fresh = _halo.exchange_many(fields, exchange, mesh_axes, radius=r,
                                 periodic=periodic, depths=depths,
                                 compress=halo_compress)
-    res = kernel(**fresh, **scalars)
-    if kernel.reductions:
-        outs, reds = res
-        res = (outs, finish_reductions(kernel, reds, mesh_axes))
-    return res, fresh
+    if not kernel.reductions:
+        return kernel(**fresh, **scalars), fresh
+    outs = kernel.with_reductions(None)(**fresh, **scalars)
+    named = {kernel.outputs[0]: outs} if len(kernel.outputs) == 1 else outs
+    return (outs, owned_reductions(kernel, named, fresh, mesh_axes, r)), fresh
 
 
 def multi_step(
@@ -203,7 +233,8 @@ def multi_step(
     per field, axis and side instead of the full ``k*r``. The ghost ring
     is stale afterwards and must be re-exchanged before the next k-step
     block. Rank-local (inside shard_map). Returns (final outputs, fresh
-    fields).
+    fields). Fused reductions are combined by :func:`finish_reductions`,
+    so they fold the stale ghost ring too: not the global check.
     """
     r, depths, _ = _kernel_geometry(kernel, fields, scalars, exchange,
                                     mesh_axes)
@@ -249,10 +280,10 @@ def overlapped_step(
     Fused reductions: the bulk launch's partials would fold stale-halo
     shell cells that the face re-updates are about to overwrite, so the
     overlapped path runs the reduction-free kernel variants and folds
-    the reductions over the *pasted* outputs instead
-    (``kernel.apply_reductions`` — whole-array jnp folds fused into the
-    surrounding jit, then one :func:`finish_reductions` collective);
-    returns ``((outs, reds), fresh)`` like :func:`sequential_step`.
+    the reductions over the *pasted* outputs' owned cells instead
+    (:func:`owned_reductions` — jnp folds fused into the surrounding jit,
+    then one collective); returns ``((outs, reds), fresh)`` like
+    :func:`sequential_step`.
     """
     r, _, ir = _kernel_geometry(kernel, fields, scalars, exchange,
                                 mesh_axes)
@@ -316,6 +347,5 @@ def overlapped_step(
                 bulk[o] = _paste_shell(bulk[o], slab_out[o], axis, side, r)
     res = bulk[kernel.outputs[0]] if single else bulk
     if kernel.reductions:
-        reds = kernel.apply_reductions(bulk, fresh)
-        res = (res, finish_reductions(kernel, reds, mesh_axes))
+        res = (res, owned_reductions(kernel, bulk, fresh, mesh_axes, r))
     return res, fresh

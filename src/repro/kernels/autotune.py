@@ -9,8 +9,9 @@ n_fields) — so the search cost is paid once per problem class per process
 
 The candidate space is deliberately small and deterministic:
 
-  * tiles — the analytically-derived block plus a few divisor-preserving
-    perturbations of the non-minor axes (the minor axis stays lane-aligned);
+  * tiles — the analytically-derived block of the TPU layout plus a few
+    divisor-preserving perturbations of the tiled leading axes (the two
+    minor axes stay whole, as Mosaic needs them);
   * nsteps — temporal-blocking depths; per-step time is what is compared,
     so a k-fused candidate wins only when its redundant halo compute is
     cheaper than the HBM traffic it saves.
@@ -72,7 +73,12 @@ def _divisors(n: int) -> list[int]:
     return [d for d in range(1, n + 1) if n % d == 0]
 
 
-_window_bytes = _stencil.window_footprint_bytes
+def _footprint(shape, tile, radius, field_offsets, itemsize, nsteps=1,
+               march_axis=None):
+    """VMEM the launch allocates (one output), as the preflight counts it."""
+    return _stencil.launch_footprint_bytes(
+        shape, tile, radius * nsteps, field_offsets, itemsize, 1,
+        march_axis, nsteps)
 
 
 def tile_candidates(
@@ -84,25 +90,28 @@ def tile_candidates(
     max_candidates: int = 4,
     field_offsets: Sequence[Sequence[int]] | None = None,
 ) -> list[tuple[int, ...]]:
-    """Derived block plus divisor-preserving halvings/doublings of the
-    leading (non-minor) axes, all within the VMEM budget. The budget is
-    checked against the *full coupled field set's* window footprint
-    (``field_offsets``: one staggering tuple per field; defaults to
-    ``n_fields`` collocated fields)."""
+    """The derived block of the TPU layout (the two minor axes whole) plus
+    divisor-preserving halvings/doublings of the tiled leading axes, each
+    within the VMEM budget. The budget is checked against the launch's
+    whole footprint for the *full coupled field set* (``field_offsets``:
+    one staggering tuple per field; defaults to ``n_fields`` collocated
+    fields)."""
     shape = tuple(int(s) for s in shape)
     nd = len(shape)
     if field_offsets is None:
         field_offsets = [(0,) * nd] * n_fields
     _, base = _stencil.derive_launch(shape, radius, n_fields, itemsize,
                                      vmem_budget,
-                                     field_offsets=field_offsets)
-    halo = radius
+                                     field_offsets=field_offsets,
+                                     tpu_layout=True)
 
     def fits(blk):
-        return _window_bytes(blk, halo, field_offsets, itemsize) <= vmem_budget
+        return _footprint(shape, blk, radius, field_offsets,
+                          itemsize) <= vmem_budget
 
+    whole = _stencil.tpu_whole_axes(nd)
     cands = [base]
-    for axis in range(max(nd - 1, 1)):
+    for axis in (a for a in range(nd) if a not in whole):
         for factor in (2, 0.5):
             b = int(base[axis] * factor)
             if b < 1 or b > shape[axis] or shape[axis] % b:
@@ -111,6 +120,41 @@ def tile_candidates(
             if fits(cand) and cand not in cands:
                 cands.append(cand)
     return cands[:max_candidates]
+
+
+def launch_candidates(
+    shape: Sequence[int],
+    radius: int,
+    n_fields: int,
+    itemsize: int,
+    nsteps_candidates: Sequence[int],
+    tiles: Sequence[Sequence[int]] | None = None,
+    vmem_budget: int = _stencil.DEFAULT_VMEM_BUDGET,
+    field_offsets: Sequence[Sequence[int]] | None = None,
+    march_candidates: Sequence[int | None] | None = None,
+) -> list[tuple[tuple[int, ...], int, int | None]]:
+    """The (tile, nsteps, march_axis) configurations :func:`autotune`
+    measures before pruning: ``tiles`` (default :func:`tile_candidates`)
+    crossed with the depths and march axes. Derived tiles are kept only
+    where the launch at that depth fits ``vmem_budget`` — temporal
+    blocking widens the halo to k*radius, summed over the full coupled
+    field set, and streamed candidates are costed with their plane
+    queues instead of march-axis halos. Explicitly-passed tiles bypass
+    the budget: the caller may be tuning a backend where it is
+    irrelevant, e.g. jnp."""
+    nd = len(tuple(shape))
+    offs = (field_offsets if field_offsets is not None
+            else [(0,) * nd] * n_fields)
+    derived_tiles = tiles is None
+    if derived_tiles:
+        tiles = tile_candidates(shape, radius, n_fields, itemsize, vmem_budget,
+                                field_offsets=field_offsets)
+    marches = (None,) if march_candidates is None else tuple(march_candidates)
+    return [(tuple(int(b) for b in tile), int(k), march)
+            for tile in tiles for k in nsteps_candidates for march in marches
+            if not derived_tiles or _footprint(
+                shape, tile, radius, offs, itemsize, int(k),
+                march) <= vmem_budget]
 
 
 def cache_key(shape, dtype, radius: int, n_fields: int, tag: str = "",
@@ -182,7 +226,6 @@ def autotune(
     reductions: Sequence[str] | None = None,
     check_every: int | None = None,
     compute_dtype=None,
-    interpret: bool | None = None,
 ) -> TuneResult:
     """Find the fastest (tile, nsteps[, march_axis]) for a stencil
     problem class.
@@ -221,12 +264,9 @@ def autotune(
 
     A candidate whose tile the launch cannot realize by construction
     (:class:`~repro.kernels.stencil.UnrealizableTile`) is skipped. Any
-    other failure is re-raised when the candidates compile for the device
-    (``interpret`` false; default: whenever the backend is a TPU) — a
-    kernel the compiler refuses is a fault, not a slow candidate.
+    other failure is re-raised — a kernel the compiler refuses is a
+    fault, not a slow candidate.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     prune_tag = (None if cost_model is None or hw is None
                  else (getattr(hw, "name", "hw"), float(prune_ratio)))
     # Every tune keys the FULL (storage, compute) dtype pair — the v4
@@ -263,38 +303,10 @@ def autotune(
             return hit
 
     itemsize = jnp.dtype(dtype).itemsize if itemsize is None else itemsize
-    nd = len(tuple(shape))
-    offs = (field_offsets if field_offsets is not None
-            else [(0,) * nd] * n_fields)
-    derived_tiles = tiles is None
-    if derived_tiles:
-        tiles = tile_candidates(shape, radius, n_fields, itemsize, vmem_budget,
-                                field_offsets=field_offsets)
     pass_march = march_candidates is not None
-    marches = (None,) if march_candidates is None else tuple(march_candidates)
-    cands: list[tuple[tuple[int, ...], int, int | None]] = []
-    for tile in tiles:
-        tile = tuple(int(b) for b in tile)
-        for k in nsteps_candidates:
-            k = int(k)
-            for march in marches:
-                if derived_tiles:
-                    # Temporal blocking widens the halo to k*radius; enforce
-                    # the VMEM budget at the depth actually being measured,
-                    # summed over the full coupled field set — streamed
-                    # candidates are costed with their plane queues instead
-                    # of march-axis halos.
-                    # (Explicitly-passed tiles bypass this: the caller may
-                    # be tuning a backend where the budget is irrelevant,
-                    # e.g. jnp.)
-                    if march is None:
-                        wb = _window_bytes(tile, radius * k, offs, itemsize)
-                    else:
-                        wb = _stencil.streamed_footprint_bytes(
-                            tile, radius * k, offs, itemsize, march)
-                    if wb > vmem_budget:
-                        continue
-                cands.append((tile, k, march))
+    cands = launch_candidates(shape, radius, n_fields, itemsize,
+                              nsteps_candidates, tiles, vmem_budget,
+                              field_offsets, march_candidates)
     pruned = 0
     if prune_tag is not None and len(cands) > 1:
         preds = {c: cost_model.predict_per_step_s(c[0], c[1], hw, c[2],
@@ -313,10 +325,6 @@ def autotune(
             m = teff.measure(fn, iters=iters, warmup=1)
         except _stencil.UnrealizableTile:
             continue  # tile the launch cannot realize by construction
-        except Exception:
-            if not interpret:
-                raise
-            continue
         tried += 1
         per_step = m.median_s / k
         if best is None or per_step < best.per_step_s:

@@ -32,11 +32,7 @@ def _body(scal_ref, T2_ref, T_ref, Ci_ref, o_ref, *stage, block, shape,
           nsteps, whalo, realign):
     lam, dt, idx2, idy2, idz2 = (scal_ref[i] for i in range(5))
     pids = tuple(pl.program_id(a) for a in range(3))
-    origin = tuple(
-        0 if block[a] == shape[a]
-        else _stencil.window_start(pids[a], block[a], *whalo[a], shape[a])
-        if realign[a] else pids[a] * block[a]
-        for a in range(3))
+    origin = _stencil.frame_origin(pids, block, whalo, shape)
     T = T_ref[...]
     Ci = Ci_ref[...][1:-1, 1:-1, 1:-1]
     mask = _stencil._valid_mask(origin, T.shape, shape, (1, 1, 1),
@@ -65,25 +61,16 @@ def _body(scal_ref, T2_ref, T_ref, Ci_ref, o_ref, *stage, block, shape,
 @functools.lru_cache(maxsize=32)
 def _build(shape, dtype_name, tile, interpret, nsteps, alias):
     dtype = jnp.dtype(dtype_name)
-    if not interpret and tile is not None and tuple(tile[1:]) != shape[1:]:
-        raise _stencil.TileNotCompilable(
-            f"tile {tile} splits a minor axis of shape {shape}; compiled for "
-            "the TPU the diffusion kernel keeps its two minor axes whole — "
-            "pass a tile spanning them or leave tile=None")
     grid, block = _stencil.derive_launch(
         shape, 1, 3, dtype.itemsize, tile=tile, nsteps=nsteps,
-        whole_axes=(1, 2) if tile is None else ())
+        tpu_layout=True, compiled=not interpret)
     whalo = _stencil.effective_halo(shape, block, nsteps)
     realign = tuple(lo + hi > 0 for lo, hi in whalo)
     fext = tuple(min(b + lo + hi, s)
                  for s, b, (lo, hi) in zip(shape, block, whalo))
 
     def win_map(*pids):
-        return tuple(
-            0 if block[a] == shape[a]
-            else _stencil.window_start(pids[a], block[a], *whalo[a], shape[a])
-            if realign[a] else pids[a] * block[a]
-            for a in range(3))
+        return _stencil.frame_origin(pids, block, whalo, shape)
 
     body = functools.partial(_body, block=block, shape=shape, nsteps=nsteps,
                              whalo=whalo, realign=realign)

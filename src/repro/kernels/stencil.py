@@ -96,8 +96,10 @@ DEFAULT_VMEM_LIMIT = 120 << 20
 
 # Soft budget steering automatic tile derivation: the launch's whole
 # accounted footprint (see :func:`launch_footprint_bytes`) stays under it
-# where the array allows.
-DEFAULT_VMEM_BUDGET = 48 << 20
+# where the array allows. It admits the 2-plane f32 blocks of 512-wide
+# grids — (2, 512, 512) at 44 MiB, and (2, 514, 514), a 512^3 rank with
+# its ghost ring, at 55.9 MiB once lanes are padded — not 4-plane ones.
+DEFAULT_VMEM_BUDGET = 56 << 20
 
 
 class UnrealizableTile(ValueError):
@@ -286,35 +288,53 @@ def launch_footprint_bytes(
     ``halo`` is the total (all sweeps) per-axis depth; axes the block
     spans whole carry none (:func:`effective_halo`).
 
+    Each buffer is counted as Mosaic lays it out: its two minor dims
+    rounded up to whole (sublane, 128-lane) tiles of 32-bit words (8
+    sublanes for f32, 16 for bf16), so a 514-wide axis costs 640 lanes.
     The intermediates are estimated as ``1 + n_out + nsteps`` frames at
     the compute dtype, a bound fitted to Mosaic's scoped allocations for
-    the diffusion step at 128³-512³ (f32 and bf16, 1-4 sweeps, streamed)
+    the diffusion step at 128³-514³ (f32 and bf16, 1-4 sweeps, streamed)
     that it exceeded by at most 1.5 MiB."""
     shape = tuple(int(s) for s in shape)
     block = tuple(int(b) for b in block)
     nd = len(block)
     pairs = effective_halo(shape, block, halo)
-    if march_axis is None:
-        windows = window_footprint_bytes(block, pairs, field_offsets,
-                                         itemsize)
-        queues = 0
-    else:
-        streamed = streamed_footprint_bytes(block, pairs, field_offsets,
-                                            itemsize, march_axis)
-        windows = streamed_footprint_bytes(
-            block, pairs, field_offsets, itemsize, march_axis,
-            queue=False)
-        queues = streamed - windows
-    frame = math.prod(min(b + lo + hi, s)
-                      for s, b, (lo, hi) in zip(shape, block, pairs))
+
+    def vmem(extent, size=itemsize):
+        ext = list(extent)
+        ext[-1] = -(-ext[-1] // 128) * 128
+        if nd > 1:
+            sub = 8 * max(4 // size, 1)
+            ext[-2] = -(-ext[-2] // sub) * sub
+        return math.prod(ext) * size
+
+    windows = queues = 0
+    for off in field_offsets:
+        ext = [b + lo + hi - o for b, (lo, hi), o in zip(block, pairs, off)]
+        if march_axis is not None:
+            # the fetch window carries no march-axis halo; the plane
+            # queue holds Q blocks of planes
+            m = march_axis
+            q, _, _ = march_queue_blocks(block[m], pairs[m])
+            ext[m] = block[m] - off[m]
+            queues += vmem(ext[:m] + [q * block[m]] + ext[m + 1:])
+        windows += vmem(ext)
+    frame = [min(b + lo + hi, s) for s, b, (lo, hi) in zip(shape, block, pairs)]
     frames = 0
     if nsteps > 1 or any(a != march_axis and sum(pairs[a])
                          for a in range(nd)):
-        frames = n_out * frame * itemsize
-    outs = n_out * math.prod(block) * itemsize
-    body = ((1 + n_out + int(nsteps)) * frame
-            * max(itemsize, compute_itemsize or itemsize))
+        frames = n_out * vmem(frame)
+    outs = n_out * vmem(block)
+    body = ((1 + n_out + int(nsteps))
+            * vmem(frame, max(itemsize, compute_itemsize or itemsize)))
     return 2 * (windows + outs) + frames + queues + body
+
+
+def tpu_whole_axes(nd: int, march_axis: int | None = None) -> tuple[int, ...]:
+    """The axes a TPU launch keeps at full extent: the two minor axes
+    (Mosaic refuses windows whose minor dims are neither (8, 128)-aligned
+    nor whole, and padded windows), less a march axis."""
+    return tuple(a for a in range(max(nd - 2, 0), nd) if a != march_axis)
 
 
 def derive_launch(
@@ -330,7 +350,8 @@ def derive_launch(
     march_axis: int | None = None,
     march_min_block: int = 1,
     vmem_limit: int | None = None,
-    whole_axes: Sequence[int] = (),
+    tpu_layout: bool = False,
+    compiled: bool = False,
     n_out: int = 1,
     compute_itemsize: int | None = None,
 ) -> tuple[tuple[int, ...], tuple[int, ...]]:
@@ -346,10 +367,14 @@ def derive_launch(
     raises a pointed :class:`LaunchFootprintError` here, before compile,
     instead of an opaque allocation failure later.
 
-    ``whole_axes`` are kept at full extent (the TPU layout keeps the two
-    minor axes whole: Mosaic refuses windows whose minor dims are neither
-    (8, 128)-aligned nor whole). Elsewhere the minor (last) axis prefers
-    128-lane multiples, the next-to-minor 8-sublane multiples. Blocks must
+    ``tpu_layout`` derives the layout the Pallas kernels run in: the
+    :func:`tpu_whole_axes` stay at full extent and only leading axes are
+    tiled. ``compiled`` (a launch Mosaic will compile, not interpret)
+    also refuses what Mosaic cannot compile: an explicit ``tile`` that
+    splits a minor axis (:class:`TileNotCompilable`) and a march axis on
+    a minor axis (``NotImplementedError``); interpret mode honours both.
+    Without ``tpu_layout`` the minor (last) axis prefers 128-lane
+    multiples, the next-to-minor 8-sublane multiples. Blocks must
     divide the array extents (the caller pads otherwise). The block set is
     shrunk until the footprint fits ``vmem_budget``. With temporal
     blocking (``nsteps > 1``) the window halo is ``nsteps * radius`` per
@@ -381,7 +406,24 @@ def derive_launch(
     if field_offsets is None:
         field_offsets = [(0,) * nd] * int(n_fields)
     field_offsets = [tuple(int(o) for o in off) for off in field_offsets]
-    whole = {int(a) for a in whole_axes}
+    minor = tuple(range(max(nd - 2, 0), nd))
+    if compiled:
+        if march_axis is not None and march_axis in minor:
+            raise NotImplementedError(
+                f"march_axis={march_axis} streams a minor axis of a {nd}-d "
+                "stencil; compiled for the TPU a launch keeps its two minor "
+                "axes whole (Mosaic windows must span them), so only a "
+                "leading axis can stream — march axis 0 of a 3-d stencil "
+                "or drop march_axis")
+        if tile is not None and any(int(tile[a]) != shape[a] for a in minor):
+            raise TileNotCompilable(
+                f"tile {tuple(tile)} splits a minor axis of shape {shape}; "
+                "compiled for the TPU a launch keeps its two minor axes "
+                "whole (Mosaic refuses windows whose minor dims are neither "
+                f"(8, 128)-aligned nor whole) — pass a tile spanning axes "
+                f"{minor} or leave tile=None")
+    whole = (set(tpu_whole_axes(nd, march_axis)) if tpu_layout or compiled
+             else set())
 
     def footprint(blk):
         return launch_footprint_bytes(shape, blk, halo, field_offsets,
@@ -447,6 +489,20 @@ def window_start(pid, block: int, lo: int, hi: int, n: int):
     or the whole axis when that is shorter) stays inside an axis of ``n``
     cells (the base extent; staggered fields clamp identically)."""
     return jnp.clip(pid * block - lo, 0, max(n - block - lo - hi, 0))
+
+
+def frame_origin(pids: Sequence, block: Sequence[int],
+                 whalo: Sequence[tuple[int, int]], shape: Sequence[int]
+                 ) -> tuple:
+    """Global index of cell 0 of a block's VMEM frame, per axis: the
+    window's element offset (what the index map returns). A literal 0 on
+    an axis the block spans whole (Mosaic must prove a minor-axis offset
+    tile-aligned, which it cannot for a traced expression), the clamped
+    :func:`window_start` on an axis whose windows overlap their
+    neighbours', the block start otherwise."""
+    return tuple(0 if b == s else window_start(p, b, lo, hi, s) if lo + hi
+                 else p * b
+                 for p, b, (lo, hi), s in zip(pids, block, whalo, shape))
 
 
 def compiler_params(nd: int, march: bool = False,
@@ -841,31 +897,11 @@ def build_stencil_call(
                     "or drop march_axis"
                 )
 
-    # The TPU layout: Mosaic refuses windows whose two minor dims are
-    # neither (8, 128)-aligned nor whole, and padded windows, so compiled
-    # launches keep the two minor axes whole and tile only leading axes
-    # with clamped windows. Interpret mode derives the same layout and
-    # additionally honours any explicit tile (the CPU test path for the
-    # general multi-axis geometry).
-    minor = tuple(range(max(nd - 2, 0), nd))
-    if not interpret:
-        if march is not None and march in minor:
-            raise NotImplementedError(
-                f"march_axis={march} streams a minor axis of a {nd}-d "
-                "stencil; compiled for the TPU a launch keeps its two minor "
-                "axes whole (Mosaic windows must span them), so only a "
-                "leading axis can stream — march axis 0 of a 3-d stencil "
-                "or drop march_axis")
-        if tile is not None and any(int(tile[a]) != shape[a] for a in minor):
-            raise TileNotCompilable(
-                f"tile {tuple(tile)} splits a minor axis of shape {shape}; "
-                "compiled for the TPU a launch keeps its two minor axes "
-                "whole (Mosaic refuses windows whose minor dims are neither "
-                f"(8, 128)-aligned nor whole) — pass a tile spanning axes "
-                f"{minor} or leave tile=None")
-    whole = tuple(a for a in minor if a != march)
-
     def _derive(m):
+        # The TPU layout (derive_launch): the two minor axes stay whole
+        # and leading axes are tiled with clamped windows; interpret mode
+        # also honours explicit tiles across any axis (the CPU test path
+        # for the general multi-axis geometry).
         # Fused neumann0 conditions copy through frame-local shifts, so
         # the (small, halo-sized) march block must still hold 2*depth
         # cells along the marched axis.
@@ -880,7 +916,7 @@ def build_stencil_call(
             field_offsets=[offsets[n] for n in field_names],
             halos=None if halos is None else sweep_halo,
             march_axis=m, march_min_block=min_bm,
-            whole_axes=whole if tile is None else (),
+            tpu_layout=True, compiled=not interpret,
             n_out=len(out_names), compute_itemsize=compute_dtype.itemsize,
         )
 
@@ -958,15 +994,13 @@ def build_stencil_call(
             )
 
     def in_index_map(*pids):
-        # whole axes return a literal 0: Mosaic must prove a minor-axis
-        # offset tile-aligned, which it cannot for a traced expression
+        # the streamed march axis fetches new planes only (clamped in the
+        # drain steps); every other axis fetches its frame
         lp = logical_pids(*pids)
         return tuple(
-            0 if block[a] == shape[a]
-            else jnp.minimum(lp[a], grid[a] - 1) * block[a] if a == march
-            else window_start(lp[a], block[a], *whalo[a], shape[a])
-            if realign[a] else lp[a] * block[a]
-            for a in range(nd))
+            jnp.minimum(lp[a], grid[a] - 1) * block[a]
+            if a == march and block[a] != shape[a] else o
+            for a, o in enumerate(frame_origin(lp, block, whalo, shape)))
 
     n_s, n_f = len(scalar_names), len(field_names)
 
@@ -1023,13 +1057,12 @@ def build_stencil_call(
         # while it fetches block i
         pids = tuple(jnp.maximum(lp[a] - lhi_b, 0) if a == march else lp[a]
                      for a in range(nd))
-        # global index of each frame's cell 0, per axis
+        # global index of each frame's cell 0, per axis (the streamed march
+        # window is assembled from the queue around the written block)
         origin = tuple(
-            0 if block[a] == shape[a]
-            else pids[a] * block[a] - whalo[a][0] if a == march
-            else window_start(lp[a], block[a], *whalo[a], shape[a])
-            if realign[a] else lp[a] * block[a]
-            for a in range(nd))
+            pids[a] * block[a] - whalo[a][0]
+            if a == march and block[a] != shape[a] else o
+            for a, o in enumerate(frame_origin(lp, block, whalo, shape)))
         windows = {n: ref[...] for n, ref in zip(field_names, in_refs)}
         if use_queue:
             # Roll each field's plane queue by one block and append the

@@ -523,3 +523,71 @@ np.testing.assert_allclose(masses, want, rtol=1e-5)
 print("DIST_REDS_OK")
 """, n_devices=4)
     assert "DIST_REDS_OK" in out
+
+
+@pytest.mark.parametrize("factors", [(2, 2), (2, 1), (1, 2)])
+def test_distributed_checks_equal_the_global_check(factors):
+    """From the second step on, an output's ghost ring holds the previous
+    step: the rank-combined checks of sequential_step, overlapped_step and
+    elastic_solve_until fold the owned cells only, so max and sum kinds
+    equal the whole-array check of a one-device run at every step."""
+    out = run_subprocess(f"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.compat import shard_map
+from repro.core import init_parallel_stencil, fd3d as fd
+from repro.distributed import elastic, halo, overlap
+from repro.launch.mesh import make_mesh
+
+factors, nt = {factors}, 2
+mesh = make_mesh(factors, ("x", "y"))
+x, y, z = np.meshgrid(*[np.linspace(0, 1, n) for n in (18, 18, 6)],
+                      indexing="ij")
+Tg = jnp.asarray(1 + np.exp(-((x - .45)**2 + (y - .55)**2) / .02), jnp.float32)
+Cig = jnp.full(Tg.shape, 0.5, jnp.float32)
+sc = dict(lam=1.0, dt=1e-3, _dx=17.0, _dy=17.0, _dz=5.0)
+ps = init_parallel_stencil(backend="jnp", ndims=3)
+checks = {{"err": "max_abs_diff(T2, T)", "m": "sum_sq(T2)"}}
+
+@ps.parallel(outputs=("T2",), rotations={{"T2": "T"}}, reductions=checks)
+def kern(T2, T, Ci, lam, dt, _dx, _dy, _dz):
+    return {{"T2": fd.inn(T) + dt*(lam*fd.inn(Ci)*(fd.d2_xi(T)*_dx**2
+            + fd.d2_yi(T)*_dy**2 + fd.d2_zi(T)*_dz**2))}}
+
+want, T2, T = [], Tg, Tg
+for _ in range(nt):
+    out, reds = kern(T2=T2, T=T, Ci=Cig, **sc)
+    T2, T = T, out
+    want.append([float(reds["err"]), float(reds["m"])])
+
+def stack(g):
+    loc = halo.global_to_local(g, factors)
+    return jnp.asarray(np.stack(loc).reshape(*factors, *loc[0].shape))
+
+def run(step):
+    def body(Tl, Cl):
+        T2, T, Ci = Tl[0, 0], Tl[0, 0], Cl[0, 0]
+        got = []
+        for _ in range(nt):
+            (out, reds), fresh = step(kern, dict(T2=T2, T=T, Ci=Ci), sc,
+                                      ("T",), ("x", "y"))
+            T2, T = fresh["T"], out
+            got.append(jnp.stack([reds["err"], reds["m"]]))
+        return jnp.stack(got)[None]
+    f = shard_map(body, mesh=mesh, in_specs=(P("x", "y"), P("x", "y")),
+                  out_specs=P(("x", "y")), check_vma=False)
+    return np.asarray(f(stack(Tg), stack(Cig)))
+
+for step in (overlap.sequential_step, overlap.overlapped_step):
+    for got in run(step):   # every rank sees the same combined values
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+res = elastic.elastic_solve_until(
+    kern, dict(T2=np.asarray(Tg), T=np.asarray(Tg), Ci=np.asarray(Cig)), sc,
+    factors=factors, tol=0.0, max_iters=nt, exchange=("T",), check_every=1,
+    error="err")
+np.testing.assert_allclose(float(res.err), want[-1][0], rtol=1e-5)
+np.testing.assert_allclose(np.asarray(res.output(kern)), np.asarray(T),
+                           rtol=1e-6)
+print("OWNED_CHECKS_OK")
+""", n_devices=4)
+    assert "OWNED_CHECKS_OK" in out

@@ -177,10 +177,10 @@ def test_autotune_picks_and_caches(tmp_path):
 
 
 def test_autotune_skips_only_unrealizable_tiles_on_device():
-    """Compiled for the device, a candidate that raises anything but
-    UnrealizableTile is a fault the tuner re-raises; interpret mode keeps
-    skipping it."""
-    from repro.kernels.stencil import UnrealizableTile
+    """A candidate that raises anything but UnrealizableTile (a compiler
+    refusal on the device) is a fault the tuner re-raises, on every
+    backend; tiles unrealizable by construction are skipped."""
+    from repro.kernels.stencil import TileNotCompilable, UnrealizableTile
 
     def make_step(fail):
         def build(tile, k):
@@ -192,15 +192,29 @@ def test_autotune_skips_only_unrealizable_tiles_on_device():
     kw = dict(shape=(16, 16, 16), dtype="float32", radius=1, n_fields=3,
               nsteps_candidates=(1, 2), iters=1)
     autotune._CACHE.clear()
-    r = autotune.autotune(make_step(UnrealizableTile("no")), tag="u1",
-                          interpret=False, **kw)
-    assert r.nsteps == 1
+    for i, fail in enumerate([UnrealizableTile("no"),
+                              TileNotCompilable("splits a minor axis")]):
+        r = autotune.autotune(make_step(fail), tag=f"u1-{i}", **kw)
+        assert r.nsteps == 1
     with pytest.raises(RuntimeError, match="refused"):
         autotune.autotune(make_step(RuntimeError("Mosaic refused it")),
-                          tag="u2", interpret=False, **kw)
-    r = autotune.autotune(make_step(RuntimeError("Mosaic refused it")),
-                          tag="u3", interpret=True, **kw)
-    assert r.nsteps == 1
+                          tag="u2", **kw)
+
+
+def test_autotune_tile_candidates_keep_the_tpu_layout():
+    """Every derived candidate keeps the two minor axes whole (the only
+    tiles Mosaic compiles) and fits the budget as the preflight counts it."""
+    from repro.kernels import stencil
+
+    for shape in [(512,) * 3, (64, 64, 256), (16, 16, 16)]:
+        cands = autotune.tile_candidates(shape, 1, 3, 4)
+        assert cands and len(set(cands)) == len(cands)
+        for t in cands:
+            assert t[1:] == shape[1:] and shape[0] % t[0] == 0
+            assert stencil.launch_footprint_bytes(
+                shape, t, 1, [(0,) * 3] * 3, 4, 1) <= \
+                stencil.DEFAULT_VMEM_BUDGET
+    assert len(autotune.tile_candidates((512,) * 3, 1, 3, 4)) >= 2
 
 
 def test_autotune_diffusion3d_smoke():

@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import fd3d as fd, init_parallel_stencil
-from repro.kernels import conv1d, diffusion3d
+from repro.kernels import autotune, conv1d, diffusion3d
 from repro.kernels.stencil import TileNotCompilable
 
 N = 512
@@ -83,23 +83,44 @@ def test_fig1_kernel_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in compiled_text(f, one_chip, dtype=dtype)
 
 
-@pytest.mark.parametrize("dtype,march", [
-    ("float32", None), ("bfloat16", None), ("float32", 0)])
-def test_vmem_accounting_covers_mosaic(one_chip, monkeypatch, dtype, march):
+@pytest.mark.parametrize("dtype,march,shape", [
+    ("float32", None, (N,) * 3), ("bfloat16", None, (N,) * 3),
+    ("float32", 0, (N,) * 3), ("float32", None, (N + 2,) * 3),
+    ("float32", None, (256, 300, 200))])
+def test_vmem_accounting_covers_mosaic(one_chip, monkeypatch, dtype, march,
+                                       shape):
     """The preflight's footprint bounds what Mosaic really allocates: a
     launch compiled with its scoped-VMEM limit set to its own accounted
-    footprint still compiles."""
+    footprint still compiles. (N+2)^3 is one rank of a 512^3-per-chip
+    decomposition; its minor dims are padded to whole lane tiles."""
     step = fig1(dtype, march_axis=march)
 
     def f(T2, T, Ci):
         return step(T2=T2, T=T, Ci=Ci, **SCALARS)
 
-    x = jax.ShapeDtypeStruct((N,) * 3, jnp.dtype(dtype))
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
     jax.eval_shape(f, x, x, x)
     (info,) = step.launch_info.values()
     monkeypatch.setenv("REPRO_VMEM_LIMIT_BYTES", str(info["vmem_bytes"]))
     step = fig1(dtype, march_axis=march)
-    assert "tpu_custom_call" in compiled_text(f, one_chip, dtype=dtype)
+    assert "tpu_custom_call" in compiled_text(f, one_chip, shape=shape,
+                                              dtype=dtype)
+
+
+@pytest.mark.parametrize("march", [None, 0])
+def test_autotune_candidates_compile_for_v5e(one_chip, march):
+    """Every (tile, nsteps, march) launch ``autotune_diffusion3d`` would
+    measure at 512^3 compiles: the tuner searches the TPU layout."""
+    cands = autotune.launch_candidates((N,) * 3, 1, 3, 4, (1, 2, 4),
+                                       march_candidates=(march,))
+    assert len(cands) >= 2
+    for tile, k, m in cands:
+        step = fig1(tile=tile, march_axis=m)
+
+        def f(T2, T, Ci):
+            return step.run_steps(k, T2=T2, T=T, Ci=Ci, **SCALARS)
+
+        assert "tpu_custom_call" in compiled_text(f, one_chip), (tile, k, m)
 
 
 def test_hand_kernel_compiles_for_v5e(one_chip):
