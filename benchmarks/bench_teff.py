@@ -385,7 +385,6 @@ def bench_telemetry(cfg: Diffusion3DConfig, iters: int = 20,
     bench_march."""
     from repro import telemetry
     from repro.core import iterate
-    from repro.telemetry import attrib
 
     g, T, T2, Ci, dt = _setup(cfg)
     inv = g.inv_spacing
@@ -399,9 +398,6 @@ def bench_telemetry(cfg: Diffusion3DConfig, iters: int = 20,
     rkern = kern.with_reductions({"err": "max_abs_diff(T2, T)"})
     fields = dict(T2=T2, T=T, Ci=Ci)
     col = telemetry.Collector(None)
-    # resolve the roofline peak up front so attribution never runs a
-    # STREAM probe inside a timed round
-    attrib.default_hardware()
 
     def run(sel):
         res = iterate.solve_until(rkern, fields, sc, tol=0.0,

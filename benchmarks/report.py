@@ -5,7 +5,8 @@
 ``--telemetry RUN.jsonl`` appends the per-phase timing and error-
 trajectory tables of an instrumented run (a ``REPRO_TELEMETRY=`` JSONL
 log) to the report — solve wall time split by span, the convergence
-trajectory harvested at chunk boundaries, and the roofline-gap gauges.
+trajectory harvested at chunk boundaries, and compile time by function
+and phase.
 """
 from __future__ import annotations
 
@@ -104,7 +105,7 @@ def dryrun_table(recs):
 
 
 def telemetry_tables(log_path: str) -> str:
-    """Per-phase timing + error-trajectory + roofline tables rendered
+    """Per-phase timing + error-trajectory + compile tables rendered
     from an instrumented run's JSONL log (same aggregation as
     ``python -m repro.telemetry.report``, embedded in this report)."""
     from repro.telemetry import report as trep, schema as tschema
@@ -116,10 +117,9 @@ def telemetry_tables(log_path: str) -> str:
          ["phase", "count", "total_s", "mean_s", "p50_s", "p90_s", "max_s"]),
         ("Error trajectory (chunk-boundary harvest)",
          trep.error_trajectory(records), ["iters", "err", "per_step_s"]),
-        ("Roofline gap (last gauges)",
-         [g for g in trep.last_gauges(records)
-          if g["gauge"].startswith("roofline.")],
-         ["gauge", "labels", "value"]),
+        ("Compiles (jit.compiles / jit.compile_s)",
+         trep.compile_summary(records),
+         ["fn", "phase", "compiles", "seconds"]),
     ):
         t = trep.format_table(rows, cols, title)
         if t:

@@ -104,19 +104,15 @@ class CheckpointManager:
         nbytes = sum(v.nbytes for v in host_vals)
 
         def write():
-            w0, t0 = time.time(), time.perf_counter()
-            try:
-                self._write(step, paths, host_vals, extra or {})
+            try:   # emitted from the writer thread when async
+                with col.span("checkpoint.write", step=step, bytes=nbytes,
+                              blocking=blocking):
+                    self._write(step, paths, host_vals, extra or {})
             except BaseException as e:  # surfaced on next wait()
                 self._error = e
                 return
-            if col.enabled:   # emitted from the writer thread when async
-                col.span_end("checkpoint.write", w0,
-                             time.perf_counter() - t0,
-                             {"step": step, "bytes": nbytes,
-                              "blocking": blocking})
-                col.count("checkpoint.saves", 1)
-                col.count("checkpoint.bytes_written", nbytes)
+            col.count("checkpoint.saves", 1)
+            col.count("checkpoint.bytes_written", nbytes)
 
         if blocking:
             write()

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry as _telemetry
-from ..telemetry import attrib as _attrib
 
 __all__ = [
     "Checkpointing", "SolveResult", "make_solver", "solve_until",
@@ -261,10 +260,12 @@ def make_solver(
             cur, _, _, it = state
             for _ in range(check_every - 1):
                 cur = rotate(cur, as_dict(plain(**cur, **scalars)))
-            outs, reds = kernel(**cur, **scalars)
+            with jax.named_scope("solve.check"):
+                outs, reds = kernel(**cur, **scalars)
+                reds = {n: jnp.asarray(v, jnp.float32)
+                        for n, v in reds.items()}
+                err = jnp.asarray(err_fn(reds), jnp.float32)
             cur = rotate(cur, as_dict(outs))
-            reds = {n: jnp.asarray(v, jnp.float32) for n, v in reds.items()}
-            err = jnp.asarray(err_fn(reds), jnp.float32)
             return cur, reds, err, it + check_every
 
         return jax.lax.while_loop(cond, body, (cur0, reds0, err0,
@@ -280,46 +281,6 @@ def _crossed(err: float, tol: float, until: str) -> bool:
 
 def _kernel_label(kernel) -> str:
     return getattr(kernel.fn, "__name__", "kernel")
-
-
-def _roofline(col, kernel, fields, scalars, per_step_s, check_every):
-    """Best-effort roofline-gap attribution for an instrumented solve:
-    pair measured per-step seconds with the kernel's IR cost model.
-    Kernels whose update cannot be IR-traced just skip the record."""
-    cost = _cost_model_cached(kernel, fields, scalars)
-    if cost is None:
-        return
-    _attrib.attribute(col, _kernel_label(kernel), per_step_s, cost,
-                      check_every=int(check_every), fused_checks=True)
-
-
-# the IR cost model depends only on field shapes/dtypes and the scalar
-# values, all of which are fixed across repeat solves — memoize it so
-# per-solve attribution is float math + record appends, not a re-trace
-_COST_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
-
-
-def _cost_model_cached(kernel, fields, scalars):
-    def build():
-        try:
-            return kernel.cost_model(**fields, **(scalars or {}))
-        except Exception:
-            return None
-
-    skey = _scalars_cache_key(scalars)
-    if skey is None:
-        return build()
-    fkey = tuple(sorted((n, tuple(getattr(v, "shape", ())),
-                         str(getattr(v, "dtype", type(v).__name__)))
-                        for n, v in fields.items()))
-    key = (fkey, skey)
-    try:
-        cache = _COST_CACHE.setdefault(kernel, {})
-    except TypeError:
-        return build()
-    if key not in cache:
-        cache[key] = build()
-    return cache[key]
 
 
 def _solve_checkpointed(
@@ -367,31 +328,29 @@ def _solve_checkpointed(
     plan = fault.FaultPlan.active()
     monitor = ckpt.monitor
     saved: list[int] = []
-    chunks: list[tuple[float, int]] = []   # (device seconds, steps) per chunk
     converged = done > 0 and _crossed(float(err), tol, until)
     while not converged and done < max_iters:
         take = min(block, max_iters - done)
-        w0 = time.time()
         t0 = time.perf_counter()
-        cur, reds, err, it = solver(cur, tol, take)
-        n = int(it)                      # chunk-boundary host sync
-        dt = time.perf_counter() - t0
-        done += n
-        converged = _crossed(float(err), tol, until)
-        chunks.append((dt, n))
-        if col.enabled:
-            # harvest ONLY what this boundary already syncs: iters + err
-            # (+ the reduction scalars the checkpoint ships anyway)
+        with col.span("solve.chunk") as span:
+            cur, reds, err, it = solver(cur, tol, take)
+            n = int(it)                  # chunk-boundary host sync
+            dt = time.perf_counter() - t0
+            done += n
+            converged = _crossed(float(err), tol, until)
             per = dt / max(n, 1)
-            col.span_end("solve.chunk", w0, dt,
-                         {"steps": n, "iters": done, "err": float(err),
-                          "per_step_s": per, "cold": len(chunks) == 1})
+            if col.enabled:
+                # harvest ONLY what this boundary already syncs: iters +
+                # err (+ the reduction scalars the checkpoint ships anyway)
+                span.set(steps=n, iters=done, err=float(err),
+                         per_step_s=per, cold=len(saved) == 0)
+        if col.enabled:
             col.count("solve.steps", n)
             col.event("solve.trajectory", iters=done, err=float(err),
                       per_step_s=per,
                       reds={k: float(v) for k, v in reds.items()})
         if monitor is not None:
-            monitor.record(done, dt / max(n, 1))
+            monitor.record(done, per)
             health = monitor.check_peers()
             if health["dead"]:
                 mgr.wait()
@@ -412,13 +371,6 @@ def _solve_checkpointed(
     if col.enabled:
         col.gauge("solve.iters", done)
         col.gauge("solve.err", float(err))
-        # per-step seconds for the roofline gap: warm chunks only (the
-        # first chunk pays trace+compile) unless the run was one chunk
-        warm = chunks[1:] if len(chunks) > 1 else chunks
-        steps = sum(n for _, n in warm)
-        if steps:
-            _roofline(col, kernel, cur, scalars,
-                      sum(dt for dt, _ in warm) / steps, check_every)
     return SolveResult(fields=cur, reds=reds, err=err,
                        iters=jnp.int32(done), resumed_from=resumed_from,
                        saved_steps=tuple(saved), step_stats=stats)
@@ -476,27 +428,23 @@ def solve_until(
         cur, reds, err, iters = solver(dict(fields), tol, max_iters)
         return SolveResult(fields=cur, reds=reds, err=err, iters=iters)
     # Instrumented plain path: same cached jitted solver as the disabled
-    # path (identical dispatch cost), with cold calls — the ones that
-    # paid trace+compile inside the timed window — detected via the jit
-    # cache size and excluded from roofline attribution so the gap
-    # reflects execution, not compilation.
+    # path (identical dispatch cost). ``solve.dispatch`` ends once the
+    # solve is enqueued; ``solve_until`` closes at the final-carry harvest,
+    # which reads iters and err in one transfer. Cold calls — the ones
+    # that paid trace+compile — are flagged via the jit cache size.
     size_fn = getattr(solver, "_cache_size", None)
     before = size_fn() if size_fn is not None else None
-    w0 = time.time()
-    t0 = time.perf_counter()
-    cur, reds, err, iters = solver(dict(fields), tol, max_iters)
-    it = int(jax.block_until_ready(iters))   # final-carry harvest
-    dt = time.perf_counter() - t0
-    cold = (size_fn() > before) if size_fn is not None else False
-    col.span_end("solve_until", w0, dt,
-                 {"kernel": _kernel_label(kernel), "iters": it,
-                  "err": float(err), "check_every": int(check_every),
-                  "cold": cold})
+    with col.span("solve_until") as span:
+        with col.span("solve.dispatch"):
+            cur, reds, err, iters = solver(dict(fields), tol, max_iters)
+        it, e = jax.device_get((iters, err))     # final-carry harvest
+        it, e = int(it), float(e)
+        span.set(kernel=_kernel_label(kernel), iters=it, err=e,
+                 check_every=int(check_every),
+                 cold=(size_fn() > before) if size_fn is not None else False)
     col.count("solve.steps", it)
     col.gauge("solve.iters", it)
-    col.gauge("solve.err", float(err))
-    if it and not cold:
-        _roofline(col, kernel, cur, scalars, dt / it, check_every)
+    col.gauge("solve.err", e)
     return SolveResult(fields=cur, reds=reds, err=err, iters=iters)
 
 

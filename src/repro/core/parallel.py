@@ -67,6 +67,7 @@ import numpy as np
 
 from ..kernels import stencil as _stencil
 from .. import ir as _ir
+from .. import telemetry as _telemetry
 
 _BACKENDS = ("jnp", "pallas")
 
@@ -686,7 +687,8 @@ class StencilKernel:
         return out, reds
 
     def _run_pallas(self, fields, scalars, base, shapes,
-                    geom: KernelGeometry, nsteps: int = 1):
+                    geom: KernelGeometry, nsteps: int = 1,
+                    col=_telemetry.NULL):
         key = (base, tuple(sorted(shapes.items())), tuple(sorted(scalars)),
                nsteps, self.march_axis)
         run = self._cache.get(key)
@@ -699,6 +701,7 @@ class StencilKernel:
 
             run = _stencil.build_stencil_call(
                 update,
+                name=getattr(self.fn, "__name__", None),
                 field_names=field_names,
                 out_names=self.outputs,
                 scalar_names=scalar_names,
@@ -722,18 +725,23 @@ class StencilKernel:
                 reductions=self.reductions,
             )
             self._cache[key] = run
-        res = run(fields, scalars)
+        res = run(fields, scalars, col)
         return res if self.reductions else (res, None)
 
     def __call__(self, **kwargs):
-        fields, scalars, base, shapes = self._split(kwargs)
-        geom = self._geometry(base, shapes, tuple(scalars))
-        if self.ps.backend == "pallas":
-            outs, reds = self._run_pallas(fields, scalars, base, shapes, geom)
-        elif self.march_axis is not None:
-            outs, reds = self._run_jnp_march(fields, scalars, base, geom)
-        else:
-            outs, reds = self._run_jnp(fields, scalars, base, geom)
+        # ``stencil.dispatch``: from entry to the return after the launch
+        # is enqueued (under a jit trace: the trace of this call)
+        col = _telemetry.get()
+        with col.span("stencil.dispatch"):
+            fields, scalars, base, shapes = self._split(kwargs)
+            geom = self._geometry(base, shapes, tuple(scalars))
+            if self.ps.backend == "pallas":
+                outs, reds = self._run_pallas(fields, scalars, base, shapes,
+                                              geom, col=col)
+            elif self.march_axis is not None:
+                outs, reds = self._run_jnp_march(fields, scalars, base, geom)
+            else:
+                outs, reds = self._run_jnp(fields, scalars, base, geom)
         res = outs[self.outputs[0]] if len(self.outputs) == 1 else outs
         return (res, reds) if self.reductions else res
 
@@ -769,12 +777,19 @@ class StencilKernel:
         if nsteps == 1:
             return self(**kwargs)
         self._check_rotations()
+        col = _telemetry.get()
+        with col.span("stencil.dispatch"):
+            outs, reds = self._steps(nsteps, kwargs, col)
+        res = outs[self.outputs[0]] if len(self.outputs) == 1 else outs
+        return (res, reds) if self.reductions else res
+
+    def _steps(self, nsteps, kwargs, col):
         fields, scalars, base, shapes = self._split(kwargs)
         geom = self._geometry(base, shapes, tuple(scalars))
         periodic = any(c.kind == "periodic" for c in self.bc.values())
         if self.ps.backend == "pallas" and not periodic:
             outs, reds = self._run_pallas(fields, scalars, base, shapes,
-                                          geom, nsteps)
+                                          geom, nsteps, col)
         else:
             # True double-buffer rotation, unrolled: sweep s scatters into
             # the stale buffer of the (out, target) pair, which is dead two
@@ -787,8 +802,8 @@ class StencilKernel:
                 step = (self._run_jnp_march if self.march_axis is not None
                         else self._run_jnp)
             else:
-                step = lambda f, s, b, g: self._run_pallas(f, s, b,  # noqa: E731
-                                                           shapes, g)
+                step = lambda f, s, b, g: self._run_pallas(  # noqa: E731
+                    f, s, b, shapes, g, col=col)
             cur = dict(fields)
             for s in range(nsteps):
                 # Intermediate sweeps' reductions are dead values — XLA's
@@ -798,8 +813,7 @@ class StencilKernel:
                 if s < nsteps - 1:
                     for o, tgt in self.rotations.items():
                         cur[o], cur[tgt] = cur[tgt], outs[o]
-        res = outs[self.outputs[0]] if len(self.outputs) == 1 else outs
-        return (res, reds) if self.reductions else res
+        return outs, reds
 
     @property
     def launch_info(self) -> dict:

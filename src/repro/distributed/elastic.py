@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import telemetry as _telemetry
 from ..core.iterate import SolveResult, _crossed, _resolve_error
 from . import fault, halo as _halo, overlap
 
@@ -226,8 +227,9 @@ def make_elastic_solver(kernel, scalars: Mapping[str, object], mesh: Mesh,
                         exchange: Sequence[str], *, check_every: int = 1,
                         error=None, until: str = "below",
                         periodic: bool = False):
-    """Build the jitted chunk driver ``solver(stacked_fields, tol, block)
-    -> (stacked_fields, reds, err, iters)``.
+    """Build the chunk driver ``solver(stacked_fields, tol, block)
+    -> (stacked_fields, reds, err, iters)``: a jitted program, each call
+    an ``elastic.chunk`` telemetry span (entry to enqueue).
 
     One ``shard_map`` over the whole chunk: the rank-local body is the
     same m-steps-per-check ``lax.while_loop`` as
@@ -273,11 +275,13 @@ def make_elastic_solver(kernel, scalars: Mapping[str, object], mesh: Mesh,
                 outs, fresh = overlap.sequential_step(
                     plain, cur, scalars, exchange, axes, periodic=periodic)
                 cur = rotate(fresh, as_dict(outs))
-            (outs, reds), fresh = overlap.sequential_step(
-                kernel, cur, scalars, exchange, axes, periodic=periodic)
+            with jax.named_scope("solve.check"):
+                (outs, reds), fresh = overlap.sequential_step(
+                    kernel, cur, scalars, exchange, axes, periodic=periodic)
+                reds = {n: jnp.asarray(v, jnp.float32)
+                        for n, v in reds.items()}
+                err = jnp.asarray(err_fn(reds), jnp.float32)
             cur = rotate(fresh, as_dict(outs))
-            reds = {n: jnp.asarray(v, jnp.float32) for n, v in reds.items()}
-            err = jnp.asarray(err_fn(reds), jnp.float32)
             return cur, reds, err, it + check_every
 
         return jax.lax.while_loop(
@@ -301,7 +305,13 @@ def make_elastic_solver(kernel, scalars: Mapping[str, object], mesh: Mesh,
         )
         return f(stacked, tol, block)
 
-    return jax.jit(solver)
+    jitted = jax.jit(solver)
+
+    def chunk(stacked, tol, block):
+        with _telemetry.get().span("elastic.chunk"):
+            return jitted(stacked, tol, block)
+
+    return chunk
 
 
 def elastic_solve_until(

@@ -415,23 +415,25 @@ def exchange_many(
     one-permute-per-field reference path. ``depths`` tightens each
     field's exchanged slab to its inferred per-axis (lo, hi) read depth;
     ``compress`` selects the ghost wire format (``"bf16"``/``"int8"``,
-    see :func:`halo_exchange`)."""
+    see :func:`halo_exchange`). The exchange's operations carry the
+    ``halo.exchange`` name scope."""
     _check_compress(compress)
     col = _telemetry.get()
     if col.enabled:
         _emit_exchange_telemetry(col, fields, names, mesh_axes, radius,
                                  depths, compress, grouped)
-    if grouped:
-        return grouped_halo_exchange(fields, names, mesh_axes, radius=radius,
-                                     periodic=periodic, depths=depths,
-                                     compress=compress)
-    out = dict(fields)
-    for n in names:
-        out[n] = halo_exchange(
-            out[n], mesh_axes, radius=radius, periodic=periodic,
-            depths=None if depths is None else depths.get(n),
-            compress=compress)
-    return out
+    with jax.named_scope("halo.exchange"):
+        if grouped:
+            return grouped_halo_exchange(
+                fields, names, mesh_axes, radius=radius, periodic=periodic,
+                depths=depths, compress=compress)
+        out = dict(fields)
+        for n in names:
+            out[n] = halo_exchange(
+                out[n], mesh_axes, radius=radius, periodic=periodic,
+                depths=None if depths is None else depths.get(n),
+                compress=compress)
+        return out
 
 
 def global_to_local(global_arr, factors: Sequence[int], radius: int = 1):

@@ -156,20 +156,19 @@ class MonitoredStepper:
         self.last_health = {"dead": [], "stragglers": [], "healthy": 1}
 
     def __call__(self, *args, **kwargs):
-        w0 = time.time()
+        col = _telemetry.get()
         t0 = time.perf_counter()
-        out = self.step(*args, **kwargs)
-        out = jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        self.calls += 1
+        with col.span("distributed.step") as span:
+            out = self.step(*args, **kwargs)
+            out = jax.block_until_ready(out)
+            dt = time.perf_counter() - t0
+            self.calls += 1
+            if col.enabled:
+                span.set(call=self.calls, steps=self.nsteps_per_call,
+                         per_step_s=dt / self.nsteps_per_call)
         self.monitor.record(self.calls * self.nsteps_per_call,
                             dt / self.nsteps_per_call)
-        col = _telemetry.get()
         if col.enabled:
-            col.span_end("distributed.step", w0, dt,
-                         {"call": self.calls,
-                          "steps": self.nsteps_per_call,
-                          "per_step_s": dt / self.nsteps_per_call})
             col.count("distributed.steps", self.nsteps_per_call)
         if self.calls % self.check_peers_every == 0:
             self.last_health = self.monitor.check_peers()

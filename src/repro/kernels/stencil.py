@@ -81,6 +81,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry as _telemetry
 from ..ir.reductions import normalize_reductions as _normalize_reductions
 
 # Per-grid-step block of a reduction-partials output: the smallest f32
@@ -714,6 +715,7 @@ def build_stencil_call(
     march_axis: int | None = None,
     write_rings: Sequence[int] | None = None,
     reductions: Mapping[str, object] | None = None,
+    name: str | None = None,
 ) -> Callable[..., dict[str, jax.Array]]:
     """Build a fused Pallas stencil step (or a k-step temporal block).
 
@@ -787,6 +789,11 @@ def build_stencil_call(
     Operands must be collocated fields; periodic BCs are incompatible
     (their wrap scatter happens after the launch, so an in-launch fold
     would see pre-wrap face values).
+
+    ``name`` (the ``@parallel`` function's name) names the launch: the
+    custom call's instruction and its ``kernel_metadata`` attribute
+    (``{"parallel": name}``) carry it into the compiled HLO, so a device
+    trace finds the launch by name.
     """
     shape = tuple(int(s) for s in shape)
     nd = len(shape)
@@ -1190,6 +1197,8 @@ def build_stencil_call(
     if not interpret:
         kwargs["compiler_params"] = compiler_params(
             len(launch_grid), march=march is not None)
+    if name is not None:
+        kwargs.update(name=name, metadata={"parallel": name})
     call = pl.pallas_call(
         body,
         grid=launch_grid,
@@ -1200,14 +1209,16 @@ def build_stencil_call(
         **kwargs,
     )
 
-    def run(fields: Mapping[str, jax.Array], scalars: Mapping[str, jax.Array]):
+    def run(fields: Mapping[str, jax.Array], scalars: Mapping[str, jax.Array],
+            col=_telemetry.NULL):
         # scalars ride in SMEM at compute precision: dt/lam quantized to
         # bf16 would perturb every update even though the fields are the
         # only thing the mixed-precision trade wants narrowed
-        ordered_scal = [
-            jnp.asarray(scalars[n], dtype=compute_dtype).reshape((1,))
-            for n in scalar_names
-        ]
+        with col.span("stencil.scalars"):
+            ordered_scal = [
+                jnp.asarray(scalars[n], dtype=compute_dtype).reshape((1,))
+                for n in scalar_names
+            ]
         ordered_fields = [jnp.asarray(fields[n], dtype=dtype) for n in field_names]
         for n, f in zip(field_names, ordered_fields):
             if f.shape != shapes[n]:

@@ -1,4 +1,4 @@
-"""Runtime telemetry for the stencil engine (metrics, spans, roofline gap).
+"""Runtime telemetry for the stencil engine (metrics, spans, compile counts).
 
 Off by default and a true no-op when off: every instrumentation site in
 the engine calls through the module-level singleton returned by

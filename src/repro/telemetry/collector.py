@@ -9,11 +9,20 @@ Two hard rules keep this subsystem honest about the engine it measures:
    opened, nothing is imported lazily on the hot path.
 
 2. **Nothing here runs inside compiled device code.** Instrumentation
-   sites emit only from host-side control flow (chunk boundaries,
-   checkpoint writers, autotune decisions) or at *trace* time (the halo
-   byte accounting). The jitted program — and its jaxpr — is byte-for-byte
-   identical with telemetry on or off; the zero-host-sync guarantee of
-   ``solve_until`` is preserved by construction and asserted by test.
+   sites emit only from host-side control flow (eager dispatch, chunk
+   boundaries, checkpoint writers, autotune decisions) or at *trace* time
+   (the halo byte accounting). The jitted program — and its jaxpr — is
+   byte-for-byte identical with telemetry on or off; the zero-host-sync
+   guarantee of ``solve_until`` is preserved by construction and asserted
+   by test.
+
+An enabled collector's spans are also ``jax.profiler.TraceAnnotation``s,
+so a profiler session records them on its host lines, on the same clock
+as the device planes, where they name what the host did while the device
+sat idle. It also counts every jitted function's compile by phase
+(``jit.compiles`` and ``jit.compile_s``, labelled ``fn`` and ``phase`` in
+trace / lower / compile / cache_fetch) through one ``jax.monitoring``
+listener.
 
 Events stream to a JSONL file as they happen (one JSON object per line,
 flushed per event — events are rare: chunk boundaries, saves, decisions).
@@ -40,6 +49,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from typing import Any, Optional
 
 SCHEMA_VERSION = 1
@@ -62,6 +72,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs):
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -104,26 +117,81 @@ NULL = NullCollector()
 
 class _Span:
     """Context manager emitted by :meth:`Collector.span` — wall-clock
-    start plus a monotonic duration, recorded on exit."""
+    start plus a monotonic duration, recorded on exit. While open it is
+    also a ``jax.profiler.TraceAnnotation`` of the same name, so under a
+    profiler session the span lands on the host lines of the trace, on
+    the device planes' clock."""
 
-    __slots__ = ("_col", "_name", "_attrs", "_t0", "_w0")
+    __slots__ = ("_col", "_name", "_attrs", "_t0", "_w0", "_ann")
 
     def __init__(self, col: "Collector", name: str, attrs: dict):
         self._col = col
         self._name = name
         self._attrs = attrs
 
+    def set(self, **attrs):
+        """Attach attributes known only inside the block (a count read
+        at the block's host sync)."""
+        self._attrs = dict(self._attrs, **attrs)
+
     def __enter__(self):
+        self._ann = self._col._annotation(self._name)
+        self._ann.__enter__()
         self._w0 = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._attrs = dict(self._attrs, error=exc_type.__name__)
         self._col.span_end(self._name, self._w0, dur, self._attrs)
         return False
+
+
+# jax.monitoring duration events of a jitted function's compile, by phase;
+# a persistent-cache hit happens inside the backend-compile event
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
+}
+_COMPILE_SINKS: "weakref.WeakSet[Collector]" = weakref.WeakSet()
+_COMPILE_TLS = threading.local()
+_compile_listener_on = False
+
+
+def _on_compile_event(event: str, duration: float, **kwargs):
+    """The one jax.monitoring listener behind every enabled collector:
+    counts ``jit.compiles`` and ``jit.compile_s`` by function and phase.
+    A backend compile that fetched its executable from the persistent
+    cache counts as ``cache_fetch`` (its whole duration), not ``compile``."""
+    phase = COMPILE_EVENTS.get(event)
+    if phase is None or not _COMPILE_SINKS:
+        return
+    if phase == "cache_fetch":
+        _COMPILE_TLS.fetched = True
+        return
+    if phase == "compile" and getattr(_COMPILE_TLS, "fetched", False):
+        _COMPILE_TLS.fetched = False
+        phase = "cache_fetch"
+    fn = str(kwargs.get("fun_name", "?"))
+    for col in list(_COMPILE_SINKS):
+        col.count("jit.compiles", 1, fn=fn, phase=phase)
+        col.count("jit.compile_s", float(duration), fn=fn, phase=phase)
+
+
+def _listen_for_compiles(col: "Collector"):
+    global _compile_listener_on
+    import jax
+
+    _COMPILE_SINKS.add(col)
+    if not _compile_listener_on:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_listener_on = True
 
 
 def _jsonable(v):
@@ -199,6 +267,10 @@ class Collector:
             head["sink_degraded"] = True
         head.update({k: _jsonable(v) for k, v in (meta or {}).items()})
         self._emit(head)
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        _listen_for_compiles(self)
 
     # -- emission ------------------------------------------------------------
     def _emit(self, rec: dict):
@@ -327,6 +399,7 @@ class Collector:
                 self._fh.flush()
 
     def close(self):
+        _COMPILE_SINKS.discard(self)
         with self._lock:
             if self._fh is not None:
                 self._fh.close()

@@ -22,8 +22,8 @@ import numpy as np
 from . import export, schema
 
 __all__ = ["phase_summary", "counter_totals", "last_gauges",
-           "error_trajectory", "format_table", "merge_records",
-           "per_rank_phase_summary", "main"]
+           "error_trajectory", "compile_summary", "format_table",
+           "merge_records", "per_rank_phase_summary", "main"]
 
 
 def phase_summary(records) -> list[dict]:
@@ -74,6 +74,27 @@ def error_trajectory(records) -> list[dict]:
             out.append({"iters": a.get("iters"), "err": a.get("err"),
                         "per_step_s": a.get("per_step_s")})
     return out
+
+
+def compile_summary(records, until_ts: float | None = None) -> list[dict]:
+    """Compiles and their seconds per (function, phase), from the
+    ``jit.compiles`` / ``jit.compile_s`` counters recorded at or before
+    ``until_ts`` (all of them by default), most seconds first."""
+    by: dict[tuple, list] = {}
+    for r in records:
+        if r.get("kind") != "counter" or r["name"] not in (
+                "jit.compiles", "jit.compile_s"):
+            continue
+        if until_ts is not None and r["ts"] > until_ts:
+            continue
+        lab = r.get("labels") or {}
+        row = by.setdefault((lab.get("fn", "?"), lab.get("phase", "?")),
+                            [0, 0.0])
+        row[0 if r["name"] == "jit.compiles" else 1] += r["value"]
+    rows = [{"fn": fn, "phase": ph, "compiles": int(n), "seconds": float(t)}
+            for (fn, ph), (n, t) in by.items()]
+    rows.sort(key=lambda r: -r["seconds"])
+    return rows
 
 
 def _stream_rank(path: str, records) -> object:
@@ -173,6 +194,8 @@ def render(records, out=None, per_rank: bool = False):
          ["gauge", "labels", "value"]),
         ("Error trajectory", error_trajectory(records),
          ["iters", "err", "per_step_s"]),
+        ("Compiles", compile_summary(records),
+         ["fn", "phase", "compiles", "seconds"]),
     ]
     for title, rows, cols in tables:
         t = format_table(rows, cols, title)

@@ -1,13 +1,14 @@
 """Runtime telemetry: no-op-when-disabled, JSONL/Perfetto schema,
-device-metric harvesting at existing sync points, roofline attribution.
+device-metric harvesting at existing sync points, spans on the
+profiler's clock, compile counts.
 
 The two contracts under test:
   * disabled mode is FREE — the global collector is the shared no-op
-    singleton and the traced solve program is byte-identical with
-    telemetry on or off (zero-host-sync rule);
+    singleton and the traced program of every instrumented entry point
+    is byte-identical with telemetry on or off (zero-host-sync rule);
   * enabled mode writes schema-valid JSONL whose records carry the
-    chunk spans / error trajectory / checkpoint latencies / roofline
-    fractions the observability issue names.
+    chunk spans / error trajectory / checkpoint latencies / compile
+    counts, and its spans land on the host lines of a profiler trace.
 """
 import json
 import os
@@ -20,7 +21,7 @@ import pytest
 from repro import telemetry
 from repro.core import fd3d, init_parallel_stencil, iterate, teff
 from repro.distributed import fault, halo
-from repro.telemetry import attrib, export, report, schema
+from repro.telemetry import export, report, schema
 
 ERR = {"err": "max_abs_diff(T2, T)"}
 
@@ -172,7 +173,6 @@ def test_enabled_chunked_jsonl_schema_and_content(rng, tmp_path):
     assert ("span", "checkpoint.snapshot") in names
     assert ("span", "checkpoint.write") in names
     assert ("event", "solve.trajectory") in names
-    assert ("event", "roofline") in names
     assert ("counter", "solve.steps") in names
     assert ("counter", "checkpoint.saves") in names
     assert ("gauge", "fault.ewma_step_s") in names
@@ -186,10 +186,6 @@ def test_enabled_chunked_jsonl_schema_and_content(rng, tmp_path):
     errs = [t["attrs"]["err"] for t in traj]
     assert errs[-1] == pytest.approx(float(res.err))
     assert all(e >= errs[-1] for e in errs[:1])  # diffusion decays
-    # roofline attribution present with a sane fraction
-    roof = [r for r in records
-            if r["kind"] == "event" and r["name"] == "roofline"]
-    assert 0 < roof[-1]["attrs"]["roofline_fraction"]
     # StepMonitor surfaced on the result
     assert res.step_stats is not None and 0 in res.step_stats
     assert res.step_stats[0]["ewma_s"] > 0
@@ -268,43 +264,7 @@ def test_report_cli(rng, tmp_path, capsys):
     assert os.path.exists(trace)
 
 
-# ---------------------------------------------------------------- roofline
-def test_roofline_fraction_hand_computed(rng):
-    """roofline_fraction on the 3-D diffusion kernel against hand math:
-    frac = t_model / t_measured and t_eff_measured = A_eff / t_measured,
-    with an explicit HardwareSpec so nothing depends on the host."""
-    shape = (16, 16, 16)
-    kern = diffusion_kernel()
-    sc = dict(lam=1.0, dt=0.05, _dx=1.0, _dy=1.0, _dz=1.0)
-    cost = kern.cost_model(T2=shape, T=shape, Ci=shape, **sc)
-    hw = teff.HardwareSpec("unit", peak_bw=100e9, peak_flops=1e12)
-    per_step_s = 1e-3
-    col = telemetry.Collector(None)
-    out = attrib.attribute(col, "kern", per_step_s, cost, hw=hw)
-    t_model = cost.predict_per_step_s(shape, 1, hw)
-    a = cost.a_eff_bytes(1)
-    assert out["roofline_fraction"] == pytest.approx(t_model / per_step_s)
-    assert out["t_eff_measured"] == pytest.approx(a / per_step_s)
-    assert out["t_eff_model"] == pytest.approx(a / t_model)
-    gauges = [r for r in col.records if r["kind"] == "gauge"]
-    byname = {g["name"]: g for g in gauges}
-    assert byname["roofline.fraction"]["value"] == pytest.approx(
-        t_model / per_step_s)
-    assert byname["roofline.fraction"]["labels"] == {"kernel": "kern"}
-    assert attrib.attribute(col, "kern", 0.0, cost, hw=hw) == {}
-
-
-def test_default_hardware_env_pin(monkeypatch):
-    monkeypatch.setenv("REPRO_TELEMETRY_BW_GBS", "123")
-    monkeypatch.setenv("REPRO_TELEMETRY_FLOPS_G", "456")
-    attrib.reset_hardware_cache()
-    hw = attrib.default_hardware()
-    assert hw.peak_bw == pytest.approx(123e9)
-    assert hw.peak_flops == pytest.approx(456e9)
-    assert attrib.default_hardware() is hw       # cached
-    attrib.reset_hardware_cache()
-
-
+# ------------------------------------------------------------------- peaks
 def test_device_peaks_keyed_by_kind():
     from repro.core import teff
     # JAX reports a v5e chip as "TPU v5 lite"
@@ -425,7 +385,6 @@ def test_telemetry_overhead_under_2pct(rng):
     kern = diffusion_kernel()
     fields = dict(T2=T, T=T, Ci=Ci)
     col = telemetry.Collector(None)
-    attrib.default_hardware()   # resolve the STREAM peak outside the timing
 
     def run(sel):
         res = iterate.solve_until(kern, fields, sc, tol=0.0, max_iters=20,
@@ -607,3 +566,261 @@ def test_collector_rank_stamp(tmp_path):
     recs = schema.load_records(path)
     assert recs and all(r.get("rank") == 3 for r in recs)
     telemetry.reset()
+
+
+# ------------------------------------------------ spans on the trace's clock
+def test_null_span_needs_no_import():
+    """Disabled, a span is the shared null span: nothing is imported for
+    it (no jax, no profiler) and ``set`` is a no-op."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from repro import telemetry as t\n"
+            "col = t.get()\n"
+            "with col.span('stencil.dispatch') as s:\n"
+            "    s.set(steps=1)\n"
+            "assert col is t.NULL and s is col.span('x')\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    env.pop("REPRO_TELEMETRY", None)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def _host_events(tdir):
+    """(name, line) of every event on a host plane of the newest trace."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    return [(e.name, ln.name) for pl in ProfileData.from_file(path).planes
+            if pl.name.startswith("/host:") for ln in pl.lines
+            for e in ln.events]
+
+
+def test_enabled_span_lands_on_profiler_host_line(tmp_path):
+    col = telemetry.configure(None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with col.span("probe.outer"):
+            with col.span("probe.inner"):
+                jax.block_until_ready(jnp.arange(8.0) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    lines = {name: line for name, line in events}
+    assert "probe.outer" in lines and "probe.inner" in lines
+    assert lines["probe.outer"] == lines["probe.inner"]   # one thread
+    spans = [r for r in col.records if r["kind"] == "span"]
+    assert [r["name"] for r in spans] == ["probe.inner", "probe.outer"]
+
+
+def test_span_set_attrs_inside_block():
+    col = telemetry.Collector(None)
+    with col.span("solve.chunk", phase="warm") as span:
+        span.set(steps=10)
+    with pytest.raises(ValueError):
+        with col.span("solve.chunk"):
+            raise ValueError("boom")
+    ok, failed = [r for r in col.records if r["kind"] == "span"]
+    assert ok["attrs"] == {"phase": "warm", "steps": 10}
+    assert failed["attrs"] == {"error": "ValueError"}
+
+
+def pallas_kernel(reductions=None):
+    ps = init_parallel_stencil(backend="pallas", ndims=3, interpret=True)
+
+    @ps.parallel(outputs=("T2",), rotations={"T2": "T"},
+                 reductions=reductions)
+    def fig1(T2, T, Ci, lam, dt, _dx, _dy, _dz):
+        return {"T2": fd3d.inn(T) + dt * (lam * fd3d.inn(Ci) * (
+            fd3d.d2_xi(T) * _dx ** 2 + fd3d.d2_yi(T) * _dy ** 2 +
+            fd3d.d2_zi(T) * _dz ** 2))}
+
+    return fig1
+
+
+def test_eager_call_dispatch_and_scalar_spans(rng):
+    """An eager Pallas call is one ``stencil.dispatch`` span holding one
+    ``stencil.scalars`` span; ``run_steps(k)`` is one dispatch too."""
+    T, Ci, sc = setup3d(rng, shape=(8, 8, 8))
+    kern = pallas_kernel()
+    col = telemetry.configure(None)
+    kern(T2=T, T=T, Ci=Ci, **sc)
+    kern.run_steps(2, T2=T, T=T, Ci=Ci, **sc)
+    spans = [r for r in col.records if r["kind"] == "span"]
+    names = [r["name"] for r in spans]
+    assert names == ["stencil.scalars", "stencil.dispatch",
+                     "stencil.scalars", "stencil.dispatch"]
+    inner, outer = spans[0], spans[1]
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur_s"] <= outer["ts"] + outer["dur_s"] + 1e-6
+
+
+@pytest.mark.parametrize("entry", ["eager_call", "run_steps", "solver"])
+def test_instrumented_jaxpr_identical_on_off(rng, entry):
+    T, Ci, sc = setup3d(rng, shape=(8, 8, 8))
+    if entry == "solver":
+        kern = pallas_kernel(ERR)
+        fn = iterate._jitted_solver(kern, sc, check_every=2, error=None,
+                                    until="below")
+        args = (dict(T2=T, T=T, Ci=Ci), 1e-5, 10)
+    else:
+        kern = pallas_kernel()
+        k = 1 if entry == "eager_call" else 2
+        fn = lambda a, b, c: kern.run_steps(k, T2=a, T=b, Ci=c, **sc)  # noqa: E731
+        args = (T, T, Ci)
+    off = str(jax.make_jaxpr(fn)(*args))
+    telemetry.configure(None)
+    on = str(jax.make_jaxpr(fn)(*args))
+    assert on == off
+    assert "callback" not in on
+
+
+def test_solve_until_harvests_in_one_transfer(rng, monkeypatch):
+    """The instrumented solve reads iters and err in one device-to-host
+    transfer; ``solve.dispatch`` ends inside ``solve_until``."""
+    T, Ci, sc = setup3d(rng)
+    kern = diffusion_kernel()
+    fields = dict(T2=T, T=T, Ci=Ci)
+    iterate.solve_until(kern, fields, sc, tol=2e-5, max_iters=50,
+                        check_every=5, telemetry=False)   # compile
+    calls = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: calls.append(x) or real(x))
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: calls.append(
+        "block") or x)
+    col = telemetry.Collector(None)
+    res = iterate.solve_until(kern, fields, sc, tol=2e-5, max_iters=50,
+                              check_every=5, telemetry=col)
+    assert len(calls) == 1 and len(calls[0]) == 2
+    spans = {r["name"]: r for r in col.records if r["kind"] == "span"}
+    solve, dispatch = spans["solve_until"], spans["solve.dispatch"]
+    assert solve["ts"] <= dispatch["ts"]
+    assert dispatch["dur_s"] <= solve["dur_s"]
+    assert solve["attrs"]["iters"] == int(res.iters)
+    assert solve["attrs"]["err"] == pytest.approx(float(res.err))
+    assert solve["attrs"]["cold"] is False
+
+
+def test_solve_check_named_scope_in_hlo(rng):
+    """The solve's check (checked launch, reductions, error) carries the
+    ``solve.check`` scope into the compiled program's metadata."""
+    T, Ci, sc = setup3d(rng, shape=(8, 8, 8))
+    solver = jax.jit(iterate.make_solver(diffusion_kernel(), sc,
+                                         check_every=2))
+    text = solver.lower(dict(T2=T, T=T, Ci=Ci), 1e-5, 10).compile().as_text()
+    assert "/solve.check/" in text
+
+
+# ----------------------------------------------------------- compile counts
+_COMPILE_PROBE = """
+import json
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro import telemetry
+from repro.telemetry import report
+col = telemetry.configure(None)
+
+@jax.jit
+def probe(x):
+    return jnp.sin(x) * 2 + 1
+
+probe(jnp.arange(16.0)).block_until_ready()
+print(json.dumps({r["phase"]: r["compiles"]
+                  for r in report.compile_summary(col.records)
+                  if r["fn"] == "jit(probe)"}))
+"""
+
+
+def test_jit_compiles_counts_compile_then_cache_fetch(tmp_path):
+    """Two processes sharing a persistent cache: the first compiles
+    ``probe``, the second fetches it (and does not compile)."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+                   os.path.dirname(__file__), "..", "src"))
+    env.pop("REPRO_TELEMETRY", None)
+    seen = []
+    for _ in range(2):
+        p = subprocess.run([sys.executable, "-c", _COMPILE_PROBE], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr
+        seen.append(json.loads(p.stdout.strip().splitlines()[-1]))
+    assert seen[0]["compile"] == 1 and "cache_fetch" not in seen[0]
+    assert seen[1]["cache_fetch"] == 1 and "compile" not in seen[1]
+    assert seen[0]["lower"] == seen[1]["lower"] == 1
+
+
+def test_compile_summary_by_function_and_phase():
+    col = telemetry.Collector(None)
+    for fn, phase, s, ts in (("jit(a)", "compile", 2.0, 10.0),
+                             ("jit(a)", "compile", 1.0, 20.0),
+                             ("jit(b)", "cache_fetch", 0.5, 30.0)):
+        col._emit({"kind": "counter", "ts": ts, "name": "jit.compiles",
+                   "value": 1, "labels": {"fn": fn, "phase": phase}})
+        col._emit({"kind": "counter", "ts": ts, "name": "jit.compile_s",
+                   "value": s, "labels": {"fn": fn, "phase": phase}})
+    rows = report.compile_summary(col.records)
+    assert rows == [
+        {"fn": "jit(a)", "phase": "compile", "compiles": 2, "seconds": 3.0},
+        {"fn": "jit(b)", "phase": "cache_fetch", "compiles": 1,
+         "seconds": 0.5}]
+    assert report.compile_summary(col.records, until_ts=15.0) == [
+        {"fn": "jit(a)", "phase": "compile", "compiles": 1, "seconds": 2.0}]
+
+
+def test_elastic_chunk_span_scopes_and_jaxpr():
+    """The elastic chunk driver on a 2x2x1 mesh of virtual devices: one
+    ``elastic.chunk`` span per call, the same jaxpr with telemetry on and
+    off, and the exchange and the check named in the compiled program."""
+    from conftest import run_subprocess
+
+    out = run_subprocess("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro import telemetry
+from repro.core import init_parallel_stencil, fd3d as fd
+from repro.distributed import elastic
+from repro.launch.mesh import make_mesh
+
+ps = init_parallel_stencil(backend="jnp", ndims=3)
+@ps.parallel(outputs=("T2",), rotations={"T2": "T"},
+             reductions={"err": "max_abs_diff(T2, T)"})
+def fig1(T2, T, Ci, lam, dt, _dx, _dy, _dz):
+    return {"T2": fd.inn(T) + dt*(lam*fd.inn(Ci)*(fd.d2_xi(T)*_dx**2
+            + fd.d2_yi(T)*_dy**2 + fd.d2_zi(T)*_dz**2))}
+sc = dict(lam=1.0, dt=1e-3, _dx=1.0, _dy=1.0, _dz=1.0)
+factors, axes = (2, 2, 1), ("x", "y", "z")
+mesh = make_mesh(factors, axes)
+rng = np.random.RandomState(0)
+g = {k: rng.rand(18, 18, 10).astype(np.float32) for k in ("T", "T2", "Ci")}
+stacked = elastic.remesh(elastic.decompose_fields(g, factors, 1), mesh,
+                         {k: P(*axes, None, None, None) for k in g})
+chunk = elastic.make_elastic_solver(fig1, sc, mesh, factors, axes, ("T",),
+                                    check_every=2)
+args = (stacked, jnp.float32(0.0), jnp.int32(4))
+off = str(jax.make_jaxpr(chunk)(*args))
+col = telemetry.configure(None)
+assert str(jax.make_jaxpr(chunk)(*args)) == off
+text = jax.jit(chunk).lower(*args).compile().as_text()
+assert "/halo.exchange/" in text and "/solve.check/" in text
+def chunks():
+    return [r for r in col.records
+            if r["kind"] == "span" and r["name"] == "elastic.chunk"]
+before = len(chunks())
+for _ in range(2):
+    jax.block_until_ready(chunk(*args))
+assert len(chunks()) == before + 2, chunks()
+print("ok")
+""", n_devices=4)
+    assert "ok" in out

@@ -11,6 +11,7 @@ may be loaded by one process at a time, so describing it while a module
 is imported would break the other test workers.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -156,3 +157,21 @@ def test_modes_refused_for_the_tpu(one_chip):
     with pytest.raises(NotImplementedError, match="Mosaic refuses"):
         jax.jit(lambda x, w: conv1d.conv1d_causal(x, w, interpret=False)
                 ).lower(x, w).compile()
+
+
+def test_launch_carries_parallel_name(one_chip):
+    """The compiled launch is named after the ``@parallel`` function and
+    carries it in its ``kernel_metadata``, where a device trace finds it;
+    the ``custom_call_target`` the benchmark matches is unchanged."""
+    step = fig1()
+
+    def f(T2, T, Ci):
+        return step(T2=T2, T=T, Ci=Ci, **SCALARS)
+
+    text = compiled_text(f, one_chip, shape=(64,) * 3)
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"'
+                       r'[^\n]*kernel_metadata=\{\s*([^}]*)\}', text)
+    assert len(calls) == 1
+    name, meta = calls[0]
+    assert re.sub(r"\.\d+$", "", name) == "step"
+    assert meta.strip() == '"parallel":"step"'
