@@ -24,7 +24,11 @@ memory/registers and sweeps it. The TPU-native equivalent built here:
   * scalars ride in SMEM;
   * launch parameters (grid + block shapes) are **derived automatically**
     from the array bounds, stencil radius and a VMEM budget, mirroring
-    ParallelStencil's automatic launch-parameter derivation;
+    ParallelStencil's automatic launch-parameter derivation; where the
+    derived blocks would tile the leading axis and refetch each other's
+    halo planes, the launch instead *streams* that axis through a VMEM
+    plane queue (``march_axis``, see :func:`build_stencil_call`), so each
+    plane crosses HBM once;
   * **temporal blocking** (``nsteps=k``): the VMEM window halo grows to
     ``k*radius`` and the update function is swept ``k`` times inside one
     launch, the valid region shrinking by ``radius`` per sweep. Each field
@@ -74,6 +78,7 @@ from __future__ import annotations
 import functools
 import math
 import os
+import sys
 from typing import Callable, Mapping, Sequence
 
 import jax
@@ -194,6 +199,16 @@ def _halo_pairs(halo, nd: int) -> tuple[tuple[int, int], ...]:
             lo, hi = h
             out.append((int(lo), int(hi)))
     return tuple(out)
+
+
+def _total_halo(radius: int, halos, nsteps: int, nd: int
+                ) -> tuple[tuple[int, int], ...]:
+    """Per-axis (lo, hi) window halo of all ``nsteps`` sweeps: the
+    symmetric ``radius`` or the per-axis single-sweep ``halos``, times k."""
+    k = max(int(nsteps), 1)
+    return tuple((k * lo, k * hi)
+                 for lo, hi in _halo_pairs(radius if halos is None else halos,
+                                           nd))
 
 
 def window_footprint_bytes(
@@ -400,10 +415,7 @@ def derive_launch(
     shape = tuple(int(s) for s in shape)
     nd = len(shape)
     k = max(int(nsteps), 1)
-    if halos is None:
-        halo = _halo_pairs(radius * k, nd)
-    else:
-        halo = tuple((k * lo, k * hi) for lo, hi in _halo_pairs(halos, nd))
+    halo = _total_halo(radius, halos, k, nd)
     if field_offsets is None:
         field_offsets = [(0,) * nd] * int(n_fields)
     field_offsets = [tuple(int(o) for o in off) for off in field_offsets]
@@ -457,12 +469,15 @@ def derive_launch(
             block[m] = fit[0] if fit else shape[m]
 
         # Shrink the largest non-minor axis first; keep lane alignment
-        # longest; whole axes never shrink.
+        # longest; whole axes never shrink, nor a march block below
+        # ``march_min_block`` (a fused neumann0 shift needs its cells).
         while footprint(block) > vmem_budget:
             cands = sorted((a for a in range(nd) if a not in whole),
                            key=lambda a: (a == nd - 1, -block[a]))
             for a in cands:
-                smaller = [d for d in _divisors_leq(shape[a], block[a] - 1)]
+                floor = march_min_block if a == march_axis else 1
+                smaller = [d for d in _divisors_leq(shape[a], block[a] - 1)
+                           if d >= floor]
                 if smaller:
                     block[a] = smaller[-1]
                     break
@@ -475,6 +490,57 @@ def derive_launch(
                        explicit_tile=False)
     grid = tuple(s // b for s, b in zip(shape, block))
     return grid, block
+
+
+def derive_default_launch(
+    shape: Sequence[int],
+    radius: int,
+    n_fields: int,
+    itemsize: int,
+    *,
+    nsteps: int = 1,
+    field_offsets: Sequence[Sequence[int]] | None = None,
+    halos: Sequence[tuple[int, int]] | None = None,
+    march_min_block: int = 1,
+    vmem_limit: int | None = None,
+    **kw,
+) -> tuple[tuple[int, ...], tuple[int, ...], int | None]:
+    """The launch of a caller that fixed neither ``tile`` nor
+    ``march_axis``: ``(grid, block, march_axis)``.
+
+    The all-parallel :func:`derive_launch` geometry, unless it tiles axis
+    0 and carries a halo along it — then every block would refetch its
+    neighbours' halo planes, and the launch streams along axis 0 instead
+    (each plane crosses HBM once per launch), provided no field is
+    staggered along axis 0, axis 0 fills the plane queue, and the
+    streamed footprint passes the VMEM preflight. ``kw`` goes to
+    :func:`derive_launch` (never ``tile`` or ``march_axis``);
+    ``march_min_block`` applies to the streamed derivation only."""
+    shape = tuple(int(s) for s in shape)
+
+    def derive(**extra):
+        return derive_launch(shape, radius, n_fields, itemsize, nsteps=nsteps,
+                             field_offsets=field_offsets, halos=halos,
+                             **kw, **extra)
+
+    def all_parallel():
+        return derive(vmem_limit=vmem_limit) + (None,)
+
+    # the all-parallel geometry; the launch kept is admitted below
+    grid, _ = derive(vmem_limit=sys.maxsize)
+    lo, hi = _total_halo(radius, halos, nsteps, len(shape))[0]
+    if grid[0] == 1 or not lo + hi or any(
+            off[0] for off in field_offsets or ()):
+        return all_parallel()
+    try:
+        s_grid, s_block = derive(march_axis=0, vmem_limit=vmem_limit,
+                                 march_min_block=march_min_block)
+    except LaunchFootprintError:
+        return all_parallel()
+    q_blocks, _, _ = march_queue_blocks(s_block[0], (lo, hi))
+    if shape[0] < q_blocks * s_block[0]:
+        return all_parallel()
+    return s_grid, s_block, 0
 
 
 def window_spec(extent: Sequence[int], index_map: Callable) -> pl.BlockSpec:
@@ -772,7 +838,14 @@ def build_stencil_call(
     drain steps flush the tail), which is transparent to the caller.
     Fields staggered along the march axis are unsupported (ValueError);
     a march extent smaller than the queue falls back to the all-parallel
-    path (``run.march_fallback``).
+    path (``run.march_fallback``). Without ``tile`` and ``march_axis``
+    the launch streams along axis 0 wherever the all-parallel blocks
+    would tile it and refetch its halo planes
+    (:func:`derive_default_launch`); an explicit ``tile`` or
+    ``march_axis`` builds exactly the launch it names. Each build counts
+    ``stencil.builds{fn, path}`` (``path``: ``streamed``, ``refetched``
+    — axis 0 tiled by overlapping windows — or ``whole``, axis 0 in one
+    block).
 
     Reductions (``reductions={name: ir.Reduction | "kind(field[, other])"}``):
     named convergence/conservation checks (``max_abs``, ``max_abs_diff``,
@@ -904,30 +977,38 @@ def build_stencil_call(
                     "or drop march_axis"
                 )
 
-    def _derive(m):
-        # The TPU layout (derive_launch): the two minor axes stay whole
-        # and leading axes are tiled with clamped windows; interpret mode
-        # also honours explicit tiles across any axis (the CPU test path
-        # for the general multi-axis geometry).
+    def _min_block(m):
         # Fused neumann0 conditions copy through frame-local shifts, so
         # the (small, halo-sized) march block must still hold 2*depth
         # cells along the marched axis.
-        min_bm = 1
-        if m is not None:
-            for o, c in inkernel_bc.items():
-                if c.kind == "neumann0" and m in c.resolved_axes(nd):
-                    min_bm = max(min_bm, 2 * c.depth + offsets[o][m])
-        return derive_launch(
-            shape, radius, len(field_names), dtype.itemsize, vmem_budget,
-            tile, nsteps=nsteps,
-            field_offsets=[offsets[n] for n in field_names],
-            halos=None if halos is None else sweep_halo,
-            march_axis=m, march_min_block=min_bm,
-            tpu_layout=True, compiled=not interpret,
-            n_out=len(out_names), compute_itemsize=compute_dtype.itemsize,
-        )
+        return max([1] + [2 * c.depth + offsets[o][m]
+                          for o, c in inkernel_bc.items()
+                          if c.kind == "neumann0" and m in c.resolved_axes(nd)])
 
-    grid, block = _derive(march)
+    # The TPU layout (derive_launch): the two minor axes stay whole and
+    # leading axes are tiled with clamped windows; interpret mode also
+    # honours explicit tiles across any axis (the CPU test path for the
+    # general multi-axis geometry).
+    derive_kw = dict(
+        vmem_budget=vmem_budget, nsteps=nsteps,
+        field_offsets=[offsets[n] for n in field_names],
+        halos=None if halos is None else sweep_halo,
+        tpu_layout=True, compiled=not interpret, n_out=len(out_names),
+        compute_itemsize=compute_dtype.itemsize)
+
+    def _derive(m):
+        return derive_launch(
+            shape, radius, len(field_names), dtype.itemsize, tile=tile,
+            march_axis=m, march_min_block=_min_block(m), **derive_kw)
+
+    if tile is None and march is None:
+        # The caller chose no launch: stream axis 0 where the
+        # all-parallel blocks would refetch its halo planes.
+        grid, block, march = derive_default_launch(
+            shape, radius, len(field_names), dtype.itemsize,
+            march_min_block=_min_block(0), **derive_kw)
+    else:
+        grid, block = _derive(march)
     if march is not None:
         q_blocks, llo_b, lhi_b = march_queue_blocks(
             block[march], (nsteps * sweep_halo[march][0],
@@ -1248,6 +1329,10 @@ def build_stencil_call(
                 for rn, p in zip(red_names, partials)}
         return outs, reds
 
+    # once per built launch (never per call): which geometry axis 0 got
+    _telemetry.count("stencil.builds", fn=name,
+                     path="streamed" if march is not None
+                     else "whole" if grid[0] == 1 else "refetched")
     run.grid = grid
     run.block = block
     run.nsteps = nsteps

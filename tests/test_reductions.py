@@ -162,7 +162,8 @@ def test_march_reduction_parity(backend, rng):
     # overwritten, drain flushing the tail) must equal the all-parallel
     # fold
     T, Ci, sc = setup3d(rng, shape=(24, 16, 16))
-    kern = diffusion_kernel(backend)
+    # the tile pins the all-parallel reference (the default streams)
+    kern = diffusion_kernel(backend, tile=(4, 16, 16))
     out_ref, reds_ref = kern(T2=T, T=T, Ci=Ci, **sc)
     marched = kern.marched(0)
     out_m, reds_m = marched(T2=T, T=T, Ci=Ci, **sc)
@@ -177,7 +178,7 @@ def test_march_reduction_parity(backend, rng):
 def test_march_ksteps_reduction_parity(rng):
     T, Ci, sc = setup3d(rng, shape=(24, 16, 16))
     for backend in ("jnp", "pallas"):
-        kern = diffusion_kernel(backend)
+        kern = diffusion_kernel(backend, tile=(4, 16, 16))
         _, reds_ref = kern.run_steps(2, T2=T, T=T, Ci=Ci, **sc)
         out, reds = kern.marched(0).run_steps(2, T2=T, T=T, Ci=Ci, **sc)
         assert_reds_close(reds, reds_ref)

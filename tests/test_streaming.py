@@ -11,8 +11,11 @@ import jax.numpy as jnp
 import pytest
 
 from conftest import run_subprocess
+from repro import telemetry
 from repro.core import fd2d, fd3d, init_parallel_stencil, teff
+from repro.ir import BoundaryCondition
 from repro.kernels import autotune
+from repro.kernels.stencil import build_stencil_call
 from repro.launch import roofline as _roofline
 
 SHAPE3 = (20, 16, 24)
@@ -55,6 +58,89 @@ def test_streamed_matches_parallel(backend, march, rng):
 
 
 # ---------------------------------------------------------------------------
+# the default derivation streams axis 0 where all-parallel blocks refetch it
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def collector():
+    col = telemetry.configure(None)
+    yield col
+    telemetry.reset()
+
+
+def _builds(col, path):
+    return col.counters.get(
+        ("stencil.builds", (("fn", "k"), ("path", path))), 0)
+
+
+N = 512
+GP = dict(n_fields=5, radius=2, halos=((2, 2),) * 3, n_out=2)
+
+
+@pytest.mark.parametrize("case,kw,march,path", [
+    ("fig1-512", dict(shape=(N,) * 3, bm=1), 0, "streamed"),
+    ("rank-514", dict(shape=(N + 2,) * 3, bm=1), 0, "streamed"),
+    # five radius-2 frames fit the VMEM budget only one plane deep
+    ("gp-512", dict(shape=(N,) * 3, bm=1, **GP), 0, "streamed"),
+    # a fused neumann0 shift needs two planes in the block
+    ("neumann0-512", dict(shape=(N,) * 3, bm=2, bc={
+        "f0": BoundaryCondition("neumann0")}), 0, "streamed"),
+    ("grid-16", dict(shape=(16,) * 3), None, "whole"),
+    ("2d-512", dict(shape=(N, N)), None, "whole"),
+    ("staggered-axis0", dict(shape=(N,) * 3,
+                             field_shapes={"f0": (N - 1, N, N)}),
+     None, "refetched"),
+    ("explicit-tile", dict(shape=(N,) * 3, tile=(2, N, N)), None,
+     "refetched"),
+    ("explicit-march", dict(shape=(N,) * 3, march_axis=1), 1, "streamed"),
+])
+def test_default_launch_streams_leading_axis(collector, case, kw, march,
+                                             path):
+    """Geometry alone (nothing is traced): a launch the caller left to
+    the derivation streams along axis 0 when its all-parallel blocks
+    would tile axis 0 with a halo; a one-block axis 0, a 2-D grid, a
+    field staggered on axis 0 and an explicit tile keep the all-parallel
+    launch, and an explicit march axis is built as given."""
+    kw = dict(kw)
+    bm = kw.pop("bm", None)
+    names = tuple(f"f{i}" for i in range(kw.pop("n_fields", 3)))
+    run = build_stencil_call(
+        lambda f, s: {}, name="k", field_names=names,
+        out_names=names[:kw.pop("n_out", 1)], scalar_names=(),
+        radius=kw.pop("radius", 1), dtype="float32", interpret=True, **kw)
+    assert run.march_axis == march and not run.march_fallback
+    if "tile" in kw:
+        assert run.block == kw["tile"]
+    if bm is not None:
+        # a march block of halo depth (or the least that fits): each
+        # plane is fetched once, plus one drain block
+        assert run.block[0] == bm and run.grid[0] == kw["shape"][0] // bm
+    assert (run.grid[0] == 1) == (path == "whole")
+    assert _builds(collector, path) == 1
+    assert sum(collector.counters.values()) == 1
+
+
+def test_default_launch_streams_in_interpret_mode(collector, rng):
+    """On a grid whose axis 0 the all-parallel derivation tiles, the
+    default launch streams, equals the all-parallel launch pinned by an
+    explicit tile, and counts one streamed build however often it runs."""
+    shape = (64, 8, 128)
+    T = jnp.asarray(rng.rand(*shape), jnp.float32)
+    Ci = jnp.asarray(rng.rand(*shape) + 0.5, jnp.float32)
+    k = _diffusion("pallas")
+    got = np.asarray(k(T2=T, T=T, Ci=Ci, **SC3))
+    k(T2=T, T=T, Ci=Ci, **SC3)
+    (info,) = k.launch_info.values()
+    assert info["march_axis"] == 0 and info["grid"] == (64, 1, 1)
+    assert collector.counters[("stencil.builds",
+                               (("fn", "kern"), ("path", "streamed")))] == 1
+    pinned = _diffusion("pallas", tile=(16, 8, 128))
+    want = np.asarray(pinned(T2=T, T=T, Ci=Ci, **SC3))
+    (pinfo,) = pinned.launch_info.values()
+    assert pinfo["march_axis"] is None and pinfo["grid"] == (4, 1, 1)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # temporal blocking: streamed k-step == all-parallel k-step, and the
 # streamed kernel is self-consistent (fused vs sequential, same object)
 # ---------------------------------------------------------------------------
@@ -62,8 +148,8 @@ def test_streamed_matches_parallel(backend, march, rng):
 @pytest.mark.parametrize("k", [1, 2, 4])
 def test_streamed_temporal_matches_parallel(backend, k, rng):
     T2, T, Ci = _fields3(rng)
-    want = np.asarray(_diffusion(backend).run_steps(k, T2=T2, T=T, Ci=Ci,
-                                                    **SC3))
+    want = np.asarray(_diffusion(backend, tile=(4, 4, 8)).run_steps(
+        k, T2=T2, T=T, Ci=Ci, **SC3))
     kern = _diffusion(backend, march=0, tile=(4, 4, 8))
     got = np.asarray(kern.run_steps(k, T2=T2, T=T, Ci=Ci, **SC3))
     np.testing.assert_allclose(got, want, atol=1e-6)

@@ -93,7 +93,9 @@ def test_vmem_accounting_covers_mosaic(one_chip, monkeypatch, dtype, march,
     """The preflight's footprint bounds what Mosaic really allocates: a
     launch compiled with its scoped-VMEM limit set to its own accounted
     footprint still compiles. (N+2)^3 is one rank of a 512^3-per-chip
-    decomposition; its minor dims are padded to whole lane tiles."""
+    decomposition; its minor dims are padded to whole lane tiles. With
+    ``march`` None the derivation chooses, and streams these shapes
+    along axis 0."""
     step = fig1(dtype, march_axis=march)
 
     def f(T2, T, Ci):
@@ -106,6 +108,61 @@ def test_vmem_accounting_covers_mosaic(one_chip, monkeypatch, dtype, march,
     step = fig1(dtype, march_axis=march)
     assert "tpu_custom_call" in compiled_text(f, one_chip, shape=shape,
                                               dtype=dtype)
+
+
+@pytest.mark.parametrize("n", [N, N + 2])
+def test_vmem_accounting_covers_mosaic_all_parallel(one_chip, monkeypatch,
+                                                    n):
+    """The same bound for the all-parallel launch an explicit tile pins
+    (two-plane blocks, the derivation's choice before it streamed)."""
+    tile = (2, n, n)
+
+    def f(T2, T, Ci):
+        return step(T2=T2, T=T, Ci=Ci, **SCALARS)
+
+    step = fig1(tile=tile)
+    x = jax.ShapeDtypeStruct((n,) * 3, jnp.float32)
+    jax.eval_shape(f, x, x, x)
+    (info,) = step.launch_info.values()
+    assert info["march_axis"] is None and info["block"] == tile
+    monkeypatch.setenv("REPRO_VMEM_LIMIT_BYTES", str(info["vmem_bytes"]))
+    step = fig1(tile=tile)
+    assert "tpu_custom_call" in compiled_text(f, one_chip, shape=(n,) * 3)
+
+
+def _cell_launch(case):
+    """The launch a benchmark cell builds, by default derivation: the
+    kernel, the function of three fields that runs it, and the shape."""
+    if case == "gp-fused":
+        from examples import gross_pitaevskii as gp
+
+        cfg = gp.GPConfig(n=N, backend="pallas", fused=True, interpret=False)
+        step = gp.make_step(gp.make_grid(cfg), cfg)
+        return (step.kernels[0],
+                lambda re, im, V: step(re, im, 1e-3, V), (N,) * 3)
+    kw = {"reductions": {"err": "max_abs_diff(T2, T)"}} \
+        if case == "fig1-reductions" else {}
+    step = fig1(**kw)
+    return (step, lambda T2, T, Ci: step(T2=T2, T=T, Ci=Ci, **SCALARS),
+            (N + 2,) * 3 if case == "rank-514" else (N,) * 3)
+
+
+@pytest.mark.parametrize("case", ["fig1-reductions", "rank-514", "gp-fused"])
+def test_cell_launches_stream_and_compile_for_v5e(one_chip, monkeypatch,
+                                                  case):
+    """The default-derived launches the benchmark's cells run — Fig. 1
+    with its fused check (the solve cell), one 514^3 rank of the four-chip
+    cell, GP's fused radius-2 update — stream along axis 0, compile, and
+    their accounted VMEM bounds what Mosaic allocates (each compiles with
+    its scoped-VMEM limit set to its own footprint)."""
+    kern, f, shape = _cell_launch(case)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    jax.eval_shape(f, x, x, x)
+    (info,) = kern.launch_info.values()
+    assert info["march_axis"] == 0 and info["block"][1:] == shape[1:]
+    monkeypatch.setenv("REPRO_VMEM_LIMIT_BYTES", str(info["vmem_bytes"]))
+    kern, f, shape = _cell_launch(case)
+    assert "tpu_custom_call" in compiled_text(f, one_chip, shape=shape)
 
 
 @pytest.mark.parametrize("march", [None, 0])
