@@ -1,0 +1,9 @@
+"""The Stokes3D launch ``compute_V``'s share of the HBM roofline
+(velocities with free-slip walls: 6 field operands and 3
+results, each counted once) over the chip's HBM bandwidth times the
+launch's device time in the traced window (``yardstick/launch_share.py``)."""
+from yardstick import launch_share
+
+
+def read(ctx):
+    return launch_share.read(ctx, "compute_V")

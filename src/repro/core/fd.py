@@ -14,7 +14,10 @@ here (DESIGN.md C1/C2).
 Naming follows ParallelStencil:
   ``*_a``  operate over the full extent of the differentiated axis,
   ``*_i``  additionally restrict all *other* axes to their interior,
-  ``inn``  selects the interior in all axes.
+  ``inn``  selects the interior in all axes;
+  ``av_xya``/``av_xyi`` (and ``xz``, ``yz``; 3-D) average the 4 cells
+  around an edge over two axes (the shear-stress averages of a
+  staggered Stokes grid).
 
 All operators reduce the differentiated axis length by their stencil width;
 combined with ``inn``-style selection the results of e.g. ``d2_xi``,
@@ -54,6 +57,13 @@ class FiniteDifferences:
             setattr(self, f"d2_{name}i", self._make(self._d2, axis, inner_other=True))
             setattr(self, f"av_{name}a", self._make(self._av, axis, inner_other=False))
             setattr(self, f"av_{name}i", self._make(self._av, axis, inner_other=True))
+        # Two-axis averages (3-D: av_xya, av_xyi, av_xza, ...): the mean of
+        # the 4 cells around an edge; `_i` takes the remaining axis's interior.
+        if ndim == 3:
+            for a, b in ((0, 1), (0, 2), (1, 2)):
+                nm = ax_names[a] + ax_names[b]
+                setattr(self, f"av_{nm}a", self._make_av2(a, b, inner_other=False))
+                setattr(self, f"av_{nm}i", self._make_av2(a, b, inner_other=True))
 
     # -- primitive stencils ------------------------------------------------
     def _d(self, A, axis, other):
@@ -79,6 +89,23 @@ class FiniteDifferences:
         def f(A):
             return op(A, axis, other)
         f.__name__ = f"{op.__name__}_ax{axis}_{'i' if inner_other else 'a'}"
+        return f
+
+    def _make_av2(self, a, b, inner_other):
+        """ParallelStencil's ``@av_xyi``-style average over axes ``a`` and
+        ``b``: ``(A[i,j] + A[i+1,j] + A[i,j+1] + A[i+1,j+1]) * 0.25``, summed
+        in that order."""
+        other = _INN if inner_other else _FULL
+
+        def corner(da, db):
+            return tuple(slice(da, None if da else -1) if ax == a
+                         else slice(db, None if db else -1) if ax == b
+                         else other for ax in range(self.ndim))
+
+        def f(A):
+            return (A[corner(0, 0)] + A[corner(1, 0)] + A[corner(0, 1)]
+                    + A[corner(1, 1)]) * 0.25
+        f.__name__ = f"_av2_ax{a}{b}_{'i' if inner_other else 'a'}"
         return f
 
     # -- interior / neighborhood ops ---------------------------------------

@@ -15,6 +15,12 @@ checked one), rotates the double buffers in place (the carry is donated
 reads the fused error scalar. Zero host transfers from the first step to
 convergence; one compiled program regardless of iteration count.
 
+One iteration may be several dependent launches (``kernel`` a sequence):
+each reads the carry as the launches before it left it, and the check
+block's last iteration runs every launch that declares reductions in its
+checked variant, so a convergence scalar may combine reductions folded
+in different launches (the Stokes residuals and the divergence).
+
 ``until="below"`` runs while ``err > tol`` (convergence: stop once the
 residual drops under tol); ``until="above"`` runs while ``err <= tol``
 (drift guard: stop once a conserved-quantity error exceeds tol).
@@ -51,6 +57,7 @@ one attribute check.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import time
 import weakref
 from typing import Any, Callable, Mapping, Optional, Union
@@ -93,7 +100,8 @@ def _scalars_cache_key(scalars) -> Optional[tuple]:
 
 
 def _jitted_solver(kernel, scalars, *, check_every, error, until):
-    """The jitted driver for (kernel, scalars, policy), memoized."""
+    """The jitted driver for (kernel or launch sequence, scalars, policy),
+    memoized on the first launch."""
     def build():
         return jax.jit(make_solver(kernel, scalars, check_every=check_every,
                                    error=error, until=until))
@@ -101,16 +109,18 @@ def _jitted_solver(kernel, scalars, *, check_every, error, until):
     skey = _scalars_cache_key(scalars)
     if skey is None:
         return build()
+    launches = _launches(kernel)
     err_key = error if (error is None or isinstance(error, str)) \
         else id(error)
-    key = (int(check_every), err_key, until, skey)
+    key = (int(check_every), err_key, until, skey,
+           tuple(id(k) for k in launches[1:]))
     try:
-        cache = _SOLVER_CACHE.setdefault(kernel, {})
+        cache = _SOLVER_CACHE.setdefault(launches[0], {})
     except TypeError:                      # kernel not weak-referenceable
         return build()
     if key not in cache:
         keep = [v for v in (scalars or {}).values()
-                if isinstance(v, jax.Array)]
+                if isinstance(v, jax.Array)] + list(launches[1:])
         cache[key] = (build(), keep)
     return cache[key][0]
 
@@ -173,24 +183,56 @@ class SolveResult:
         return tgts
 
 
+def _launches(kernel) -> tuple:
+    """The launches of one iteration: a ``StencilKernel``, or a sequence
+    of them run in order."""
+    launches = tuple(kernel) if isinstance(kernel, (tuple, list)) \
+        else (kernel,)
+    if not launches:
+        raise ValueError("an iteration needs at least one kernel")
+    return launches
+
+
+def _declared(launches) -> dict:
+    """The fused reductions of every launch of the iteration, by name."""
+    reds = {}
+    for k in launches:
+        for name, r in k.reductions.items():
+            if name in reds:
+                raise ValueError(
+                    f"reduction {name!r} is declared by two launches of the "
+                    "iteration; give each its own name")
+            reds[name] = r
+    return reds
+
+
 def _resolve_error(kernel, error) -> Callable[[Mapping[str, Any]], Any]:
+    declared = _declared(_launches(kernel))
     if error is None:
-        if len(kernel.reductions) != 1:
+        if len(declared) != 1:
             raise ValueError(
-                f"kernel declares reductions {tuple(kernel.reductions)}; "
+                f"kernel declares reductions {tuple(declared)}; "
                 "pass error=<name> (or a callable over the reduction dict) "
                 "to pick the convergence scalar"
             )
-        error = next(iter(kernel.reductions))
+        error = next(iter(declared))
     if isinstance(error, str):
-        if error not in kernel.reductions:
+        if error not in declared:
             raise ValueError(
                 f"error={error!r} is not a declared reduction "
-                f"(have {tuple(kernel.reductions)})"
+                f"(have {tuple(declared)})"
             )
         name = error
         return lambda reds: reds[name]
     return error
+
+
+def _arguments(kernel):
+    """The argument names ``kernel`` takes (None: it takes any)."""
+    params = inspect.signature(kernel.fn).parameters.values()
+    if any(p.kind is p.VAR_KEYWORD for p in params):
+        return None
+    return {p.name for p in params}
 
 
 def make_solver(
@@ -204,41 +246,62 @@ def make_solver(
     """Build the un-jitted driver ``solver(fields, tol, max_iters) ->
     (fields, reds, err, iters)`` for :func:`solve_until`.
 
+    ``kernel`` is one ``@parallel`` kernel or a sequence of them, one
+    iteration's launches in order. The carry holds every field by name;
+    each launch takes its own arguments from it (and from ``scalars``),
+    and its outputs rebind the carry's names for the launches after it:
+    a rotated output swaps with its partner (``T2 -> T``), any other
+    takes its own name (written fresh, or updated in place). Every
+    launch that declares
+    reductions runs its checked variant in the last iteration of each
+    check block, its reduction-free variant otherwise; ``error`` reads
+    the reductions of all of them, merged by name.
+
     Exposed separately so callers (and the zero-host-sync test) can
     inspect the traced program: ``jax.make_jaxpr(solver)(...)`` is ONE
     ``while`` — no transfers, no callbacks between checks.
     """
-    if not kernel.reductions:
+    launches = _launches(kernel)
+    declared = _declared(launches)
+    if not declared:
         raise ValueError(
             "solve_until needs a kernel with fused reductions "
             "(declare reductions={'err': 'max_abs_diff(T2, T)'}-style on "
             "@parallel)"
-        )
-    rot = kernel.rotations
-    if not rot or set(kernel.outputs) - set(rot):
-        raise ValueError(
-            "solve_until rotates double buffers between steps and needs "
-            "rotations covering every output (pass rotations={'T2': 'T'}-"
-            "style mapping to @parallel)"
         )
     check_every = int(check_every)
     if check_every < 1:
         raise ValueError(f"check_every must be >= 1, got {check_every}")
     if until not in ("below", "above"):
         raise ValueError(f"until must be 'below' or 'above', got {until!r}")
-    err_fn = _resolve_error(kernel, error)
+    err_fn = _resolve_error(launches, error)
     scalars = dict(scalars or {})
-    plain = kernel.with_reductions(None)
-    single = len(kernel.outputs) == 1
+    plains = tuple(k.with_reductions(None) for k in launches)
+    takes = [_arguments(k) for k in launches]
+    _telemetry.count("solve.launches", len(launches),
+                     fn=_kernel_label(launches))
 
-    def as_dict(res):
-        return {kernel.outputs[0]: res} if single else dict(res)
-
-    def rotate(cur, outs):
-        cur = dict(cur)
-        for o, tgt in rot.items():
-            cur[o], cur[tgt] = cur[tgt], outs[o]
-        return cur
+    def iteration(cur, kernels):
+        """One pass of the launches; returns the carry and the merged
+        reductions."""
+        cur, reds = dict(cur), {}
+        for k, names in zip(kernels, takes):
+            args = {n: v for n, v in cur.items()
+                    if names is None or n in names}
+            args.update((n, v) for n, v in scalars.items()
+                        if names is None or n in names)
+            res = k(**args)
+            if k.reductions:
+                res, r = res
+                reds.update(r)
+            outs = {k.outputs[0]: res} if len(k.outputs) == 1 else res
+            for o in k.outputs:
+                tgt = k.rotations.get(o)
+                if tgt is None:
+                    cur[o] = outs[o]
+                else:
+                    cur[o], cur[tgt] = cur[tgt], outs[o]
+        return cur, reds
 
     def solver(fields, tol, max_iters):
         tol = jnp.asarray(tol, jnp.float32)
@@ -246,9 +309,9 @@ def make_solver(
         # Carry fields at the kernel's STORAGE dtype: a bf16-storage
         # kernel returns bf16 buffers, so f32 initial fields would make
         # the while_loop carry type-unstable after the first rotation.
-        st = kernel.ps.dtype
+        st = launches[0].ps.dtype
         cur0 = {n: jnp.asarray(v, st) for n, v in fields.items()}
-        reds0 = {n: jnp.zeros((), jnp.float32) for n in kernel.reductions}
+        reds0 = {n: jnp.zeros((), jnp.float32) for n in declared}
         err0 = jnp.float32(jnp.inf if until == "below" else -jnp.inf)
 
         def cond(state):
@@ -259,13 +322,12 @@ def make_solver(
         def body(state):
             cur, _, _, it = state
             for _ in range(check_every - 1):
-                cur = rotate(cur, as_dict(plain(**cur, **scalars)))
+                cur, _ = iteration(cur, plains)
             with jax.named_scope("solve.check"):
-                outs, reds = kernel(**cur, **scalars)
+                cur, reds = iteration(cur, launches)
                 reds = {n: jnp.asarray(v, jnp.float32)
                         for n, v in reds.items()}
                 err = jnp.asarray(err_fn(reds), jnp.float32)
-            cur = rotate(cur, as_dict(outs))
             return cur, reds, err, it + check_every
 
         return jax.lax.while_loop(cond, body, (cur0, reds0, err0,
@@ -280,7 +342,9 @@ def _crossed(err: float, tol: float, until: str) -> bool:
 
 
 def _kernel_label(kernel) -> str:
-    return getattr(kernel.fn, "__name__", "kernel")
+    """The launches' names, ``+``-joined for a sequence."""
+    return "+".join(getattr(k.fn, "__name__", "kernel")
+                    for k in _launches(kernel))
 
 
 def _solve_checkpointed(
@@ -306,8 +370,9 @@ def _solve_checkpointed(
     block = save_every * check_every
     # storage-dtype carry (same rationale as make_solver): resume-vs-
     # fresh stay bitwise because checkpoints then hold storage dtype too
-    cur = {n: jnp.asarray(v, kernel.ps.dtype) for n, v in fields.items()}
-    reds = {n: jnp.zeros((), jnp.float32) for n in kernel.reductions}
+    launches = _launches(kernel)
+    cur = {n: jnp.asarray(v, launches[0].ps.dtype) for n, v in fields.items()}
+    reds = {n: jnp.zeros((), jnp.float32) for n in _declared(launches)}
     err = jnp.float32(jnp.inf if until == "below" else -jnp.inf)
     done, resumed_from = 0, None
 
@@ -394,12 +459,16 @@ def solve_until(
     steps — zero host transfers between checks.
 
     ``kernel`` is a :class:`~repro.core.parallel.StencilKernel` with
-    ``reductions=`` and ``rotations=`` declared. ``fields`` maps every
-    field argument to its initial array; ``scalars`` the non-field
-    arguments. ``error`` picks the convergence scalar: a reduction name
-    (default: the single declared reduction) or a callable over the
-    reduction dict (e.g. a relative-drift formula); it must be cheap —
-    it runs inside the loop condition's body on device.
+    ``reductions=`` and ``rotations=`` declared, or a sequence of
+    kernels that together make one iteration (e.g. the pseudo-transient
+    Stokes solver's pressure, stress, residual and velocity launches;
+    see :func:`make_solver` for how their outputs rebind the carry).
+    ``fields`` maps every field argument of every launch to its initial
+    array; ``scalars`` the non-field arguments. ``error`` picks the
+    convergence scalar: a reduction name (default: the single declared
+    reduction) or a callable over the reduction dict (merged over the
+    launches; e.g. a relative-drift formula); it must be cheap — it runs
+    inside the loop condition's body on device.
 
     ``checkpoint`` (a directory path or :class:`Checkpointing`) makes
     the solve survivable: the loop is chunked at check boundaries, the

@@ -52,8 +52,9 @@ lives on cell faces, e.g. the Darcy flux ``qx`` of shape ``(nx-1, ny)``
 next to cell-centered ``phi``/``Pe`` of shape ``(nx, ny)``. Per-output
 write semantics follow the shape of the returned update along each axis:
 a symmetric interior margin writes the interior (``@inn``, boundary ring
-preserved), full-field extent writes everything (``@all`` — mandatory on
-staggered axes). See kernels/stencil.py for the window geometry.
+preserved; on a staggered axis the inferred halo reaches past the
+stagger), full-field extent writes everything (``@all``). See
+kernels/stencil.py for the window geometry.
 """
 from __future__ import annotations
 
@@ -184,7 +185,7 @@ class KernelGeometry:
     active), the staggering band, and the per-axis window halo."""
 
     ir: _ir.StencilIR | None
-    band: int                                  # staggering band radius
+    band: int                                  # largest staggering offset
     halos: tuple[tuple[int, int], ...] | None  # per-axis (lo, hi) or None
 
     @property
@@ -207,7 +208,7 @@ class StencilKernel:
         self.radius = radius
         self.tile = tile
         self.vmem_budget = vmem_budget
-        self.rotations = dict(rotations) if rotations else None
+        self.rotations = dict(rotations or {})
         self.bc = _ir.bc.normalize_bcs(bc, outputs, ps.ndims)
         self.march_axis = None if march_axis is None else int(march_axis)
         self.reductions = _ir.normalize_reductions(reductions)
@@ -342,12 +343,7 @@ class StencilKernel:
                             f"reduction {name!r} = {r.describe()} reads "
                             f"{op!r}, which is not a field of this kernel"
                         )
-                    if any(b - s for b, s in zip(base, shapes[op])):
-                        raise ValueError(
-                            f"reduction {name!r} = {r.describe()} reads "
-                            f"staggered field {op!r}; reduction operands "
-                            "must be collocated"
-                        )
+                _ir.reductions.check_operand_shapes(name, r, shapes)
 
         if ir is not None and self.radius is not None \
                 and ir.inferred_radius != self.radius:
@@ -360,15 +356,22 @@ class StencilKernel:
                 "geometry, or fix the declaration)"
             )
 
+        # The staggering band: how much shorter than the base a field of
+        # the system may be. On the legacy path the declared radius; with
+        # the inferred footprint of radius r, a face (the base) down to the
+        # cell interior r deep on each side, 2r + 1 (ParallelStencil's
+        # Stokes residual Rx, (n-1, n-2, n-2), beside the face field Vx,
+        # (n+1, n, n), at r = 1). With an inferred footprint the band only
+        # validates: the windows follow the traced halos.
         band = self.radius if self.radius is not None \
-            else max(ir.inferred_radius, 1)
+            else 2 * max(ir.inferred_radius, 1) + 1
         for n, s in shapes.items():
             off = tuple(b - x for b, x in zip(base, s))
             if any(o < 0 or o > band for o in off):
                 raise ValueError(
                     f"field {n!r} shape {s} is inconsistent with the coupled "
                     f"system's base shape {base}: per-axis offsets {off} "
-                    f"exceed the staggering band [0, radius={band}] — fields "
+                    f"exceed the staggering band [0, {band}] — fields "
                     "of one system must agree up to face/cell staggering"
                 )
         # bc face depths must fit the actual field extents

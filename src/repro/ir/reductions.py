@@ -32,8 +32,10 @@ but work standalone like any other kind.
 Operands name *fields of the launch*: an output operand reduces the
 freshly written values, an input operand the current (boundary-source)
 values — e.g. ``max_abs_diff(T2, T)`` is exactly ``max|T2_new - T|``.
-Operands must be collocated (no staggering): the per-tile domain masks
-of the partials fold over base-extent blocks.
+An operand may be staggered: it reduces over its own extent (the
+per-tile domain masks bound each block's cells by the operand's shape,
+so the base-extent padding a staggered output is stored with never
+enters the fold). The operands of one reduction share one shape.
 
 Cross-program caveat (the reassociation rule): reductions reassociate,
 so the fused value is *bitwise* reproducible only within one compiled
@@ -45,7 +47,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Sequence
 
-__all__ = ["Reduction", "normalize_reductions", "REDUCTION_KINDS"]
+__all__ = ["Reduction", "check_operand_shapes", "normalize_reductions",
+           "REDUCTION_KINDS"]
 
 # kind -> (arity, combine): combine is "max" or "sum" (both associative
 # and commutative — the partials may be folded in any tile order).
@@ -192,3 +195,15 @@ def normalize_reductions(
                     )
         out[str(name)] = r
     return out
+
+
+def check_operand_shapes(name: str, r: Reduction,
+                         shapes: Mapping[str, Sequence[int]]) -> None:
+    """The operands of one reduction must share one shape (each reduces
+    over its own extent; a difference of two staggerings has none)."""
+    got = {op: tuple(shapes[op]) for op in r.operands}
+    if len(set(got.values())) > 1:
+        raise ValueError(
+            f"reduction {name!r} = {r.describe()} reads operands of "
+            f"different staggered shapes {got}; the operands of one "
+            "reduction must share one shape")

@@ -23,7 +23,7 @@ import dataclasses
 from typing import Callable, Mapping, Sequence
 
 from . import sym
-from .reductions import Reduction, normalize_reductions
+from .reductions import Reduction, check_operand_shapes, normalize_reductions
 from .sym import SymArray, TraceError
 
 __all__ = ["StencilIR", "trace_stencil"]
@@ -154,14 +154,16 @@ def trace_stencil(
 
     ``reductions`` declares the launch's fused reduction epilogues
     (``{name: Reduction | "kind(field[, other])"}``): operands are
-    validated against the field set (collocated fields only) and each
+    validated against the field set (the operands of one reduction share
+    one shape; each reduces over its own, possibly staggered, extent) and each
     check's elementwise map is traced into ``red_exprs`` — the cost
     model then prices check flops exactly and check *traffic* at what a
     separate pass would pay (``check_io_bytes``).
 
     Raises :class:`TraceError` for untraceable constructs and plain
     ``ValueError`` for genuinely invalid kernels (bad write extents,
-    interior writes on staggered axes, staggered reduction operands).
+    interior writes on staggered axes, reduction operands of different
+    shapes).
     """
     shapes = {n: tuple(int(x) for x in s) for n, s in field_shapes.items()}
     if not shapes:
@@ -218,14 +220,18 @@ def trace_stencil(
                     max(halo[a][1], hi + offsets[f][a]),
                 )
             field_halo[f] = tuple(fh)
-    # A staggered `all`-write output must have its whole block frame
-    # covered by the update: the window needs at least `off` extra cells
-    # on the high side even when the kernel's *reads* are shallower
-    # (update extent on a window is B - off + lo + hi; covering the
-    # B-cell out frame needs hi >= off).
+    # A staggered output must have its whole block frame covered by the
+    # update: the window needs at least `off + w` extra cells on the high
+    # side even when the kernel's *reads* are shallower (update extent
+    # on a window is B - off + lo + hi - 2w, placed w cells in; covering
+    # the B-cell out frame needs hi >= off + w, with w = 0 for `all`).
+    # A collocated output's ring is the launch's concern (its window
+    # extends to the write rings), not the data footprint's.
     for o in out_names:
         for a, off_a in enumerate(offsets[o]):
-            halo[a] = (halo[a][0], max(halo[a][1], off_a))
+            if off_a:
+                halo[a] = (halo[a][0],
+                           max(halo[a][1], off_a + write_rings[o][a]))
     halo = tuple((max(lo, 0), max(hi, 0)) for lo, hi in halo)
     field_halo = {
         n: tuple((max(lo, 0), max(hi, 0)) for lo, hi in d)
@@ -240,13 +246,7 @@ def trace_stencil(
     reds = normalize_reductions(reductions, tuple(shapes))
     red_exprs: dict[str, SymArray] = {}
     for name, r in reds.items():
-        for op in r.operands:
-            if any(offsets[op]):
-                raise ValueError(
-                    f"reduction {name!r} = {r.describe()} reads staggered "
-                    f"field {op!r} (offsets {offsets[op]}); reduction "
-                    "operands must be collocated with the base grid"
-                )
+        check_operand_shapes(name, r, shapes)
         ops = [sym.field(op, shapes[op]) for op in r.operands]
         red_exprs[name] = r.map_element(*ops)
 

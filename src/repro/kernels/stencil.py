@@ -39,11 +39,13 @@ Coupled multi-field systems
 ---------------------------
 One launch may carry several simultaneous output fields (``out_names``)
 and **mixed-shape staggered fields**: a field whose extent along axis ``a``
-is ``shape[a] - off`` with ``0 <= off <= radius`` lives on cell faces
-(``off = 1`` is the classic face-centered flux next to cell-centered
-scalars). Per-field halo windows are derived from the field's staggering:
-a field with offset ``off`` gets a VMEM window of ``block + 2*halo - off``
-per axis, which is exactly what makes the *relative slice* fd operators
+is ``shape[a] - off`` with ``0 <= off <= radius`` (the staggering band)
+lives on cell faces (``off = 1`` is the classic face-centered flux next
+to cell-centered scalars; with an inferred footprint of radius r the band
+is 2r + 1, down to ParallelStencil's inner-face residuals). Per-field
+halo windows are derived from the field's staggering: a field with
+offset ``off`` gets a VMEM window of ``block + 2*halo - off`` per axis,
+which is exactly what makes the *relative slice* fd operators
 (``d_xa``, ``av_xa``, ``inn``, ...) consume shapes on windows the same way
 they do on full arrays — the single-source shape contract.
 
@@ -54,10 +56,10 @@ vs ``@all(qx)`` left-hand sides):
   * update extent == window extent - 2*radius  ->  ``inn``: interior
     write; the output's boundary ring keeps its previous values.
   * update extent == window extent             ->  ``all``: every
-    in-domain cell is written (no boundary ring). Staggered axes
-    (``off > 0``) *must* use ``all`` semantics: an interior-style
-    staggered write would leave the faces straddling block boundaries
-    covered by no block.
+    in-domain cell is written (no boundary ring). On staggered axes
+    (``off > 0``) an interior-style write needs the inferred footprint,
+    whose window halo reaches ``off`` cells further (see
+    :func:`write_geometry`).
 
 Multi-output temporal blocking: with ``nsteps=k`` each sweep's outputs
 rotate into their ``rotations[out]`` partner windows (the in-kernel
@@ -87,7 +89,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry as _telemetry
-from ..ir.reductions import normalize_reductions as _normalize_reductions
+from ..ir.reductions import (check_operand_shapes as _check_operand_shapes,
+                             normalize_reductions as _normalize_reductions)
 
 # Per-grid-step block of a reduction-partials output: the smallest f32
 # tile Mosaic stores (one value, broadcast; ``run`` reads [..., 0, 0]).
@@ -631,14 +634,16 @@ def write_geometry(
     ``@all(qx) = ...`` left-hand side — every in-domain cell is written;
     ring 0). ``inn``: it spans a symmetric window interior (``@inn(T2) =
     ...`` — a ``w``-cell boundary ring keeps its previous values).
-    Staggered axes must be ``all``: an interior-style write on a
-    face-centered axis would leave the faces straddling block boundaries
-    written by no block.
 
     ``ring`` pins the accepted ``inn`` depth (the legacy declared-radius
     contract); ``None`` accepts any symmetric margin (the inferred-
     footprint engine, where the ring is whatever the kernel's own slicing
-    produced).
+    produced). An ``inn`` write on a staggered axis (ParallelStencil's
+    ``@inn(Vx)`` on a face field) needs a window that reaches ``off + w``
+    cells past each block, which the inferred halo provides
+    (``ir.trace_stencil``) and the legacy symmetric ``radius`` halo does
+    not: with a pinned ring such a write would leave the faces straddling
+    block boundaries written by no block, and is refused.
     """
     modes, rings = [], []
     for a, (u, w, o) in enumerate(zip(update_shape, window_shape, off)):
@@ -648,7 +653,7 @@ def write_geometry(
             continue
         margin = w - u
         if margin > 0 and margin % 2 == 0 and (ring is None or margin == 2 * ring):
-            if o > 0:
+            if o > 0 and ring is not None:
                 raise ValueError(
                     f"output {name!r} is staggered along axis {a} (offset "
                     f"{o}) but its update covers only the interior there; "
@@ -845,7 +850,8 @@ def build_stencil_call(
     ``march_axis`` builds exactly the launch it names. Each build counts
     ``stencil.builds{fn, path}`` (``path``: ``streamed``, ``refetched``
     — axis 0 tiled by overlapping windows — or ``whole``, axis 0 in one
-    block).
+    block) and ``stencil.cropped_outputs{fn}``, its outputs stored at the
+    base extent and cropped to their staggered shapes after the launch.
 
     Reductions (``reductions={name: ir.Reduction | "kind(field[, other])"}``):
     named convergence/conservation checks (``max_abs``, ``max_abs_diff``,
@@ -859,9 +865,12 @@ def build_stencil_call(
     the partials: no operand crosses HBM a second time. ``run`` then
     returns ``(outputs, reductions)``. With ``nsteps=k`` only the final
     sweep reduces (the k-step value — what a sequential checker sees).
-    Operands must be collocated fields; periodic BCs are incompatible
-    (their wrap scatter happens after the launch, so an in-launch fold
-    would see pre-wrap face values).
+    A staggered operand reduces over its own extent: its per-tile mask
+    bounds the block's cells by the operand's shape, so the padding a
+    staggered output is stored with (and cropped from) never enters the
+    fold; the operands of one reduction share one shape. Periodic BCs
+    are incompatible (their wrap scatter happens after the launch, so an
+    in-launch fold would see pre-wrap face values).
 
     ``name`` (the ``@parallel`` function's name) names the launch: the
     custom call's instruction and its ``kernel_metadata`` attribute
@@ -896,13 +905,7 @@ def build_stencil_call(
     reductions = _normalize_reductions(reductions, field_names)
     red_names = tuple(reductions)
     for rn, r in reductions.items():
-        for op in r.operands:
-            if any(b - s for b, s in zip(shape, shapes[op])):
-                raise ValueError(
-                    f"reduction {rn!r} = {r.describe()} reads staggered "
-                    f"field {op!r} (shape {shapes[op]} vs base {shape}); "
-                    "reduction operands must be collocated"
-                )
+        _check_operand_shapes(rn, r, shapes)
     bc = dict(bc or {})
     inkernel_bc = {o: c for o, c in bc.items() if c.kind != "periodic"}
     post_bc = {o: c for o, c in bc.items() if c.kind == "periodic"}
@@ -1019,19 +1022,26 @@ def build_stencil_call(
             # the all-parallel launch (identical results, refetched halos).
             march, march_fallback = None, True
             grid, block = _derive(None)
-    for o, c in inkernel_bc.items():
-        if c.kind == "neumann0":
-            for a in c.resolved_axes(nd):
-                if block[a] < 2 * c.depth + offsets[o][a]:
-                    raise ValueError(
-                        f"fused neumann0 depth {c.depth} on axis {a} needs "
-                        f"block extent >= {2 * c.depth + offsets[o][a]}, got "
-                        f"{block[a]} (pass a larger tile)"
-                    )
     # Axes the block spans whole carry no halo: the update sees the whole
     # domain along them and every sweep keeps their full extent.
     ehalo = effective_halo(shape, block, sweep_halo)
     whalo = tuple((nsteps * lo, nsteps * hi) for lo, hi in ehalo)
+    fext = tuple(min(b + lo + hi, s)
+                 for s, b, (lo, hi) in zip(shape, block, whalo))
+    for o, c in inkernel_bc.items():
+        if c.kind == "neumann0":
+            for a in c.resolved_axes(nd):
+                # the frame-local shift copies a face from the cells next
+                # to it: a one-sweep all-parallel frame (block plus window
+                # halo) must hold them, a streamed or multi-sweep block too
+                need = 2 * c.depth + offsets[o][a]
+                have = fext[a] if a != march and nsteps == 1 else block[a]
+                if have < need:
+                    raise ValueError(
+                        f"fused neumann0 depth {c.depth} on axis {a} needs "
+                        f"a block frame >= {need}, got {have} (pass a "
+                        "larger tile)"
+                    )
     # Tiled axes whose windows overlap their neighbours' ("realigned"):
     # the window offset is clamped into the array at the domain ends, so
     # the frame's origin moves with it and each output block is cut from
@@ -1041,8 +1051,6 @@ def build_stencil_call(
     # edge) never reach the block.
     realign = tuple(a != march and whalo[a][0] + whalo[a][1] > 0
                     for a in range(nd))
-    fext = tuple(min(b + lo + hi, s)
-                 for s, b, (lo, hi) in zip(shape, block, whalo))
     win = {n: tuple(e - o for e, o in zip(fext, offsets[n]))
            for n in field_names}
     fetch = {n: tuple(block[a] if a == march else w
@@ -1220,28 +1228,38 @@ def build_stencil_call(
         # Fused reduction epilogue: fold each named check over the frame
         # cells of this block — output operands are the blended values
         # still live in VMEM, input operands the window (the value the
-        # boundary copy reads) — masked to the block's in-domain cells
-        # (each domain cell lies in exactly one block, so the per-tile
-        # partials tile the whole-array reduction without overlap).
-        if red_names:
-            dom = None
-            for a in range(nd):
-                g = origin[a] + jax.lax.broadcasted_iota(jnp.int32, fext, a)
-                b0 = pids[a] * block[a]
-                ma = (g >= b0) & (g < b0 + block[a]) & (g < shape[a])
-                dom = ma if dom is None else (dom & ma)
-            for rn, rref in zip(red_names, red_refs):
-                r = reductions[rn]
-                # operands lift to the accumulation dtype BEFORE the
-                # elementwise map: |T2 - T| and T*T happen at >= f32
-                # even when the blended storage values are bf16
-                mapped = r.map_element(*[
-                    blendeds.get(op, windows[op]).astype(acc_dtype)
-                    for op in r.operands])
-                # one (8, 128) tile per grid step: the smallest block
-                # Mosaic stores; run() reads element [0, 0]
-                rref[...] = jnp.full(rref.shape, r.fold(mapped, dom),
-                                     acc_dtype)
+        # boundary copy reads) — masked to the block's cells inside the
+        # operand's own extent (each of its cells lies in exactly one
+        # block, so the per-tile partials tile the whole-array reduction
+        # without overlap; a staggered operand's frame is its window,
+        # shorter than the base frame, and no pad enters).
+        doms = {}
+
+        def domain(frame, extent):
+            if (frame, extent) not in doms:
+                dom = None
+                for a in range(nd):
+                    g = origin[a] + jax.lax.broadcasted_iota(jnp.int32,
+                                                             frame, a)
+                    b0 = pids[a] * block[a]
+                    ma = (g >= b0) & (g < b0 + block[a]) & (g < extent[a])
+                    dom = ma if dom is None else (dom & ma)
+                doms[frame, extent] = dom
+            return doms[frame, extent]
+
+        for rn, rref in zip(red_names, red_refs):
+            r = reductions[rn]
+            # operands lift to the accumulation dtype BEFORE the
+            # elementwise map: |T2 - T| and T*T happen at >= f32
+            # even when the blended storage values are bf16
+            mapped = r.map_element(*[
+                blendeds.get(op, windows[op]).astype(acc_dtype)
+                for op in r.operands])
+            dom = domain(mapped.shape, shapes[r.operands[0]])
+            # one (8, 128) tile per grid step: the smallest block
+            # Mosaic stores; run() reads element [0, 0]
+            rref[...] = jnp.full(rref.shape, r.fold(mapped, dom),
+                                 acc_dtype)
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in scalar_names]
     in_specs += [window_spec(fetch[n], in_index_map) for n in field_names]
@@ -1329,10 +1347,13 @@ def build_stencil_call(
                 for rn, p in zip(red_names, partials)}
         return outs, reds
 
-    # once per built launch (never per call): which geometry axis 0 got
+    # once per built launch (never per call): which geometry axis 0 got,
+    # and how many outputs are stored at the base extent and cropped
     _telemetry.count("stencil.builds", fn=name,
                      path="streamed" if march is not None
                      else "whole" if grid[0] == 1 else "refetched")
+    _telemetry.count("stencil.cropped_outputs",
+                     sum(shapes[n] != shape for n in out_names), fn=name)
     run.grid = grid
     run.block = block
     run.nsteps = nsteps

@@ -170,18 +170,58 @@ def test_inconsistent_field_shape_raises(rng):
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_staggered_interior_write_raises(backend, rng):
-    """An `inn`-style write on a staggered axis would leave block-boundary
-    faces unwritten — rejected with a pointed message on BOTH backends
-    (a kernel that traces on jnp must trace on pallas and vice versa)."""
+    """On the legacy declared-radius geometry (an update the footprint
+    tracer cannot read) an `inn`-style write on a staggered axis would
+    leave block-boundary faces unwritten — rejected with a pointed
+    message on BOTH backends (a kernel that traces on jnp must trace on
+    pallas and vice versa). The inferred footprint reaches past the
+    stagger and takes such a write (next test)."""
     ps = init_parallel_stencil(backend=backend, ndims=2)
 
-    @ps.parallel(outputs=("q2",))
+    @ps.parallel(outputs=("q2",), radius=1)
     def kern(q2, q, T):
-        return {"q2": fd.inn(q)}
+        return {"q2": jnp.tanh(fd.inn(q))}
 
     q = _arr(rng, (SHAPE[0] - 1, SHAPE[1]))
     with pytest.raises(ValueError, match="staggered along axis 0"):
         kern(q2=q, q=q, T=_arr(rng))
+
+
+@pytest.mark.parametrize("tile", [None, 1, 2, 3])
+def test_staggered_interior_write_with_free_slip(tile):
+    """ParallelStencil's ``@inn(Vy) = @inn(Vy) + @all(dVy)`` on face fields
+    staggered along the tiled axis 0, with zero-gradient walls fused on two
+    axes each (the Stokes velocity update): the inferred window reaches
+    ``offset + ring`` past each block, so blocks of any depth along axis 0,
+    one plane too, write exactly what the jnp backend writes."""
+    from repro.core import fd3d
+    from repro.ir import BoundaryCondition
+
+    n, rng = 11, np.random.RandomState(5)
+
+    def arr(*shape):
+        return jnp.asarray(rng.rand(*shape), jnp.float32)
+
+    fields = dict(Vx=arr(n + 1, n, n), Vy=arr(n, n + 1, n),
+                  dVx=arr(n - 1, n - 2, n - 2), dVy=arr(n - 2, n - 1, n - 2))
+    walls = {"Vx": BoundaryCondition("neumann0", axes=(1, 2)),
+             "Vy": BoundaryCondition("neumann0", axes=(0, 2))}
+    outs = {}
+    for backend, t in (("jnp", None), ("pallas", tile)):
+        ps = init_parallel_stencil(backend=backend, ndims=3)
+
+        @ps.parallel(outputs=("Vx", "Vy"), bc=walls,
+                     tile=None if t is None else (t, n + 1, n))
+        def velocities(Vx, Vy, dVx, dVy):
+            return {"Vx": fd3d.inn(Vx) + dVx, "Vy": fd3d.inn(Vy) + dVy}
+
+        outs[backend] = velocities(**fields)
+        if t is not None:
+            (info,) = velocities.launch_info.values()
+            assert info["block"][0] == t
+    for o in ("Vx", "Vy"):
+        np.testing.assert_array_equal(np.asarray(outs["pallas"][o]),
+                                      np.asarray(outs["jnp"][o]))
 
 
 def test_overlapped_step_staggered_output_rejected(rng):

@@ -185,3 +185,29 @@ def test_cli_main_smoke(capsys):
     assert "GP:" in capsys.readouterr().out
     gp.main(["--n", "12", "--nt", "2", "--bc", "dirichlet"])
     assert "GP:" in capsys.readouterr().out
+
+
+def test_stokes3d_example_runs_to_tolerance(capsys):
+    """The Stokes3D example as a user runs it: four launches per
+    pseudo-transient iteration through ``solve_until``, to a tolerance."""
+    from examples import stokes3d
+
+    stokes3d.main(["--n", "15", "--tol", "1e-4", "--max-iters", "300"])
+    out = capsys.readouterr().out
+    assert out.startswith("Stokes3D: ") and "[jnp]" in out
+    iters = int(out.split()[1])
+    assert 0 < iters < 300 and iters % 10 == 0
+
+
+def test_stokes3d_backend_parity():
+    """The four staggered launches on jnp and on interpreted Pallas."""
+    from examples import stokes3d
+
+    res = {b: stokes3d.solve(stokes3d.StokesConfig(
+        n=15, tol=0.0, max_iters=20, backend=b)) for b in ("jnp", "pallas")}
+    for k in ("Vx", "Vy", "Vz", "Pt", "txy", "Rz"):
+        a, b = (np.asarray(res[x].fields[k]) for x in ("jnp", "pallas"))
+        np.testing.assert_allclose(b, a, rtol=0,
+                                   atol=1e-5 * np.max(np.abs(a)), err_msg=k)
+    assert abs(float(res["jnp"].err) - float(res["pallas"].err)) \
+        <= 1e-4 * float(res["jnp"].err)

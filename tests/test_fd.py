@@ -67,3 +67,32 @@ def test_operators_are_linear(rng):
     for op in (fd3d.d2_xi, fd3d.d_ya, fd3d.av, fd3d.inn):
         np.testing.assert_allclose(op(2 * A + 3 * B), 2 * op(A) + 3 * op(B),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("axes,name", [((0, 1), "xy"), ((0, 2), "xz"),
+                                       ((1, 2), "yz")])
+@pytest.mark.parametrize("kind", ["a", "i"])
+def test_two_axis_averages_match_parallelstencil(axes, name, kind, rng):
+    """``av_xyi``-style averages are ParallelStencil's FiniteDifferences3D
+    macros: ``(A[i,j,k] + A[i+1,j,k] + A[i,j+1,k] + A[i+1,j+1,k]) * 0.25``
+    over the two named axes, the third axis whole (``a``) or its interior
+    (``i``, index + 1), element by element."""
+    A = np.asarray(rng.rand(6, 7, 8), np.float32)
+    got = np.asarray(getattr(fd3d, f"av_{name}{kind}")(jnp.asarray(A)))
+    (c,) = set(range(3)) - set(axes)
+    shape = [s - 1 if a in axes else (s if kind == "a" else s - 2)
+             for a, s in enumerate(A.shape)]
+    assert got.shape == tuple(shape)
+    want = np.empty(shape, np.float32)
+    for idx in np.ndindex(*shape):
+        base = list(idx)
+        base[c] += kind == "i"
+
+        def at(da, db):
+            p = list(base)
+            p[axes[0]] += da
+            p[axes[1]] += db
+            return A[tuple(p)]
+
+        want[idx] = (at(0, 0) + at(1, 0) + at(0, 1) + at(1, 1)) * 0.25
+    np.testing.assert_array_equal(got, want)

@@ -219,11 +219,21 @@ def test_trace_error_surface():
 
 
 def test_staggered_interior_write_rejected_at_trace():
-    def bad(f, s):
+    """The trace takes an `inn` write on a staggered axis (ParallelStencil's
+    ``@inn(Vx)`` on a face field) by widening the window's high halo to
+    offset + ring, which covers every block's faces; the pinned ring of
+    the legacy declared-radius geometry, whose halo cannot, rejects it."""
+    from repro.kernels.stencil import write_geometry
+
+    def inner(f, s):
         return {"q2": f["q"][1:-1, 1:-1]}
 
+    ir = trace_stencil(inner, {"q2": (7, 8), "q": (7, 8), "T": (8, 8)},
+                       ("q2",))
+    assert ir.write_modes["q2"] == ("inn", "inn")
+    assert ir.halo[0] == (0, 2)
     with pytest.raises(ValueError, match="staggered along axis 0"):
-        trace_stencil(bad, {"q2": (7, 8), "q": (7, 8), "T": (8, 8)}, ("q2",))
+        write_geometry((5, 6), (7, 8), (1, 0), "q2", ring=1)
 
 
 def test_staggered_output_halo_covers_block_seams(rng):

@@ -227,11 +227,12 @@ def test_coupled_per_output_reductions(rng):
 
 
 def test_staggered_operand_rejected(rng):
-    # reducing a face-centered (staggered) field is a pointed error
+    # each operand reduces over its own extent, so a reduction over two
+    # operands of different staggerings is a pointed error
     n = 16
     ps = init_parallel_stencil(backend="jnp", ndims=2)
 
-    @ps.parallel(outputs=("qx",), reductions={"q": "max_abs(qx)"})
+    @ps.parallel(outputs=("qx",), reductions={"q": "max_abs_diff(qx, Pe)"})
     def fluxes(qx, Pe):
         return {"qx": -fd2d.d_xa(Pe)}
 
@@ -239,6 +240,39 @@ def test_staggered_operand_rejected(rng):
     qx = jnp.zeros((n - 1, n), jnp.float32)
     with pytest.raises(ValueError, match="staggered"):
         fluxes(qx=qx, Pe=Pe)
+
+
+STAGGERED_REDS = {"s": "sum(Rx)", "m2": "sum_sq(Rx)", "mx": "max_abs(Rx)",
+                  "p": "sum(Pt)"}
+
+
+@pytest.mark.parametrize("backend,tile", [
+    ("jnp", None), ("pallas", None), ("pallas", (4, 15, 15))])
+def test_staggered_output_reduces_over_own_extent(backend, tile):
+    """A reduction over a staggered output (the Stokes residual's
+    (n-1, n-2, n-2) beside the (n+1, n, n) face field) equals the plain
+    reduction over the output's own extent: the base-extent pad it is
+    stored with never enters, on one block or on tiled blocks."""
+    n = 15
+    rng = np.random.RandomState(3)
+    ps = init_parallel_stencil(backend=backend, ndims=3)
+
+    @ps.parallel(outputs=("Rx", "Pt"), tile=tile, reductions=STAGGERED_REDS)
+    def resid(Rx, Pt, Vx, tau, dx):
+        return {"Rx": fd3d.d_xi(tau) / dx + fd3d.av_xi(Pt),
+                "Pt": Pt - 0.5 * fd3d.d_xa(Vx)}
+
+    def arr(*shape):
+        return jnp.asarray(rng.rand(*shape) + 1.0, jnp.float32)
+
+    Vx, tau, Pt = arr(n + 1, n, n), arr(n, n, n), arr(n, n, n)
+    outs, reds = resid(Rx=jnp.full((n - 1, n - 2, n - 2), 1e3, jnp.float32),
+                       Pt=Pt, Vx=Vx, tau=tau, dx=0.5)
+    Rx, Pt2 = outs["Rx"], outs["Pt"]
+    assert Rx.shape == (n - 1, n - 2, n - 2) and Pt2.shape == (n, n, n)
+    want = {"s": jnp.sum(Rx), "m2": jnp.sum(Rx ** 2),
+            "mx": jnp.max(jnp.abs(Rx)), "p": jnp.sum(Pt2)}
+    assert_reds_close(reds, want, rtol=1e-5)
 
 
 # ------------------------------------------------------------ IR accounting
@@ -368,6 +402,73 @@ def test_solve_until_zero_host_transfers():
     assert not (all_names & forbidden)
 
 
+def test_single_kernel_solver_traces_as_before(rng):
+    """One kernel, or a sequence holding only it, traces to one ``while``
+    whose body holds ``check_every`` launches of that kernel, as before
+    sequences of launches were taken."""
+    T, Ci, sc = setup3d(rng, shape=(10, 10, 10))
+    kern = diffusion_kernel("pallas", {"err": "max_abs_diff(T2, T)"})
+    args = (dict(T2=T, T=T, Ci=Ci), 1e-5, 100)
+    jaxpr = jax.make_jaxpr(iterate.make_solver(kern, sc, check_every=3))(
+        *args)
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    calls = [e.params["name"] for e in loop.params["body_jaxpr"].jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert calls == ["kern"] * 3
+    seq = jax.make_jaxpr(iterate.make_solver((kern,), sc, check_every=3))(
+        *args)
+    assert str(seq) == str(jaxpr)
+
+
+def test_launch_sequence_errors(rng):
+    """A sequence of launches: reductions are merged by name (a name
+    declared twice is refused), and at least one launch must declare
+    one."""
+    T, Ci, sc = setup3d(rng, shape=(8, 8, 8))
+    a = diffusion_kernel("jnp", {"err": "max_abs(T2)"})
+    b = diffusion_kernel("jnp", {"err": "sum(T2)"})
+    with pytest.raises(ValueError, match="declared by two launches"):
+        iterate.make_solver((a, b), sc)
+    with pytest.raises(ValueError, match="fused reductions"):
+        iterate.make_solver((a.with_reductions(None),
+                             b.with_reductions(None)), sc)
+    with pytest.raises(ValueError, match="at least one kernel"):
+        iterate.make_solver((), sc)
+
+
+def test_launch_sequence_rebinds_outputs(rng):
+    """In a sequence, an output without a rotation rebinds its own name
+    for the launches after it; a rotated one swaps with its partner. Two
+    half-step launches then equal the host loop that chains them."""
+    T, Ci, sc = setup3d(rng, shape=(10, 10, 10))
+    ps = init_parallel_stencil(backend="jnp", ndims=3)
+
+    @ps.parallel(outputs=("U",))
+    def half(U, T, dt):
+        return {"U": fd3d.inn(T) + 0.5 * dt * fd3d.d2_xi(T)}
+
+    @ps.parallel(outputs=("T2",), rotations={"T2": "T"},
+                 reductions={"err": "max_abs_diff(T2, T)"})
+    def rest(T2, T, U, dt):
+        return {"T2": fd3d.inn(U) + 0.5 * dt * fd3d.d2_xi(U)}
+
+    res = iterate.solve_until((half, rest), dict(U=T, T2=T, T=T),
+                              dict(dt=0.1), tol=0.0, max_iters=6,
+                              check_every=3)
+    cur = dict(U=T, T2=T, T=T)
+    for _ in range(6):
+        cur["U"] = half(U=cur["U"], T=cur["T"], dt=0.1)
+        out, _ = rest(T2=cur["T2"], T=cur["T"], U=cur["U"], dt=0.1)
+        cur["T2"], cur["T"] = cur["T"], out
+    assert int(res.iters) == 6
+    for k in ("U", "T", "T2"):
+        np.testing.assert_allclose(np.asarray(res.fields[k]),
+                                   np.asarray(cur[k]), rtol=0, atol=1e-6)
+    np.testing.assert_allclose(
+        float(res.err), float(jnp.max(jnp.abs(cur["T"] - cur["T2"]))),
+        rtol=1e-6)
+
+
 def test_solve_until_errors(rng):
     T, Ci, sc = setup3d(rng, shape=(8, 8, 8))
     plain = diffusion_kernel("jnp", reductions=None)
@@ -387,16 +488,21 @@ def test_solve_until_errors(rng):
     with pytest.raises(ValueError, match="check_every"):
         iterate.solve_until(kern, dict(T2=T, T=T, Ci=Ci), sc, tol=1e-5,
                             max_iters=10, error="err", check_every=0)
-    # missing rotations
+    # an output without a rotation is not an error: it is written fresh
+    # under its own name each iteration, for a lone kernel as in a sequence
     ps = init_parallel_stencil(backend="jnp", ndims=3)
 
     @ps.parallel(outputs=("T2",), reductions={"err": "max_abs(T2)"})
     def norot(T2, T, dt):
         return {"T2": fd3d.inn(T) * dt}
 
-    with pytest.raises(ValueError, match="rotations"):
-        iterate.solve_until(norot, dict(T2=T, T=T), dict(dt=0.5), tol=1e-5,
-                            max_iters=10)
+    res = iterate.solve_until(norot, dict(T2=T, T=T), dict(dt=0.5), tol=1e-5,
+                              max_iters=10)
+    once, _ = norot(T2=T, T=T, dt=0.5)
+    assert int(res.iters) == 10
+    np.testing.assert_array_equal(np.asarray(res.fields["T2"]),
+                                  np.asarray(once))
+    np.testing.assert_array_equal(np.asarray(res.fields["T"]), np.asarray(T))
 
 
 # ------------------------------------------------------------- example wiring

@@ -10,6 +10,7 @@ The topology is described inside module fixtures only: the TPU runtime
 may be loaded by one process at a time, so describing it while a module
 is imported would break the other test workers.
 """
+import math
 import os
 import re
 
@@ -232,3 +233,83 @@ def test_launch_carries_parallel_name(one_chip):
     name, meta = calls[0]
     assert re.sub(r"\.\d+$", "", name) == "step"
     assert meta.strip() == '"parallel":"step"'
+
+
+STOKES_N = 383
+
+
+def _stokes_launch(name):
+    """One launch of the Stokes3D iteration at 383^3 cells, as the
+    benchmark's cell builds it: the kernel and a function of its fields."""
+    import inspect
+
+    from examples import stokes3d as st
+
+    cfg = st.StokesConfig(n=STOKES_N, backend="pallas", interpret=False)
+    kern = {k.__name__: k for k in st.make_kernels(cfg)}[name]
+    shapes = st.shapes(STOKES_N)
+    params = list(inspect.signature(kern.fn).parameters)
+    fields = [p for p in params if p in shapes]
+    scalars = {p: 1.0 for p in params if p not in shapes}
+
+    def f(*arrays, kernel=kern):
+        return kernel(**dict(zip(fields, arrays)), **scalars)
+
+    return kern, f, [shapes[p] for p in fields]
+
+
+def _with_operand_shapes(compiled):
+    """The compiled module's text with each operand's shape printed, as a
+    device trace names an operation."""
+    from jax._src.lib import xla_client
+
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return "\n".join(m.to_string(opts) for m in
+                     compiled.runtime_executable().hlo_modules())
+
+
+@pytest.mark.parametrize("name", ["compute_P", "compute_tau", "compute_dV",
+                                  "compute_V"])
+def test_stokes_launch_compiles_for_v5e_and_counts_its_bytes(one_chip, name):
+    """Each launch of the Stokes3D cell compiles at 383^3 for the
+    described v5e, on the refetching all-parallel path (a field staggered
+    on axis 0 keeps it off the streamed one), and the benchmark's byte
+    count for it (``bench/references/stokes3d.py``) is what the device
+    trace's reducer (``bench/yardstick/trace.py``) reads from its HLO: the
+    plain launch exactly, the checked one plus its reductions' partials,
+    one (8, 128) f32 tile per block."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(rel, as_name):
+        spec = importlib.util.spec_from_file_location(
+            as_name, os.path.join(root, "bench", *rel.split("/")))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    ref = load("references/stokes3d.py", "bench_reference_stokes3d")
+    trace = load("yardstick/trace.py", "bench_yardstick_trace")
+    with open(os.path.join(root, "bench", "configs",
+                           "stokes3d-383.json")) as f:
+        cfg = json.load(f)
+    checked, fn, shapes = _stokes_launch(name)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    counted = []
+    for kern in (checked.with_reductions(None), checked):
+        compiled = jax.jit(lambda *a: fn(*a, kernel=kern)).lower(
+            *args).compile()
+        (info,) = kern.launch_info.values()
+        assert info["march_axis"] is None and info["grid"][0] > 1
+        lines = [ln for ln in _with_operand_shapes(compiled).splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert len(lines) == 1
+        counted.append(trace.launch_bytes(lines[0], {}))
+    want = ref.launch_bytes(cfg)[name]
+    assert counted[0] == want
+    partials = len(checked.reductions) * math.prod(info["grid"]) * 8 * 128 * 4
+    assert counted[1] == want + partials
