@@ -679,7 +679,7 @@ def _valid_mask(origin, frame_shape, field_shape, rings, modes):
 
     ``inn`` axes accept the field's global interior at that axis's ring
     depth; ``all`` axes accept every in-domain cell (cells beyond a
-    staggered field's extent stay masked and are cropped by the caller).
+    staggered field's extent stay masked and are never stored).
     """
     m = None
     for a in range(len(frame_shape)):
@@ -850,8 +850,11 @@ def build_stencil_call(
     ``march_axis`` builds exactly the launch it names. Each build counts
     ``stencil.builds{fn, path}`` (``path``: ``streamed``, ``refetched``
     — axis 0 tiled by overlapping windows — or ``whole``, axis 0 in one
-    block) and ``stencil.cropped_outputs{fn}``, its outputs stored at the
-    base extent and cropped to their staggered shapes after the launch.
+    block) and ``stencil.own_extent_outputs{fn}``, its outputs staggered
+    against the base extent. Each output is stored at its own extent: its
+    block is the launch's on a tiled or marched axis and its own extent on
+    an axis the block spans whole, its block index is clamped to its last
+    block, and a block wholly past its extent stores nothing.
 
     Reductions (``reductions={name: ir.Reduction | "kind(field[, other])"}``):
     named convergence/conservation checks (``max_abs``, ``max_abs_diff``,
@@ -866,11 +869,11 @@ def build_stencil_call(
     returns ``(outputs, reductions)``. With ``nsteps=k`` only the final
     sweep reduces (the k-step value — what a sequential checker sees).
     A staggered operand reduces over its own extent: its per-tile mask
-    bounds the block's cells by the operand's shape, so the padding a
-    staggered output is stored with (and cropped from) never enters the
-    fold; the operands of one reduction share one shape. Periodic BCs
-    are incompatible (their wrap scatter happens after the launch, so an
-    in-launch fold would see pre-wrap face values).
+    bounds the block's cells by the operand's shape, so no cell of the
+    base frame past it enters the fold; the operands of one reduction
+    share one shape. Periodic BCs are incompatible (their wrap scatter
+    happens after the launch, so an in-launch fold would see pre-wrap face
+    values).
 
     ``name`` (the ``@parallel`` function's name) names the launch: the
     custom call's instruction and its ``kernel_metadata`` attribute
@@ -1112,10 +1115,22 @@ def build_stencil_call(
     sweep_win = {n: tuple(block[a] + whalo[a][0] + whalo[a][1]
                           if a == march else w for a, w in enumerate(win[n]))
                  for n in field_names}
-    # the block's place in the (base-extent) frame where it is static
-    static_cut = tuple(
+    # Each output is stored at its own extent. Its block keeps the base
+    # block on a tiled or marched axis and takes the output's extent on an
+    # axis the block spans whole. A staggered field is shorter at the high
+    # end only, so its block i holds the base block i's cells cut at its
+    # extent; the blocks wholly past it (the last along an axis) map to
+    # the last block that holds cells and store nothing.
+    oblock = {o: tuple(shapes[o][a] if block[a] == shape[a] else block[a]
+                       for a in range(nd)) for o in out_names}
+    olast = {o: tuple(-(-shapes[o][a] // block[a]) - 1 for a in range(nd))
+             for o in out_names}
+    short = {o: tuple(a for a in range(nd) if olast[o][a] < grid[a] - 1)
+             for o in out_names}
+    # the block's place in the output's frame where it is static
+    static_cut = {o: tuple(
         slice(whalo[a][0], whalo[a][0] + block[a]) if a == march
-        else slice(0, block[a]) for a in range(nd))
+        else slice(0, oblock[o][a]) for a in range(nd)) for o in out_names}
 
     def blend(o, updates, windows, tgt, origin):
         """The output ``o``'s update placed on the frame of ``tgt``,
@@ -1215,16 +1230,28 @@ def build_stencil_call(
         blendeds = {}
         for o, oref, sref in zip(out_names, out_refs, stage_refs):
             blendeds[o] = blend(o, updates, windows, o, origin)
-            # outputs are stored at the base extent: a staggered frame
-            # pads up to it (the pad is cropped on the way out)
-            full = _embed(blendeds[o], fext, (0,) * nd)
-            if sref is None:
-                oref[...] = full[static_cut]
-            else:
-                sref[...] = full
+
+            def store(o=o, oref=oref, sref=sref):
+                # the frame is the output's own window; the block's cells
+                # past its extent are dropped by the (partial) block store
+                own = blendeds[o]
+                if sref is None:
+                    oref[...] = own[static_cut[o]]
+                    return
+                if own.shape == sref.shape:   # collocated: the whole stage
+                    sref[...] = own
+                else:
+                    sref[tuple(slice(0, e) for e in own.shape)] = own
                 oref[...] = sref[tuple(
                     pl.ds(pids[a] * block[a] - origin[a], block[a])
-                    if realign[a] else static_cut[a] for a in range(nd))]
+                    if realign[a] else static_cut[o][a] for a in range(nd))]
+
+            if short[o]:
+                pl.when(functools.reduce(
+                    jnp.logical_and, [pids[a] * block[a] < shapes[o][a]
+                                      for a in short[o]]))(store)
+            else:
+                store()
         # Fused reduction epilogue: fold each named check over the frame
         # cells of this block — output operands are the blended values
         # still live in VMEM, input operands the window (the value the
@@ -1263,12 +1290,18 @@ def build_stencil_call(
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in scalar_names]
     in_specs += [window_spec(fetch[n], in_index_map) for n in field_names]
-    # Outputs are stored at the base extent (blocks tile it exactly) and
-    # cropped back to their staggered extents on the way out. Reduction
-    # partials ride as one (8, 128) tile per grid tile through the same
-    # (lagged, on the streamed path) block index map.
-    out_specs = [pl.BlockSpec(block, out_index_map) for _ in out_names]
-    out_shape = [jax.ShapeDtypeStruct(shape, dtype) for _ in out_names]
+    # Outputs are stored at their own extents: a staggered output's block
+    # index is clamped to its last block on the axes where it is short.
+    # Reduction partials ride as one (8, 128) tile per grid tile through
+    # the same (lagged, on the streamed path) block index map.
+    def own_index_map(o):
+        def index_map(*pids):
+            return tuple(jnp.minimum(i, olast[o][a]) if a in short[o] else i
+                         for a, i in enumerate(out_index_map(*pids)))
+        return index_map
+
+    out_specs = [pl.BlockSpec(oblock[o], own_index_map(o)) for o in out_names]
+    out_shape = [jax.ShapeDtypeStruct(shapes[o], dtype) for o in out_names]
     out_specs += [pl.BlockSpec((1,) * nd + _PARTIAL_TILE,
                                lambda *p: tuple(out_index_map(*p)) + (0, 0))
                   for _ in red_names]
@@ -1329,11 +1362,7 @@ def build_stencil_call(
             outs = [outs]
         outs = list(outs)
         partials = [p[..., 0, 0] for p in outs[n_out:]]
-        outs = [
-            o[tuple(slice(0, s) for s in shapes[n])] if shapes[n] != shape else o
-            for n, o in zip(out_names, outs[:n_out])
-        ]
-        outs = dict(zip(out_names, outs))
+        outs = dict(zip(out_names, outs[:n_out]))
         # Periodic faces wrap across the whole domain — realized as a
         # face-slab scatter fused into the surrounding jit (touches
         # O(N^(d-1) * depth) cells; no extra whole-array HBM round-trip).
@@ -1348,11 +1377,11 @@ def build_stencil_call(
         return outs, reds
 
     # once per built launch (never per call): which geometry axis 0 got,
-    # and how many outputs are stored at the base extent and cropped
+    # and how many outputs are stored at an extent shorter than the base
     _telemetry.count("stencil.builds", fn=name,
                      path="streamed" if march is not None
                      else "whole" if grid[0] == 1 else "refetched")
-    _telemetry.count("stencil.cropped_outputs",
+    _telemetry.count("stencil.own_extent_outputs",
                      sum(shapes[n] != shape for n in out_names), fn=name)
     run.grid = grid
     run.block = block

@@ -224,6 +224,89 @@ def test_staggered_interior_write_with_free_slip(tile):
                                       np.asarray(outs["jnp"][o]))
 
 
+def _own_extent_kernel(ps, case, n, tile):
+    """A launch whose outputs are staggered against its base extent, in
+    the geometry ``case`` names, and its fields."""
+    from repro.core import fd3d
+    from repro.ir import BoundaryCondition
+
+    rng = np.random.RandomState(7)
+
+    def arr(*shape):
+        return jnp.asarray(rng.rand(*shape), jnp.float32)
+
+    if case == "streamed-minor":
+        # streamed along axis 0; F is staggered on the minor axis 1 only
+        @ps.parallel(outputs=("T2", "F"), march_axis=0, tile=tile)
+        def kern(T2, F, T):
+            return {"T2": fd3d.inn(T) + 0.1 * fd3d.d2_xi(T),
+                    "F": fd3d.d_ya(T) - 0.5 * fd3d.av_ya(T)}
+
+        return kern, dict(T2=arr(n, n, n), F=arr(n, n - 1, n), T=arr(n, n, n))
+    if case == "neumann0":
+        # compute_V: Vy is one plane short of the base on axis 0
+        walls = {"Vx": BoundaryCondition("neumann0", axes=(1, 2)),
+                 "Vy": BoundaryCondition("neumann0", axes=(0, 2))}
+
+        @ps.parallel(outputs=("Vx", "Vy"), bc=walls, tile=tile)
+        def kern(Vx, Vy, dVx, dVy):
+            return {"Vx": fd3d.inn(Vx) + dVx, "Vy": fd3d.inn(Vy) + dVy}
+
+        return kern, dict(Vx=arr(n + 1, n, n), Vy=arr(n, n + 1, n),
+                          dVx=arr(n - 1, n - 2, n - 2),
+                          dVy=arr(n - 2, n - 1, n - 2))
+    # compute_tau's shapes: the base is (n+1, n+1, n); P is one plane
+    # short of it on axes 0 and 1, txy two on axis 0
+    reds = {"ss": "sum_sq(txy)"} if case == "sum-sq" else None
+
+    @ps.parallel(outputs=("P", "txy"), tile=tile, reductions=reds)
+    def kern(P, txy, Vx, Vy):
+        return {"P": P - 0.5 * (fd3d.d_xa(Vx) + fd3d.d_ya(Vy)),
+                "txy": 0.5 * txy + (fd3d.d_yi(Vx) + fd3d.d_xi(Vy))}
+
+    return kern, dict(P=arr(n, n, n), txy=arr(n - 1, n - 1, n - 2),
+                      Vx=arr(n + 1, n, n), Vy=arr(n, n + 1, n))
+
+
+@pytest.mark.parametrize("case,tile", [
+    # 1-plane blocks: P's last block and txy's last two lie wholly past
+    # their extents (compute_tau's geometry)
+    ("one-plane", (1, 8, 7)),
+    # 4-plane blocks: the last block of each output is ragged
+    ("ragged", (4, 8, 7)),
+    # both leading axes tiled with clamped windows: the staged cut
+    ("realigned", (2, 4, 7)),
+    ("streamed-minor", (1, 7, 7)),
+    ("sum-sq", (1, 8, 7)),
+    ("neumann0", (2, 8, 7)),
+])
+def test_staggered_output_stored_at_own_extent(case, tile):
+    """A staggered output leaves the launch at its own extent, bit for bit
+    what the jnp backend writes, whichever of its blocks are ragged, wholly
+    past its extent, realigned or streamed; a fused ``sum_sq`` over it and
+    a fused ``neumann0`` on it see the same cells."""
+    n = 7
+    outs, kerns = {}, {}
+    for backend, t in (("jnp", None), ("pallas", tile)):
+        kern, fields = _own_extent_kernel(
+            init_parallel_stencil(backend=backend, ndims=3), case, n, t)
+        outs[backend], kerns[backend] = kern(**fields), kern
+    (info,) = kerns["pallas"].launch_info.values()
+    assert info["block"] == tile
+    assert info["march_axis"] == (0 if case == "streamed-minor" else None)
+    if case == "sum-sq":
+        (got, reds), (want, want_reds) = outs["pallas"], outs["jnp"]
+        np.testing.assert_allclose(
+            float(reds["ss"]), float(jnp.sum(got["txy"] ** 2)), rtol=1e-6)
+        np.testing.assert_allclose(float(reds["ss"]),
+                                   float(want_reds["ss"]), rtol=1e-6)
+    else:
+        got, want = outs["pallas"], outs["jnp"]
+    for o, v in want.items():
+        assert got[o].shape == fields[o].shape == v.shape
+        np.testing.assert_array_equal(np.asarray(got[o]), np.asarray(v))
+
+
 def test_overlapped_step_staggered_output_rejected(rng):
     """Outputs staggered along a decomposed axis are out of overlapped_
     step's contract (shared rank faces) — rejected before any collective."""

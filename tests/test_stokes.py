@@ -149,8 +149,8 @@ def test_pt_solve_is_one_while_of_four_launches_per_iteration():
 
 def test_solve_launches_and_crops_are_counted():
     """``solve.launches{fn}`` counts the launches of one iteration once per
-    solver build; ``stencil.cropped_outputs{fn}`` each launch's outputs
-    stored at the base extent and cropped, once per build."""
+    solver build; ``stencil.own_extent_outputs{fn}`` each launch's outputs
+    stored at an extent shorter than its base, once per build."""
     n = 7
     col = telemetry.configure(None)
     try:
@@ -165,12 +165,12 @@ def test_solve_launches_and_crops_are_counted():
         c = col.counters
         label = "compute_P+compute_tau+compute_dV+compute_V"
         assert c[("solve.launches", (("fn", label),))] == 4
-        crops = {k[1][0][1]: v for k, v in c.items()
-                 if k[0] == "stencil.cropped_outputs"}
+        own = {k[1][0][1]: v for k, v in c.items()
+                 if k[0] == "stencil.own_extent_outputs"}
         # compute_P and compute_dV build a plain and a checked variant;
         # compute_dV's base is the cell extent, so Rx.. and dVx.. are
-        # cropped, its cell-extent fields not
-        assert crops == {"compute_P": 4, "compute_tau": 6, "compute_dV": 12,
+        # shorter than it, its cell-extent fields not
+        assert own == {"compute_P": 4, "compute_tau": 6, "compute_dV": 12,
                          "compute_V": 3}
         builds = {k[1][0][1]: v for k, v in c.items()
                   if k[0] == "stencil.builds"}

@@ -118,9 +118,9 @@ def test_default_launch_streams_leading_axis(collector, case, kw, march,
     assert _builds(collector, path) == 1
     assert sum(v for k, v in collector.counters.items()
                if k[0] == "stencil.builds") == 1
-    # the build also counts its outputs stored at the base extent and
-    # cropped: only the output staggered on axis 0 is
-    assert collector.counters[("stencil.cropped_outputs", (("fn", "k"),))] \
+    # the build also counts its outputs stored at an extent shorter than
+    # the base: only the output staggered on axis 0 is
+    assert collector.counters[("stencil.own_extent_outputs", (("fn", "k"),))] \
         == (case == "staggered-axis0")
 
 

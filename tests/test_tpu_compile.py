@@ -274,13 +274,17 @@ def _with_operand_shapes(compiled):
 def test_stokes_launch_compiles_for_v5e_and_counts_its_bytes(one_chip, name):
     """Each launch of the Stokes3D cell compiles at 383^3 for the
     described v5e, on the refetching all-parallel path (a field staggered
-    on axis 0 keeps it off the streamed one), and the benchmark's byte
-    count for it (``bench/references/stokes3d.py``) is what the device
-    trace's reducer (``bench/yardstick/trace.py``) reads from its HLO: the
-    plain launch exactly, the checked one plus its reductions' partials,
-    one (8, 128) f32 tile per block."""
+    on axis 0 keeps it off the streamed one), and the device trace's
+    reducer (``bench/yardstick/trace.py``) reads from its HLO the
+    benchmark's byte count for it (``bench/references/stokes3d.py``) less
+    what that count holds past each result's own extent (it counts results
+    at the launch's base extent, where the launch stores them at their
+    own): the plain launch exactly, the checked one plus its reductions'
+    partials, one (8, 128) f32 tile per block."""
     import importlib.util
     import json
+
+    from examples import stokes3d as st
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -309,7 +313,63 @@ def test_stokes_launch_compiles_for_v5e_and_counts_its_bytes(one_chip, name):
                  if 'custom_call_target="tpu_custom_call"' in ln]
         assert len(lines) == 1
         counted.append(trace.launch_bytes(lines[0], {}))
-    want = ref.launch_bytes(cfg)[name]
+    base = math.prod(max(e) for e in zip(*shapes))
+    padding = sum(base - math.prod(st.shapes(STOKES_N)[o])
+                  for o in checked.outputs)
+    want = ref.launch_bytes(cfg)[name] - 4 * padding
     assert counted[0] == want
     partials = len(checked.reductions) * math.prod(info["grid"]) * 8 * 128 * 4
     assert counted[1] == want + partials
+
+
+def test_stokes_iteration_stores_results_at_own_extent(one_chip):
+    """The Stokes solver's 383^3 program (the ``while`` of four launches
+    per iteration, checked every 10) compiled for the described v5e: each
+    launch's field results have the fields' own shapes, and no ``slice``
+    or ``copy`` takes one as its operand (the launch stores each staggered
+    result at its own extent, nothing crops it after)."""
+    from examples import stokes3d as st
+    from repro.core import iterate
+
+    cfg = st.StokesConfig(n=STOKES_N, backend="pallas", interpret=False)
+    kernels = st.make_kernels(cfg)
+    shapes = st.shapes(STOKES_N)
+    solver = iterate.make_solver(kernels, st.scalars(cfg), check_every=10,
+                                 error=st.residual_error(STOKES_N))
+    state = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+             for k, s in shapes.items()}
+    text = _with_operand_shapes(
+        jax.jit(solver).lower(state, 1e-6, 100).compile())
+    ops = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w-]+)\((.*)", line)
+        if m:
+            ops[m.group(1)] = (m.group(3), m.group(2), m.group(4))
+    calls = {n: typ for n, (op, typ, rest) in ops.items()
+             if op == "custom-call" and "tpu_custom_call" in rest}
+    names = {re.sub(r"\.\d+$", "", n) for n in calls}
+    assert names == {"compute_P", "compute_tau", "compute_dV", "compute_V"}
+    field = {f"f32[{','.join(map(str, s))}]" for s in shapes.values()}
+    outputs = {k: {"f32[" + ",".join(map(str, shapes[o])) + "]"
+                   for o in kern.outputs}
+               for k, kern in ((k.__name__, k) for k in kernels)}
+    results = set()
+    for n, typ in calls.items():
+        got = {t.split("{")[0] for t in re.findall(r"f32\[[\d,]*\]\{[^}]*\}",
+                                                   typ)}
+        # the field results, one per output at its own shape; the rest
+        # are the reductions' per-block partials, rank 5
+        assert {t for t in got if t.count(",") == 2} \
+            == outputs[re.sub(r"\.\d+$", "", n)], (n, typ)
+        results.add(n)
+    for n, (op, typ, rest) in ops.items():
+        if op == "get-tuple-element":
+            src = re.search(r"%([^\s,)]+)", rest).group(1)
+            if src in calls and typ.split("{")[0] in field:
+                results.add(n)
+    for n, (op, typ, rest) in ops.items():
+        if op in ("slice", "copy"):
+            # the operand list ends at the first ")," (a layout's
+            # tiling, ``T(8,128)``, holds a ")" too)
+            operands = set(re.findall(r"%([\w.-]+)", rest.split("), ")[0]))
+            assert not operands & results, (n, op, rest)
