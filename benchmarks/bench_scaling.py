@@ -42,7 +42,7 @@ except ImportError:
 
 _SCALE_CHILD = r"""
 import json, os, numpy as np, jax, jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import init_parallel_stencil, fd3d as fd
 from repro.distributed import overlap

@@ -19,8 +19,8 @@ written, T and Ci read (the paper's counting for Fig. 1). Under temporal
 blocking the per-launch ideal traffic divides by k (teff.a_eff_blocked),
 so both the *classic* fraction (per-sweep traffic) and the *blocked*
 fraction (per-launch traffic) are reported. T_peak for the CPU rows is a
-measured STREAM-copy bandwidth; the TPU v5e roofline fraction is *derived*
-in the README §Roofline from the dry-run (no TPU here).
+measured STREAM-copy bandwidth. These are host-clock numbers: the record
+of speed on the TPU is PERF.md and the benchmark ledger.
 
 ``--nsteps k`` also records the comparison to ``BENCH_teff_n{N}_k{K}.json``
 so perf regressions of the fused path are visible in CI.
@@ -43,7 +43,7 @@ import numpy as np
 from repro.configs.diffusion3d import BENCH_128, BENCH_256, Diffusion3DConfig
 from repro.core import Grid, fd3d as fd, init_parallel_stencil, teff
 from repro.kernels import ops, ref
-from repro.launch import roofline as _roofline
+from repro.ir import cost as _roofline
 
 try:
     from ._meta import bench_meta   # imported as benchmarks.bench_teff

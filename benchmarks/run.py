@@ -6,40 +6,14 @@ Prints ``name,us_per_call,derived`` CSV lines per the harness contract:
   * Fig. 2  — T_eff of 3-D diffusion, fused kernel vs array broadcasting
   * §3      — solver-translation efficiency (diffusion + Gross-Pitaevskii)
   * §3      — weak scaling, sequential vs hidden-communication halo steps
-  * §Roofline — summary of the dry-run derived rooflines (reads
-               results/dryrun if present; see launch/dryrun.py)
 """
 from __future__ import annotations
 
 import argparse
-import glob
-import json
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-
-def roofline_summary(dryrun_dir: str = "results/dryrun"):
-    files = sorted(glob.glob(os.path.join(dryrun_dir, "*__single.json")))
-    if not files:
-        print("roofline_summary,0,no dry-run records (run repro.launch.dryrun)")
-        return []
-    rows = []
-    for f in files:
-        r = json.load(open(f))
-        if not r.get("runnable") or "roofline" not in r:
-            continue
-        ro = r["roofline"]
-        t_mem = r.get("t_memory_analytic", ro["t_memory"])
-        terms = {"compute": ro["t_compute"], "memory": t_mem,
-                 "collective": ro["t_collective"]}
-        dom = max(terms, key=terms.get)
-        bound = terms[dom]
-        rows.append({"arch": r["arch"], "shape": r["shape"], "dominant": dom,
-                     **{f"t_{k}": v for k, v in terms.items()}})
-        print(f"roofline_{r['arch']}_{r['shape']},{bound*1e6:.0f},dom={dom}")
-    return rows
 
 
 def main():
@@ -71,9 +45,6 @@ def main():
         print("# --- paper S3: weak scaling w/ hidden communication ---")
         from benchmarks import bench_scaling
         bench_scaling.main(["--quick"] if args.quick else [])
-
-    print("# --- roofline: dry-run derived ---")
-    roofline_summary()
 
 
 if __name__ == "__main__":

@@ -1,3 +1,2 @@
-"""repro — xPU stencil computations in JAX (ParallelStencil.jl reproduction)
-plus the multi-pod LM substrate it shares its distributed runtime with."""
+"""repro — xPU stencil computations in JAX (ParallelStencil.jl reproduction)."""
 __version__ = "0.1.0"

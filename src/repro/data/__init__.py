@@ -1,3 +1,4 @@
-from . import tokens, physics
-from .tokens import DataConfig, make_source
-__all__ = ["tokens", "physics", "DataConfig", "make_source"]
+"""Initial and boundary conditions for the PDE solvers (``physics``)."""
+from . import physics
+
+__all__ = ["physics"]

@@ -1,5 +1,5 @@
-"""Distributed runtime: halo exchange + overlap (paper C6), sharding rules,
-gradient compression, elasticity and fault handling."""
-from . import halo, overlap, sharding, compression, fault, elastic
+"""Distributed runtime: halo exchange and overlap (paper C6), int8 halo
+compression, elasticity and fault handling."""
+from . import halo, overlap, compression, fault, elastic
 
-__all__ = ["halo", "overlap", "sharding", "compression", "fault", "elastic"]
+__all__ = ["halo", "overlap", "compression", "fault", "elastic"]

@@ -238,8 +238,6 @@ def make_elastic_solver(kernel, scalars: Mapping[str, object], mesh: Mesh,
     and the check's reductions arrive pre-combined across ranks (ONE
     ``pmax``/``psum``), so the loop condition is rank-uniform and the
     whole chunk needs zero host round-trips."""
-    from ..compat import shard_map
-
     err_fn = _resolve_error(kernel, error)
     scalars = dict(scalars or {})
     plain = kernel.with_reductions(None)
@@ -296,7 +294,7 @@ def make_elastic_solver(kernel, scalars: Mapping[str, object], mesh: Mesh,
     def solver(stacked, tol, block):
         field_spec = {k: _field_specs(factors, axes, stacked[k].ndim)
                       for k in stacked}
-        f = shard_map(
+        f = jax.shard_map(
             local_chunk, mesh=mesh,
             in_specs=(field_spec, P(), P()),
             out_specs=(field_spec,

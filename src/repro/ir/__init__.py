@@ -17,7 +17,7 @@ to take on faith from a hand-declared ``radius`` and hand-counted
     ``core.boundary`` post-pass;
   * **cost models** (:mod:`.cost`) — exact flop/byte counts per output
     feeding ``core.teff``, the autotuner's pre-compile candidate pruning
-    and ``launch.roofline`` stencil positions.
+    and the roofline position of a launch (``cost.stencil_roofline``).
 """
 from .sym import SymArray, TraceError, field as sym_field
 from .trace import StencilIR, trace_stencil

@@ -1,233 +1,12 @@
-"""Public jit'd wrappers around the kernels.
+"""Public wrapper around the hand-written Fig. 1 kernel.
 
-Every op has (at least) three interchangeable implementations:
-
-  * ``impl="pallas"`` — the Pallas TPU kernel (interpret-mode on CPU);
-  * ``impl="chunked"`` — memory-bounded pure-jnp (lax.scan blocking). This
-    is what the model/dry-run path uses: it compiles on any backend and its
-    HLO has realistic (bounded) memory footprints at 32k–500k context;
-  * ``impl="ref"`` — the O(L^2)-memory oracle in ref.py (tests/tiny shapes).
-
-Tests sweep shapes/dtypes and assert all implementations agree.
+``impl="pallas"`` runs the Pallas kernel (``kernels/diffusion3d.py``;
+interpret mode on CPU); ``impl="ref"`` runs its jnp oracle in ref.py.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
-import jax
-import jax.numpy as jnp
-
 from . import ref as _ref
-from . import attention as _attn_kernel
-from . import conv1d as _conv_kernel
-from . import ssd as _ssd_kernel
 from . import diffusion3d as _diff_kernel
-
-NEG_INF = -1e30
-
-
-def _pick_divisor(n: int, target: int) -> int:
-    c = min(target, n)
-    while n % c:
-        c -= 1
-    return max(c, 1)
-
-
-# =====================================================================
-# attention
-# =====================================================================
-def attention(q, k, v, causal: bool = True, window: Optional[int] = None,
-              scale: Optional[float] = None, impl: str = "chunked",
-              q_chunk: int = 512, k_chunk: int = 1024):
-    """Self-attention with GQA; q (B,Hq,L,D), k/v (B,Hkv,L,D)."""
-    if impl == "ref":
-        return _ref.attention(q, k, v, causal=causal, scale=scale, window=window)
-    if impl == "pallas":
-        return _attn_kernel.flash_attention(q, k, v, causal=causal, window=window,
-                                            scale=scale)
-    return _chunked_attention(q, k, v, causal, window, scale, q_chunk, k_chunk)
-
-
-def _chunked_attention(q, k, v, causal, window, scale, q_chunk, k_chunk):
-    """Memory-efficient attention: scan over q blocks; online softmax over
-    k blocks; the per-q-block computation is rematerialized on backward
-    (jax.checkpoint), so residual memory is O(L*D), not O(L^2)."""
-    B, Hq, Lq, D = q.shape
-    Hkv, Lk = k.shape[1], k.shape[2]
-    R = Hq // Hkv
-    scale = (D ** -0.5) if scale is None else scale
-    Qc = _pick_divisor(Lq, q_chunk)
-    Kc = _pick_divisor(Lk, k_chunk)
-    nq, nk = Lq // Qc, Lk // Kc
-    pos_off = Lk - Lq  # align sequence ends (prefill continuation friendly)
-
-    qg = q.reshape(B, Hkv, R, Lq, D)
-    # (nq, B, G, R, Qc, D)
-    qs = jnp.moveaxis(qg.reshape(B, Hkv, R, nq, Qc, D), 3, 0)
-
-    def q_block(qi, qblk):
-        qf = qblk.astype(jnp.float32) * scale
-        qpos = pos_off + qi * Qc + jnp.arange(Qc)
-
-        def k_step(carry, ki):
-            m, l, acc = carry
-            kblk = jax.lax.dynamic_slice_in_dim(k, ki * Kc, Kc, axis=2)
-            vblk = jax.lax.dynamic_slice_in_dim(v, ki * Kc, Kc, axis=2)
-            s = jnp.einsum("bgrqd,bgkd->bgrqk", qf, kblk.astype(jnp.float32))
-            kpos = ki * Kc + jnp.arange(Kc)
-            mask = jnp.ones((Qc, Kc), bool)
-            if causal:
-                mask &= kpos[None, :] <= qpos[:, None]
-            if window is not None:
-                mask &= kpos[None, :] > qpos[:, None] - window
-            s = jnp.where(mask[None, None, None], s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            p = jnp.where(mask[None, None, None], p, 0.0)
-            l_new = l * alpha + jnp.sum(p, axis=-1)
-            acc_new = acc * alpha[..., None] + jnp.einsum(
-                "bgrqk,bgkd->bgrqd", p, vblk.astype(jnp.float32))
-            return (m_new, l_new, acc_new), None
-
-        m0 = jnp.full((B, Hkv, R, Qc), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, Hkv, R, Qc), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, R, Qc, D), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(k_step, (m0, l0, a0), jnp.arange(nk))
-        l = jnp.where(l > 0, l, 1.0)
-        return (acc / l[..., None]).astype(q.dtype)
-
-    blk = jax.checkpoint(q_block, static_argnums=())
-    _, outs = jax.lax.scan(lambda _, xs: (None, blk(xs[0], xs[1])),
-                           None, (jnp.arange(nq), qs))
-    out = jnp.moveaxis(outs, 0, 3)  # (B, G, R, nq, Qc, D)
-    return out.reshape(B, Hq, Lq, D)
-
-
-def decode_attention(q, k_cache, v_cache, pos: Optional[jax.Array] = None,
-                     window: Optional[int] = None, scale: Optional[float] = None,
-                     k_chunk: int = 2048):
-    """One-token decode: q (B,Hq,D) against cache (B,Hkv,S,D) -> (B,Hq,D).
-
-    One einsum over the full cache: with the cache's sequence axis sharded
-    (launch/steps.py), GSPMD computes per-shard partials + one psum — the
-    flash-decoding pattern. (A chunked lax.scan variant was measured WORSE
-    here: dynamic-slicing the sharded S axis makes GSPMD gather per chunk —
-    minicpm decode collective 10 ms -> 3.6 s. EXPERIMENTS.md §Perf, refuted.)
-    ``pos``: current token index (masks cache > pos, applies the window);
-    None attends to the whole cache.
-    """
-    B, Hq, D = q.shape
-    Hkv, S = k_cache.shape[1], k_cache.shape[2]
-    R = Hq // Hkv
-    scale = (D ** -0.5) if scale is None else scale
-    qg = q.reshape(B, Hkv, R, D).astype(jnp.float32) * scale
-    s = jnp.einsum("bgrd,bgkd->bgrk", qg, k_cache.astype(jnp.float32))
-    kpos = jnp.arange(S)
-    mask = jnp.ones((S,), bool)
-    if pos is not None:
-        mask &= kpos <= pos
-        if window is not None:
-            mask &= kpos > pos - window
-    elif window is not None:
-        mask &= kpos > (S - 1) - window
-    s = jnp.where(mask[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bgrk,bgkd->bgrd", p, v_cache.astype(jnp.float32))
-    return out.reshape(B, Hq, D).astype(q.dtype)
-
-
-# =====================================================================
-# Mamba2 SSD
-# =====================================================================
-def ssd(x, dt, A, Bm, Cm, D=None, h0=None, chunk: int = 64, impl: str = "chunked"):
-    """SSD scan; Bm/Cm given per state-group (B, L, G, N) and broadcast to
-    heads internally. Returns (y (B,L,H,P), h_final (B,H,P,N))."""
-    B, L, H, P = x.shape
-    G, N = Bm.shape[2], Bm.shape[3]
-    if impl == "ref":
-        return _ref.ssd_scan(x, dt, A, Bm, Cm, D=D, h0=h0)
-    rep = H // G
-    Bh = jnp.repeat(Bm, rep, axis=2) if rep > 1 else Bm.reshape(B, L, H, N)
-    Ch = jnp.repeat(Cm, rep, axis=2) if rep > 1 else Cm.reshape(B, L, H, N)
-    if impl == "pallas":
-        return _ssd_kernel.ssd_chunk_scan(x, dt, A, Bh, Ch, D=D, h0=h0, chunk=chunk)
-    return _ssd_chunked_jnp(x, dt, A, Bh, Ch, D, h0, chunk)
-
-
-def _ssd_chunked_jnp(x, dt, A, Bh, Ch, D, h0, chunk):
-    """Vectorized chunked SSD (same math as the Pallas kernel, differentiable)."""
-    B, L, H, P = x.shape
-    N = Bh.shape[-1]
-    cs = _pick_divisor(L, chunk)
-    nc = L // cs
-    f32 = jnp.float32
-    xr = x.reshape(B, nc, cs, H, P).astype(f32)
-    dtr = dt.reshape(B, nc, cs, H).astype(f32)
-    Br = Bh.reshape(B, nc, cs, H, N).astype(f32)
-    Cr = Ch.reshape(B, nc, cs, H, N).astype(f32)
-
-    la = dtr * A[None, None, None, :].astype(f32)
-    logcum = jnp.cumsum(la, axis=2)                     # (B,nc,cs,H)
-    s_last = jnp.exp(logcum[:, :, -1])                  # (B,nc,H)
-
-    # chunk-local quadratic part
-    cb = jnp.einsum("bnthd,bnuhd->bntuh", Cr, Br)
-    ldiff = logcum[:, :, :, None, :] - logcum[:, :, None, :, :]
-    tri = (jnp.arange(cs)[:, None] >= jnp.arange(cs)[None, :])
-    # mask BEFORE the exp: for u > t ldiff is positive and can overflow; a
-    # post-exp where() would then backprop inf * 0 = NaN.
-    decay = jnp.exp(jnp.where(tri[None, None, :, :, None], ldiff, -1e30))
-    w = cb * decay * dtr[:, :, None, :, :]
-    y_intra = jnp.einsum("bntuh,bnuhp->bnthp", w, xr)
-
-    # per-chunk state contribution and the inter-chunk recurrence
-    coeff = jnp.exp(logcum[:, :, -1:, :] - logcum) * dtr           # (B,nc,cs,H)
-    G_ = jnp.einsum("bnuh,bnuhp,bnuhs->bnhps", coeff, xr, Br)       # (B,nc,H,P,N)
-
-    h_init = (jnp.zeros((B, H, P, N), f32) if h0 is None else h0.astype(f32))
-
-    def chunk_step(h, inp):
-        sl, g = inp  # (B,H), (B,H,P,N)
-        h_next = h * sl[..., None, None] + g
-        return h_next, h  # emit state at chunk *start*
-
-    hs_final, h_starts = jax.lax.scan(
-        chunk_step, h_init,
-        (jnp.moveaxis(s_last, 1, 0), jnp.moveaxis(G_, 1, 0)))
-    h_starts = jnp.moveaxis(h_starts, 0, 1)                        # (B,nc,H,P,N)
-
-    y_inter = jnp.einsum("bnths,bnhps->bnthp", Cr * jnp.exp(logcum)[..., None], h_starts)
-    y = (y_intra + y_inter).reshape(B, L, H, P)
-    if D is not None:
-        y = y + x.astype(f32) * D[None, None, :, None].astype(f32)
-    return y.astype(x.dtype), hs_final
-
-
-def ssd_decode_step(h, x_t, dt_t, A, B_t, C_t, D=None):
-    """Single-token SSD recurrence. h (B,H,P,N) f32; x_t (B,H,P);
-    dt_t (B,H); B_t/C_t (B,H,N). Returns (y_t, h_new)."""
-    f32 = jnp.float32
-    dA = jnp.exp(dt_t.astype(f32) * A.astype(f32)[None, :])
-    h = h * dA[..., None, None] + (dt_t.astype(f32)[..., None] * x_t.astype(f32))[..., None] \
-        * B_t.astype(f32)[:, :, None, :]
-    y = jnp.einsum("bhpn,bhn->bhp", h, C_t.astype(f32))
-    if D is not None:
-        y = y + x_t.astype(f32) * D[None, :, None].astype(f32)
-    return y.astype(x_t.dtype), h
-
-
-# =====================================================================
-# causal depthwise conv1d
-# =====================================================================
-def conv1d_causal(x, w, b=None, silu: bool = False, impl: str = "chunked"):
-    if impl == "pallas":
-        return _conv_kernel.conv1d_causal(x, w, b, silu=silu)
-    out = _ref.conv1d_causal(x, w, b)
-    if silu:
-        out = out * jax.nn.sigmoid(out)
-    return out
 
 
 # =====================================================================

@@ -1,13 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
-
-Each function is the semantic ground truth used by tests (allclose sweeps
-over shapes/dtypes) and doubles as the paper's "array programming" baseline
-in benchmarks.
+"""Pure-jnp oracle of the Fig. 1 diffusion step: the ground truth of the
+hand-written kernel (``kernels/diffusion3d.py``) and of the generated
+launches in tests, and the paper's "array programming" baseline in
+benchmarks.
 """
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 
 # -- 3-D heat diffusion (paper Fig. 1) ---------------------------------------
@@ -21,101 +17,3 @@ def diffusion3d_step(T2, T, Ci, lam, dt, inv_dx, inv_dy, inv_dz):
     d2z = (T[1:-1, 1:-1, 2:] - 2 * T[1:-1, 1:-1, 1:-1] + T[1:-1, 1:-1, :-2]) * inv_dz**2
     upd = T[1:-1, 1:-1, 1:-1] + dt * (lam * Ci[1:-1, 1:-1, 1:-1] * (d2x + d2y + d2z))
     return T2.at[1:-1, 1:-1, 1:-1].set(upd.astype(T2.dtype))
-
-
-# -- generic 2nd-order laplacian step (used by property tests) ---------------
-def laplacian_step(U, coeff, dt, inv_spacing):
-    nd = U.ndim
-    inner = tuple(slice(1, -1) for _ in range(nd))
-    lap = jnp.zeros_like(U[inner])
-    for a in range(nd):
-        lo = tuple(slice(None, -2) if i == a else slice(1, -1) for i in range(nd))
-        hi = tuple(slice(2, None) if i == a else slice(1, -1) for i in range(nd))
-        lap = lap + (U[hi] - 2 * U[inner] + U[lo]) * inv_spacing[a] ** 2
-    return U.at[inner].set(U[inner] + dt * coeff * lap)
-
-
-# -- causal depthwise conv1d (Mamba2's stencil; kernels/conv1d.py) -----------
-def conv1d_causal(x, w, b=None):
-    """x: (B, L, C), w: (K, C) depthwise taps, causal (output t uses x[t-K+1..t]).
-
-    Matches the Mamba short-conv: left-pad with zeros.
-    """
-    B, L, C = x.shape
-    K = w.shape[0]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    out = jnp.zeros_like(x)
-    for k in range(K):
-        out = out + xp[:, k : k + L, :] * w[K - 1 - k][None, None, :]
-    if b is not None:
-        out = out + b[None, None, :]
-    return out
-
-
-# -- flash attention oracle (kernels/attention.py) ----------------------------
-def attention(q, k, v, causal=True, scale=None, window=None):
-    """q: (B, Hq, Lq, D), k/v: (B, Hkv, Lk, D); GQA by head broadcast.
-
-    window: sliding-window size (tokens attend to the last `window` keys),
-    None for full attention. Computed in f32.
-    """
-    B, Hq, Lq, D = q.shape
-    Hkv = k.shape[1]
-    rep = Hq // Hkv
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    scale = (D ** -0.5) if scale is None else scale
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
-    Lk = k.shape[2]
-    qpos = jnp.arange(Lq)[:, None] + (Lk - Lq)  # align ends (decode-friendly)
-    kpos = jnp.arange(Lk)[None, :]
-    mask = jnp.ones((Lq, Lk), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-# -- Mamba2 SSD oracle (kernels/ssd.py) ---------------------------------------
-def ssd_scan(x, dt, A, B, C, D=None, h0=None):
-    """Sequential state-space-duality reference (Mamba2, arXiv:2405.21060).
-
-    x:  (batch, L, H, P)   inputs per head
-    dt: (batch, L, H)      softplus-activated step sizes (already positive)
-    A:  (H,)               negative state decay rate per head
-    B:  (batch, L, G, N)   input projection (G state groups)
-    C:  (batch, L, G, N)   output projection
-    D:  (H,) or None       skip
-    h0: (batch, H, P, N)   initial state or None
-    Returns (y: (batch, L, H, P), h_final).
-    """
-    b, L, H, P = x.shape
-    G, N = B.shape[2], B.shape[3]
-    rep = H // G
-    Bh = jnp.repeat(B, rep, axis=2)  # (b, L, H, N)
-    Ch = jnp.repeat(C, rep, axis=2)
-    h = jnp.zeros((b, H, P, N), jnp.float32) if h0 is None else h0.astype(jnp.float32)
-
-    def step(h, inp):
-        xt, dtt, Bt, Ct = inp  # (b,H,P), (b,H), (b,H,N), (b,H,N)
-        dA = jnp.exp(dtt * A[None, :])  # (b,H)
-        h = h * dA[..., None, None] + (dtt[..., None] * xt)[..., None] * Bt[:, :, None, :]
-        y = jnp.einsum("bhpn,bhn->bhp", h, Ct)
-        return h, y
-
-    xs = (
-        x.astype(jnp.float32).transpose(1, 0, 2, 3),
-        dt.astype(jnp.float32).transpose(1, 0, 2),
-        Bh.astype(jnp.float32).transpose(1, 0, 2, 3),
-        Ch.astype(jnp.float32).transpose(1, 0, 2, 3),
-    )
-    h, ys = jax.lax.scan(step, h, xs)
-    y = ys.transpose(1, 0, 2, 3)  # (b, L, H, P)
-    if D is not None:
-        y = y + x.astype(jnp.float32) * D[None, None, :, None]
-    return y.astype(x.dtype), h

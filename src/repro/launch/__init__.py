@@ -1,3 +1,3 @@
-"""Launch layer: meshes, jit step builders, dry-run, train/serve
-drivers, and the multi-process gang launcher/supervisor
+"""Launch layer: device meshes (:mod:`repro.launch.mesh`) and the
+multi-process gang launcher and supervisor
 (:mod:`repro.launch.multihost`)."""

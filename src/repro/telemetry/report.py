@@ -8,8 +8,7 @@ them by timestamp into one timeline and adds a per-rank phase table, so
 a straggling rank shows up as ITS span rows, not an averaged blur.
 
 Offline companion of the live exporters: everything here is a pure
-function over the JSONL records so ``benchmarks/report.py`` can reuse the
-same tables in EXPERIMENTS.md.
+function over the JSONL records.
 """
 from __future__ import annotations
 

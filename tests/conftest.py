@@ -1,9 +1,8 @@
 import os
 import sys
 
-# Tests must see exactly ONE device (the dry-run sets its own flags in a
-# subprocess). Multi-device tests spawn subprocesses with their own
-# XLA_FLAGS (see _multidev.py helpers).
+# Tests must see exactly ONE device. Multi-device tests spawn
+# subprocesses with their own XLA_FLAGS (``run_subprocess`` below).
 os.environ.setdefault("XLA_FLAGS", "")
 os.environ["JAX_PLATFORMS"] = "cpu"
 

@@ -1,5 +1,5 @@
 """Multi-device tests (subprocess with fake CPU devices): halo exchange,
-comm/compute overlap (paper C6), flash-decoding, compression, elastic."""
+comm/compute overlap (paper C6), compression, elastic."""
 import numpy as np
 import pytest
 
@@ -9,7 +9,7 @@ from conftest import run_subprocess
 def test_halo_overlap_and_multistep():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import init_parallel_stencil, fd3d as fd
 from repro.distributed import halo, overlap
@@ -62,7 +62,7 @@ def test_deep_halo_temporal_blocking():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_parallel_stencil, fd3d as fd
 from repro.distributed import halo, overlap
 from repro.launch.mesh import make_mesh
@@ -117,7 +117,7 @@ assert err < 1e-6
 def test_periodic_halo_wraps():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed import halo
 from repro.launch.mesh import make_mesh
@@ -138,34 +138,10 @@ print("PERIODIC_OK")
     assert "PERIODIC_OK" in out
 
 
-def test_seq_sharded_decode_attention():
-    out = run_subprocess("""
-import jax, jax.numpy as jnp, numpy as np
-from repro.distributed import sharding
-from repro.kernels import ops
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
-rng = np.random.RandomState(0)
-B, Hq, Hkv, S, D = 4, 8, 2, 64, 16
-q = jnp.asarray(rng.randn(B, Hq, D), jnp.float32)
-kc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-vc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-for pos, win in [(40, None), (40, 16), (None, None)]:
-    want = ops.decode_attention(q, kc, vc, pos=None if pos is None else jnp.asarray(pos), window=win)
-    got = sharding.seq_sharded_decode_attention(
-        q, kc, vc, mesh=mesh, seq_axes=("model",), batch_axes=("data",),
-        pos=pos, window=win)
-    err = float(jnp.max(jnp.abs(got - want)))
-    assert err < 1e-5, (pos, win, err)
-print("FLASH_DECODE_OK")
-""")
-    assert "FLASH_DECODE_OK" in out
-
-
 def test_compressed_psum_and_error_feedback():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed import compression
 from repro.launch.mesh import make_mesh
@@ -241,7 +217,7 @@ def test_halo_radius2_overlap():
     bitwise like radius-1."""
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import init_parallel_stencil
 from repro.distributed import halo, overlap
@@ -295,7 +271,7 @@ def test_grouped_exchange_matches_per_field():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed import halo
 from repro.launch.mesh import make_mesh
 
@@ -334,7 +310,7 @@ def test_overlapped_step_coupled_staggered_inputs():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_parallel_stencil, fd2d as fd
 from repro.distributed import overlap
 from repro.launch.mesh import make_mesh
@@ -383,7 +359,7 @@ def test_exchange_depths_tighten_traffic():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_parallel_stencil
 from repro.distributed import halo, overlap
 from repro.launch.mesh import make_mesh

@@ -675,3 +675,67 @@ def test_torn_inflight_step_promoted_by_storage_is_skipped_corrupt(
                             "OUT_NPY": ref})
     assert p.returncode == 0, (p.stdout, p.stderr)
     np.testing.assert_array_equal(np.load(out), np.load(ref))
+
+
+# ---------------- checkpoint ----------------
+def test_checkpoint_atomicity_on_partial_write(tmp_path, rng):
+    mgr = CheckpointManager(str(tmp_path), keep=5)
+    tree = {"w": jnp.asarray(rng.randn(4, 4), jnp.float32)}
+    mgr.save(1, tree)
+    # simulate a crashed writer: leave a stale tmp dir + torn manifest
+    os.makedirs(tmp_path / "step_000000002.tmp")
+    with open(tmp_path / "step_000000002.tmp" / "manifest.json", "w") as f:
+        f.write('{"truncat')
+    restored, extra = mgr.restore({"w": jnp.zeros((4, 4))})
+    assert extra["step"] == 1
+    np.testing.assert_array_equal(np.asarray(restored["w"]),
+                                  np.asarray(tree["w"]))
+
+
+def test_checkpoint_shape_mismatch_raises(tmp_path):
+    mgr = CheckpointManager(str(tmp_path))
+    mgr.save(1, {"w": jnp.zeros((4,))})
+    with pytest.raises(ValueError):
+        mgr.restore({"w": jnp.zeros((5,))})
+
+
+def test_async_save_error_surfaces(tmp_path, monkeypatch):
+    mgr = CheckpointManager(str(tmp_path))
+
+    def boom(*a, **k):
+        raise OSError("disk full")
+
+    monkeypatch.setattr(np, "save", boom)
+    with pytest.raises((RuntimeError, OSError)):
+        mgr.save(1, {"w": jnp.zeros((2,))}, blocking=False)
+        mgr.wait()
+
+
+# ---------------- fault / stragglers ----------------
+def test_straggler_and_dead_detection(tmp_path):
+    mons = [fault.StepMonitor(host_id=i, heartbeat_dir=str(tmp_path),
+                              straggler_factor=1.5, timeout_s=100)
+            for i in range(4)]
+    now = time.time()
+    for i, m in enumerate(mons):
+        for step in range(5):
+            m.record(step, 1.0 if i != 2 else 3.0)  # host 2 is slow
+    health = mons[0].check_peers()
+    assert health["stragglers"] == [2]
+    assert health["dead"] == []
+    # host 3 goes silent
+    data = json.load(open(tmp_path / "host_3.json"))
+    data["t"] = now - 1000
+    json.dump(data, open(tmp_path / "host_3.json", "w"))
+    health = mons[0].check_peers()
+    assert 3 in health["dead"]
+
+
+def test_retry_recovers():
+    calls = {"n": 0}
+    def flaky():
+        calls["n"] += 1
+        if calls["n"] < 3:
+            raise OSError("transient")
+        return 42
+    assert fault.retry(flaky, attempts=5, backoff_s=0.0) == 42

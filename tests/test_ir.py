@@ -11,7 +11,7 @@ from repro.core import boundary, fd2d, fd3d, init_parallel_stencil, teff
 from repro.ir import (BoundaryCondition, StencilCostModel, TraceError,
                       count_flops, trace_stencil)
 from repro.kernels import autotune
-from repro.launch import roofline
+from repro.ir import cost as roofline
 
 SHAPE2 = (20, 24)
 SHAPE3 = (16, 12, 20)

@@ -1,176 +1,9 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret) vs the ref.py oracles."""
-import jax
+"""The hand-written Fig. 1 kernel: Pallas (interpret) vs the ref.py oracle."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-
-TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
-
-
-def _tol(dt):
-    return TOL[dt]
-
-
-# --------------------------------------------------------------------------
-# conv1d
-# --------------------------------------------------------------------------
-@pytest.mark.parametrize("B,L,C,K", [(1, 16, 8, 2), (2, 48, 16, 4),
-                                     (3, 100, 24, 4), (2, 33, 8, 3)])
-@pytest.mark.parametrize("dtype", [jnp.float32])
-def test_conv1d_sweep(B, L, C, K, dtype, rng):
-    x = jnp.asarray(rng.randn(B, L, C), dtype)
-    w = jnp.asarray(rng.randn(K, C), dtype)
-    b = jnp.asarray(rng.randn(C), dtype)
-    want = ref.conv1d_causal(x, w, b)
-    got = ops.conv1d_causal(x, w, b, impl="pallas")
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=_tol(dtype),
-                               rtol=_tol(dtype))
-
-
-def test_conv1d_silu_matches(rng):
-    x = jnp.asarray(rng.randn(2, 32, 8), jnp.float32)
-    w = jnp.asarray(rng.randn(4, 8), jnp.float32)
-    got = ops.conv1d_causal(x, w, None, silu=True, impl="pallas")
-    want = ops.conv1d_causal(x, w, None, silu=True, impl="chunked")
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-
-
-def test_conv1d_bf16(rng):
-    x = jnp.asarray(rng.randn(2, 32, 16), jnp.bfloat16)
-    w = jnp.asarray(rng.randn(4, 16), jnp.bfloat16)
-    want = ref.conv1d_causal(x, w, None)
-    got = ops.conv1d_causal(x, w, None, impl="pallas")
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=3e-2, rtol=3e-2)
-
-
-# --------------------------------------------------------------------------
-# attention
-# --------------------------------------------------------------------------
-@pytest.mark.parametrize("B,Hq,Hkv,L,D", [
-    (1, 4, 4, 64, 16),    # MHA
-    (2, 8, 2, 128, 32),   # GQA
-    (1, 8, 1, 96, 16),    # MQA, non-pow2 length
-])
-@pytest.mark.parametrize("causal,window", [(True, None), (True, 37),
-                                           (False, None)])
-def test_attention_impls_agree(B, Hq, Hkv, L, D, causal, window, rng):
-    q = jnp.asarray(rng.randn(B, Hq, L, D), jnp.float32)
-    k = jnp.asarray(rng.randn(B, Hkv, L, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, Hkv, L, D), jnp.float32)
-    want = ref.attention(q, k, v, causal=causal, window=window)
-    got_c = ops.attention(q, k, v, causal=causal, window=window,
-                          impl="chunked", q_chunk=32, k_chunk=48)
-    np.testing.assert_allclose(got_c, want, atol=2e-5, rtol=2e-5)
-    got_p = ops.attention(q, k, v, causal=causal, window=window, impl="pallas")
-    np.testing.assert_allclose(got_p, want, atol=2e-5, rtol=2e-5)
-
-
-def test_attention_bf16(rng):
-    q = jnp.asarray(rng.randn(2, 4, 64, 32), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(2, 2, 64, 32), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(2, 2, 64, 32), jnp.bfloat16)
-    want = ref.attention(q, k, v)
-    for impl in ("chunked", "pallas"):
-        got = ops.attention(q, k, v, impl=impl, q_chunk=32, k_chunk=32)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   atol=3e-2, rtol=3e-2)
-
-
-def test_decode_attention_matches_last_row(rng):
-    B, Hq, Hkv, S, D = 2, 8, 2, 64, 16
-    kc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-    vc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-    q = jnp.asarray(rng.randn(B, Hq, D), jnp.float32)
-    want = ref.attention(q[:, :, None], kc, vc, causal=True)[:, :, 0]
-    got = ops.decode_attention(q, kc, vc)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-
-
-def test_decode_attention_pos_masks_future(rng):
-    B, Hq, Hkv, S, D = 1, 2, 2, 32, 8
-    kc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-    vc = jnp.asarray(rng.randn(B, Hkv, S, D), jnp.float32)
-    q = jnp.asarray(rng.randn(B, Hq, D), jnp.float32)
-    pos = 10
-    got = ops.decode_attention(q, kc, vc, pos=jnp.asarray(pos))
-    got2 = ops.decode_attention(q, kc[:, :, : pos + 1], vc[:, :, : pos + 1])
-    np.testing.assert_allclose(got, got2, atol=2e-5, rtol=2e-5)
-
-
-def test_attention_grad_matches_ref(rng):
-    B, Hq, Hkv, L, D = 1, 4, 2, 64, 16
-    q = jnp.asarray(rng.randn(B, Hq, L, D), jnp.float32)
-    k = jnp.asarray(rng.randn(B, Hkv, L, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, Hkv, L, D), jnp.float32)
-    g_ref = jax.grad(lambda q: jnp.sum(ref.attention(q, k, v) ** 2))(q)
-    g_chk = jax.grad(lambda q: jnp.sum(
-        ops.attention(q, k, v, impl="chunked", q_chunk=16, k_chunk=16) ** 2))(q)
-    np.testing.assert_allclose(g_chk, g_ref, atol=1e-4, rtol=1e-4)
-
-
-# --------------------------------------------------------------------------
-# SSD
-# --------------------------------------------------------------------------
-@pytest.mark.parametrize("B,L,H,P,G,N,chunk", [
-    (1, 32, 2, 4, 1, 8, 8),
-    (2, 64, 4, 8, 2, 16, 16),
-    (1, 80, 4, 8, 4, 8, 32),   # L not divisible by chunk -> falls back
-])
-def test_ssd_sweep(B, L, H, P, G, N, chunk, rng):
-    x = jnp.asarray(rng.randn(B, L, H, P) * 0.5, jnp.float32)
-    dt = jnp.asarray(np.abs(rng.randn(B, L, H)) * 0.1 + 0.01, jnp.float32)
-    A = jnp.asarray(-np.abs(rng.rand(H)) - 0.1, jnp.float32)
-    Bm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    Cm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    D = jnp.asarray(rng.randn(H), jnp.float32)
-    want, hw = ref.ssd_scan(x, dt, A, Bm, Cm, D=D)
-    for impl in ("chunked", "pallas"):
-        got, h = ops.ssd(x, dt, A, Bm, Cm, D=D, impl=impl, chunk=chunk)
-        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-4,
-                                   err_msg=impl)
-        np.testing.assert_allclose(h, hw, atol=3e-5, rtol=3e-4, err_msg=impl)
-
-
-def test_ssd_decode_chain_equals_scan(rng):
-    B, L, H, P, G, N = 2, 16, 4, 8, 2, 16
-    x = jnp.asarray(rng.randn(B, L, H, P) * 0.5, jnp.float32)
-    dt = jnp.asarray(np.abs(rng.randn(B, L, H)) * 0.1 + 0.01, jnp.float32)
-    A = jnp.asarray(-np.abs(rng.rand(H)) - 0.1, jnp.float32)
-    Bm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    Cm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    want, _ = ref.ssd_scan(x, dt, A, Bm, Cm)
-    Bh = jnp.repeat(Bm, H // G, axis=2)
-    Ch = jnp.repeat(Cm, H // G, axis=2)
-    h = jnp.zeros((B, H, P, N), jnp.float32)
-    outs = []
-    for t in range(L):
-        y, h = ops.ssd_decode_step(h, x[:, t], dt[:, t], A, Bh[:, t], Ch[:, t])
-        outs.append(y)
-    np.testing.assert_allclose(jnp.stack(outs, 1), want, atol=1e-5, rtol=1e-4)
-
-
-def test_ssd_h0_continuation(rng):
-    """Splitting a sequence in two with state carry == one long scan."""
-    B, L, H, P, G, N = 1, 32, 2, 4, 1, 8
-    x = jnp.asarray(rng.randn(B, L, H, P) * 0.5, jnp.float32)
-    dt = jnp.asarray(np.abs(rng.randn(B, L, H)) * 0.1 + 0.01, jnp.float32)
-    A = jnp.asarray(-np.abs(rng.rand(H)) - 0.1, jnp.float32)
-    Bm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    Cm = jnp.asarray(rng.randn(B, L, G, N) * 0.3, jnp.float32)
-    full, hf = ops.ssd(x, dt, A, Bm, Cm, impl="chunked", chunk=8)
-    half = L // 2
-    y1, h1 = ops.ssd(x[:, :half], dt[:, :half], A, Bm[:, :half], Cm[:, :half],
-                     impl="chunked", chunk=8)
-    y2, h2 = ops.ssd(x[:, half:], dt[:, half:], A, Bm[:, half:], Cm[:, half:],
-                     h0=h1, impl="chunked", chunk=8)
-    np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), full,
-                               atol=2e-5, rtol=2e-4)
-    np.testing.assert_allclose(h2, hf, atol=2e-5, rtol=2e-4)
 
 
 # --------------------------------------------------------------------------

@@ -307,7 +307,7 @@ def test_compressed_psum_exactness_shared_scale():
     # because every rank multiplies by the same power-free shared scale
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed import compression
 from repro.launch.mesh import make_mesh

@@ -9,8 +9,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import boundary, fd2d, fd3d, init_parallel_stencil
 from repro.distributed import compression
-from repro.data import DataConfig, make_source
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 SETTINGS = dict(max_examples=20, deadline=None)
 
@@ -134,21 +133,6 @@ def test_periodic_diffusion_conserves_mass(seed):
     assert abs(after - before) < 1e-3 * max(abs(before), 1.0)
 
 
-@given(seed=st.integers(0, 2**31 - 1),
-       B=st.integers(1, 3), L=st.sampled_from([16, 32, 48]),
-       window=st.sampled_from([None, 8, 24]))
-@settings(**SETTINGS)
-def test_chunked_attention_property(seed, B, L, window):
-    rng = np.random.RandomState(seed)
-    q = jnp.asarray(rng.randn(B, 4, L, 8), jnp.float32)
-    k = jnp.asarray(rng.randn(B, 2, L, 8), jnp.float32)
-    v = jnp.asarray(rng.randn(B, 2, L, 8), jnp.float32)
-    want = ref.attention(q, k, v, causal=True, window=window)
-    got = ops.attention(q, k, v, causal=True, window=window, impl="chunked",
-                        q_chunk=16, k_chunk=16)
-    np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-4)
-
-
 @given(seed=st.integers(0, 2**31 - 1), scale=st.floats(1e-3, 1e3))
 @settings(**SETTINGS)
 def test_int8_quantization_error_bound(seed, scale):
@@ -160,42 +144,3 @@ def test_int8_quantization_error_bound(seed, scale):
     back = compression.dequantize_int8(q, s, meta)
     bound = float(jnp.max(jnp.abs(g))) / 254 + 1e-8
     assert float(jnp.max(jnp.abs(back - g))) <= bound * 1.01
-
-
-@given(step=st.integers(0, 1000), shards=st.sampled_from([1, 2, 4]),
-       seed=st.integers(0, 10000))
-@settings(**SETTINGS)
-def test_data_shards_partition_global_batch(step, shards, seed):
-    """Shard batches are disjoint slices of one deterministic global batch:
-    re-running any (step, shard) reproduces identical data — the failover
-    recovery contract."""
-    gb = 8
-    batches = []
-    for sid in range(shards):
-        cfg = DataConfig(vocab=512, seq_len=12, global_batch=gb,
-                         n_shards=shards, shard_id=sid, seed=seed)
-        src = make_source(cfg)
-        b1 = src.batch(step)
-        b2 = make_source(cfg).batch(step)
-        np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-        batches.append(b1["tokens"])
-    allb = np.concatenate(batches)
-    assert allb.shape == (gb, 12)
-    assert (allb >= 0).all() and (allb < 512).all()
-
-
-@given(seed=st.integers(0, 2**31 - 1), L=st.sampled_from([16, 31, 64]))
-@settings(**SETTINGS)
-def test_conv1d_property(seed, L):
-    rng = np.random.RandomState(seed)
-    x = jnp.asarray(rng.randn(2, L, 8), jnp.float32)
-    w = jnp.asarray(rng.randn(4, 8), jnp.float32)
-    want = ref.conv1d_causal(x, w)
-    got = ops.conv1d_causal(x, w, impl="pallas")
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
-    # causality: perturbing x[t0] never changes out[:, :t0]
-    t0 = L // 2
-    x2 = x.at[:, t0].add(1.0)
-    got2 = ops.conv1d_causal(x2, w, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(got[:, :t0]),
-                                  np.asarray(got2[:, :t0]))

@@ -553,7 +553,7 @@ def test_distributed_partials_pmax_psum():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_parallel_stencil, fd3d as fd
 from repro.distributed import halo, overlap
 from repro.launch.mesh import make_mesh
@@ -641,7 +641,7 @@ def test_distributed_checks_equal_the_global_check(factors):
     out = run_subprocess(f"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_parallel_stencil, fd3d as fd
 from repro.distributed import elastic, halo, overlap
 from repro.launch.mesh import make_mesh

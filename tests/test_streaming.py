@@ -16,7 +16,7 @@ from repro.core import fd2d, fd3d, init_parallel_stencil, teff
 from repro.ir import BoundaryCondition
 from repro.kernels import autotune
 from repro.kernels.stencil import build_stencil_call
-from repro.launch import roofline as _roofline
+from repro.ir import cost as _roofline
 
 SHAPE3 = (20, 16, 24)
 SC3 = dict(lam=1.0, dt=1e-4, _dx=float(SHAPE3[0] - 1),
@@ -303,7 +303,7 @@ def test_overlapped_step_streamed_interior():
     slabs stay all-parallel; only the interior launch marches)."""
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import init_parallel_stencil, fd2d
 from repro.distributed import halo, overlap

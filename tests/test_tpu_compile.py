@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import fd3d as fd, init_parallel_stencil
-from repro.kernels import autotune, conv1d, diffusion3d
+from repro.kernels import autotune, diffusion3d
 from repro.kernels.stencil import TileNotCompilable
 
 N = 512
@@ -210,11 +210,6 @@ def test_modes_refused_for_the_tpu(one_chip):
         compiled_text(run(fig1(march_axis=2)), one_chip)
     with pytest.raises(TileNotCompilable, match="minor axis"):
         compiled_text(run(fig1(tile=(8, 64, N))), one_chip)
-    x = jax.ShapeDtypeStruct((1, 256, 128), jnp.float32, sharding=one_chip)
-    w = jax.ShapeDtypeStruct((4, 128), jnp.float32, sharding=one_chip)
-    with pytest.raises(NotImplementedError, match="Mosaic refuses"):
-        jax.jit(lambda x, w: conv1d.conv1d_causal(x, w, interpret=False)
-                ).lower(x, w).compile()
 
 
 def test_launch_carries_parallel_name(one_chip):
